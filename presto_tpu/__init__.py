@@ -36,25 +36,6 @@ import jax as _jax
 # pairs -- hot kernels that can prove 32-bit ranges downcast explicitly.)
 _jax.config.update("jax_enable_x64", True)
 
-# shard_map compatibility: the engine (and its tests) speak the current
-# `jax.shard_map(..., check_vma=)` API; older jax ships it as
-# jax.experimental.shard_map.shard_map with `check_rep=`. Install a
-# forwarding alias so one codebase runs on both.
-if not hasattr(_jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def _compat_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                              check_vma=None, **kw):
-            if check_vma is not None and "check_rep" not in kw:
-                kw["check_rep"] = check_vma
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-        _jax.shard_map = _compat_shard_map
-    except Exception:  # noqa: BLE001 - newer jax removed experimental path
-        pass
-
 __version__ = "0.1.0"
 
 

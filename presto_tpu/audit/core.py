@@ -142,11 +142,8 @@ def _suppressions(abs_path: str) -> Dict[int, frozenset]:
 def _user_frame(eqn):
     """The first non-jax frame of an eqn's traceback, or None (e.g.
     jaxprs built programmatically)."""
-    try:
-        from jax._src import source_info_util
-        return source_info_util.user_frame(eqn.source_info)
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    from jax._src import source_info_util
+    return source_info_util.user_frame(eqn.source_info.traceback)
 
 
 class KernelIR:

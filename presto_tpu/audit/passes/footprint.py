@@ -44,7 +44,7 @@ def _aval_bytes(v) -> int:
 
 
 def _jaxpr_peak(jx) -> int:
-    from jax.core import Literal
+    from jax.extend.core import Literal
     last = {}
     for i, e in enumerate(jx.eqns):
         for v in e.invars:

@@ -212,6 +212,9 @@ def main(argv=None) -> int:
                          "enriched stats while the statement runs")
     ap.add_argument("--user", default="presto")
     args = ap.parse_args(argv)
+    if not args.server:  # the embedded engine compiles in this process
+        from presto_tpu.utils.compile_cache import setup_compile_cache
+        setup_compile_cache()
 
     if args.query:
         if args.server:

@@ -644,7 +644,9 @@ def _substr(ret, a: StringColumn, start: Column, *rest):
     ln = jnp.clip(jnp.minimum(ln, a.lengths - st), 0, w)
     ln = jnp.where(valid, ln, 0)
     idx = st[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    gathered = jnp.take_along_axis(a.chars, jnp.clip(idx, 0, w - 1), axis=1)
+    # jax 0.9.0's take_along_axis widens int32 indices to the default
+    # int (int64 under x64) before the gather -- its choice, not ours
+    gathered = jnp.take_along_axis(a.chars, jnp.clip(idx, 0, w - 1), axis=1)  # kernaudit: disable=K001
     keep = jnp.arange(w, dtype=jnp.int32)[None, :] < ln[:, None]
     out = jnp.where(keep, gathered, 0).astype(jnp.uint8)
     extra = [rest[0]] if rest else []
@@ -689,16 +691,18 @@ def _trim(ret, a: StringColumn):
 
 
 def contains_pattern(a: StringColumn, needle: bytes):
-    """Vectorized substring search (LIKE '%needle%'). On TPU this
-    dispatches to the Pallas VMEM-tiled kernel (ops/pallas_kernels.py);
-    the XLA fallback materializes the window gather."""
+    """Vectorized substring search (LIKE '%needle%'). Traced for a TPU
+    this is the compiled Pallas VMEM-tiled kernel
+    (ops/pallas_kernels.py); every other backend gets the XLA form
+    below, which materializes the window gather."""
     L = max(len(needle), 1)
     n, w = a.chars.shape
     if L > w:
         return jnp.zeros(n, dtype=bool)
-    from ..ops.pallas_kernels import contains_bytes, pallas_supported
-    if pallas_supported():
-        return contains_bytes(a.chars, a.lengths, needle)
+    from ..ops import device
+    if device.on_tpu():
+        from ..ops.pallas_kernels import contains_bytes
+        return contains_bytes(a.chars, a.lengths, needle, interpret=False)
     pat = jnp.asarray(bytearray(needle), dtype=jnp.uint8)
     windows = w - L + 1
     idx = (jnp.arange(windows, dtype=jnp.int32)[:, None]

@@ -64,7 +64,8 @@ import numpy as np
 from .. import types as T
 from ..block import (Batch, Block, Column, DictionaryColumn, Int128Column,
                      StringColumn)
-from .keys import key_words
+from . import device
+from .keys import key_words, lex_sort
 
 __all__ = ["AggSpec", "GroupByResult", "group_by", "grouped_aggregate",
            "merge_partials", "finalize_states", "last_smallg_form"]
@@ -175,7 +176,7 @@ def _scatter_free() -> bool:
         return True
     if mode == "scatter":
         return False
-    return jax.default_backend() == "tpu"
+    return device.on_tpu()
 
 
 def _group_ids(key_cols: Sequence[Block], active: jnp.ndarray, max_groups: int):
@@ -257,7 +258,7 @@ def _mxu_bf16() -> bool:
         return _narrow_kernels()
     if mode == "0":
         return False
-    return _narrow_kernels() and jax.default_backend() == "tpu"
+    return _narrow_kernels() and device.on_tpu()
 
 
 # which small-G sum form the last trace actually emitted (trace-time
@@ -291,8 +292,10 @@ def _fused_limb_sums(ids, requests, max_groups: int,
     numerics, bit-identical results.
 
     On TPU the one-hot+matmul runs as a fused Pallas kernel (the
-    one-hot never stages through HBM); PRESTO_TPU_SMALLG_PALLAS=0
-    selects the XLA einsum form."""
+    one-hot never stages through HBM), always compiled, never in
+    interpret mode: if Mosaic refuses it the query fails, nothing
+    gives way to the einsum form behind the user's back.
+    PRESTO_TPU_SMALLG_PALLAS=0 selects the XLA einsum form (chip A/B)."""
     from ..int128 import limbs_of_i64
     narrow = _mxu_bf16()
     limb_bits = 8 if narrow else 13
@@ -309,11 +312,11 @@ def _fused_limb_sums(ids, requests, max_groups: int,
     L = len(limb_cols)
     lm = jnp.stack([l.astype(stage_dt) for l in limb_cols], axis=1)
     if _os.environ.get("PRESTO_TPU_SMALLG_PALLAS", "1") != "0" \
-            and jax.default_backend() == "tpu":
+            and device.on_tpu():
         from .pallas_kernels import limb_partial_sums
         _note_form("pallas-bf16" if narrow else "pallas")
         part = limb_partial_sums(
-            ids.astype(jnp.int32), lm, max_groups,
+            ids.astype(jnp.int32), lm, max_groups, interpret=False,
             compute_dtype=jnp.bfloat16 if narrow else jnp.float32)
     else:
         c = -(-n // chunk)
@@ -570,7 +573,7 @@ def _group_ids_sort(key_cols: Sequence[Block], active: jnp.ndarray,
     # inactive rows sort last: leading word 1 for inactive
     lead = jnp.where(active, np.uint64(0), np.uint64(1))
     operands = [lead, *words, jnp.arange(n, dtype=jnp.int32)]
-    sorted_ops = jax.lax.sort(operands, num_keys=len(operands) - 1)
+    sorted_ops = lex_sort(operands, num_keys=len(operands) - 1)
     s_words = sorted_ops[:-1]
     perm = sorted_ops[-1]
     s_active = s_words[0] == 0
@@ -831,7 +834,7 @@ def _group_by_sorted(batch: Batch, key_channels, aggs, max_groups: int
         ops.extend(vwords)
         n_pair_words = len(vwords)
     ops.append(jnp.arange(n, dtype=jnp.int32))
-    out = jax.lax.sort(ops, num_keys=len(ops) - 1)
+    out = lex_sort(ops, num_keys=len(ops) - 1)
     s_lead = out[0]
     s_words = out[1:1 + nkw]
     s_pair_words = out[1 + nkw:1 + nkw + n_pair_words]
@@ -1191,7 +1194,7 @@ def _acc_columns(spec: AggSpec, col: Optional[Block], ids, active, max_groups: i
         lead = jnp.where(live, np.uint64(0), np.uint64(1))
         ops_ = [lead, ids.astype(jnp.uint64), *vwords,
                 jnp.arange(n, dtype=jnp.int32)]
-        perm = jax.lax.sort(ops_, num_keys=len(ops_) - 1)[-1]
+        perm = lex_sort(ops_, num_keys=len(ops_) - 1)[-1]
         pos = jnp.arange(n, dtype=jnp.int64)
         sorted_ids = jnp.where(live[perm], ids[perm], g)
         start = _seg_min(jnp.clip(sorted_ids, 0, g - 1),

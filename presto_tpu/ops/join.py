@@ -39,7 +39,7 @@ import numpy as np
 
 from .. import types as T
 from ..block import Batch, Block, Column, DictionaryColumn, StringColumn
-from .keys import key_words
+from .keys import key_words, lex_sort
 
 __all__ = ["hash_join", "JoinResult", "semi_join_mask"]
 
@@ -114,7 +114,7 @@ def _sort_build(b_words: List[jnp.ndarray], b_usable: jnp.ndarray,
     ops = [*masked, tiebreak]
     if payload is not None:
         ops.append(payload)
-    out = jax.lax.sort(ops, num_keys=len(masked) + 1)
+    out = lex_sort(ops, num_keys=len(masked) + 1)
     sorted_words = out[:len(masked)]
     sorted_payload = out[-1] if payload is not None else None
     return sorted_words, sorted_payload
@@ -141,7 +141,7 @@ def _pack_ranks(build_words: List[jnp.ndarray], probe_words: List[jnp.ndarray]):
         is_probe = jnp.concatenate([jnp.zeros(nb, dtype=jnp.uint64),
                                     jnp.ones(npr, dtype=jnp.uint64)])
         idx = jnp.arange(nb + npr, dtype=jnp.int32)
-        r, w, tag, pi = jax.lax.sort(
+        r, w, tag, pi = lex_sort(
             [ranks.astype(jnp.uint64), words, is_probe, idx], num_keys=3)
         # dense rank over (rank, word) pairs
         boundary = (r != jnp.concatenate([r[:1], r[:-1]])) | \

@@ -1,15 +1,18 @@
-"""Pallas TPU kernels for hot string ops.
+"""Pallas TPU kernels for the hot ops XLA alone stages through HBM.
 
 Reference surface: the tight per-row loops the reference compiles to
 JVM bytecode/Velox SIMD for LIKE and substring search
-(operator/scalar/StringFunctions.java, LikeFunctions). The XLA fallback
-in expr/functions.contains_pattern materializes an (N, windows, L)
-gather in HBM; this kernel keeps each row tile in VMEM and walks the
-windows with a fori_loop -- O(N*L) VMEM traffic instead of O(N*W*L)
-HBM, the usual 10x+ for long patterns on wide columns.
+(operator/scalar/StringFunctions.java, LikeFunctions), and the
+grouped-sum inner loop of the small-table aggregation.
 
-Kernels run on TPU via pallas_call and everywhere else (tests, CPU
-mesh) in interpret mode; expr/functions dispatches based on platform.
+The engine calls these only when `ops.aggregation.on_tpu()` says the
+program is being traced for the chip, and always compiled
+(`interpret=False`): a kernel Mosaic refuses fails the query, it never
+falls back. `interpret=True` exists for the CPU tests of the kernels'
+numerics and nothing else. Both kernels are independent of the x64
+switch the package turns on (32-bit index maps, 32-bit lanes inside),
+and tests/test_tpu_compile.py compiles them for a described v5e at the
+shapes TPC-H SF1 produces.
 """
 
 from __future__ import annotations
@@ -21,86 +24,61 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["contains_bytes", "pallas_supported"]
+__all__ = ["contains_bytes", "limb_partial_sums"]
 
-_TILE = 512
+# BlockSpec index maps must return 32-bit values: under x64 a Python 0
+# traces as i64 and Mosaic cannot legalize the map's func.return
+_Z = np.int32(0)
 
-
-def pallas_supported() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+_TILE = 1024
 
 
 def _contains_kernel(chars_ref, lengths_ref, out_ref, *, pattern: tuple):
-    """One row-tile: chars (TILE, W) uint8 in VMEM; scan windows for the
-    byte pattern (compile-time constant)."""
-    chars = chars_ref[:].astype(jnp.int32)
-    lengths = lengths_ref[:]
-    tile, w = chars.shape
+    """One tile of rows, rows on LANES: chars (W, TILE) uint8, lengths
+    and the 0/1 result (1, TILE) int32. Window starts run along the
+    sublanes, so the k-th pattern byte is a static sublane-offset slice
+    and the any-window reduction leaves a lane-dense row."""
+    c = chars_ref[:].astype(jnp.int32)
+    w, tile = c.shape
     L = len(pattern)
     windows = w - L + 1
-
-    def body(i, acc):
-        # match at window start i: all pattern bytes equal
-        m = jnp.ones((tile,), dtype=jnp.bool_)
-        for k, byte in enumerate(pattern):
-            m = m & (chars[:, i + k] == byte)
-        m = m & ((i + L) <= lengths)
-        return acc | m
-
-    if windows <= 0:
-        out_ref[:] = jnp.zeros((tile,), dtype=jnp.bool_)
-        return
-    # unroll small window counts; fori_loop for wide columns
-    if windows <= 8:
-        acc = jnp.zeros((tile,), dtype=jnp.bool_)
-        for i in range(windows):
-            acc = body(i, acc)
-    else:
-        def loop_body(i, acc):
-            # per-byte compare at window i (pattern bytes are Python
-            # scalars -- no captured constant arrays)
-            m = jnp.ones((tile,), dtype=jnp.bool_)
-            for k, byte in enumerate(pattern):
-                ck = jax.lax.dynamic_slice(chars, (0, i + k), (tile, 1))[:, 0]
-                m = m & (ck == byte)
-            m = m & ((i + L) <= lengths)
-            return acc | m
-        acc = jax.lax.fori_loop(0, windows, loop_body,
-                                jnp.zeros((tile,), dtype=jnp.bool_))
-    out_ref[:] = acc
+    m = c[0:windows, :] == pattern[0]
+    for k in range(1, L):
+        m = m & (c[k:k + windows, :] == pattern[k])
+    start = jax.lax.broadcasted_iota(jnp.int32, (windows, tile), 0)
+    m = m & ((start + L) <= lengths_ref[0])  # window ends inside the row
+    out_ref[0] = jnp.max(m.astype(jnp.int32), axis=0, keepdims=True)
 
 
 def contains_bytes(chars: jax.Array, lengths: jax.Array, needle: bytes,
-                   interpret: bool | None = None) -> jax.Array:
+                   *, interpret: bool) -> jax.Array:
     """(N,) bool: needle appears within the first lengths[i] bytes of
-    row i. Pads N to the row-tile size; pattern is baked into the
-    kernel (LIKE patterns are plan constants)."""
-    if interpret is None:
-        interpret = not pallas_supported()
+    row i. The pattern is baked into the kernel (LIKE patterns are plan
+    constants). The (N, W) column is transposed once by XLA so the
+    kernel sees rows on lanes; N pads to the tile."""
     n, w = chars.shape
-    L = max(len(needle), 1)
-    if L > w:
+    if not needle:  # every string contains the empty string
+        return jnp.ones(n, dtype=bool)
+    if len(needle) > w:
         return jnp.zeros(n, dtype=bool)
     pad = (-n) % _TILE
     if pad:
         chars = jnp.pad(chars, ((0, pad), (0, 0)))
         lengths = jnp.pad(lengths, (0, pad))
     total = chars.shape[0]
+    tiles = total // _TILE
     kernel = functools.partial(_contains_kernel,
                                pattern=tuple(bytearray(needle)))
     out = pl.pallas_call(
         kernel,
-        grid=(total // _TILE,),
-        in_specs=[pl.BlockSpec((_TILE, w), lambda i: (i, 0)),
-                  pl.BlockSpec((_TILE,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((_TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((total,), jnp.bool_),
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((w, _TILE), lambda i: (_Z, i)),
+                  pl.BlockSpec((1, 1, _TILE), lambda i: (i, _Z, _Z))],
+        out_specs=pl.BlockSpec((1, 1, _TILE), lambda i: (i, _Z, _Z)),
+        out_shape=jax.ShapeDtypeStruct((tiles, 1, _TILE), jnp.int32),
         interpret=interpret,
-    )(chars, lengths.astype(jnp.int32))
-    return out[:n]
+    )(chars.T, lengths.astype(jnp.int32).reshape(tiles, 1, _TILE))
+    return out.reshape(total)[:n] != 0
 
 
 # ---------------------------------------------------------------------------
@@ -115,9 +93,11 @@ def _limb_sum_kernel(ids_ref, limbs_ref, out_ref, *, groups: int,
     """One row tile: build the one-hot(ids) in VMEM and ride the MXU
     for (G, L) partial sums -- the fused form of the XLA path's
     one_hot-materialize + einsum (which stages an (n, G) one-hot
-    through HBM). Each tile's f32 sums stay < 2^24 (exact); tiles
-    combine in int64 OUTSIDE the kernel, identical numerics to
-    aggregation._limb_matmul_sum.
+    through HBM). ids arrive lane-major (1, TILE), so the one-hot is
+    built already transposed, (G, TILE), and the dot needs no layout change.
+    Each tile's f32 sums stay < 2^24 (exact); tiles combine in int64
+    OUTSIDE the kernel, identical numerics to
+    aggregation._fused_limb_sums' einsum form.
 
     compute_dtype=bfloat16 (narrow-width execution): one MXU pass --
     exact because one-hot entries are 0/1 and 8-bit limbs (|v| <= 255,
@@ -125,21 +105,18 @@ def _limb_sum_kernel(ids_ref, limbs_ref, out_ref, *, groups: int,
     f32. compute_dtype=float32 keeps the wide form, where
     precision=HIGHEST is required: default-precision f32 dot lowers to
     bf16 passes on TPU, which cannot hold 13-bit limbs exactly."""
-    ids = ids_ref[:]
-    gidx = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], groups), 1)
-    onehot = (ids[:, None] == gidx).astype(compute_dtype)
+    ids = ids_ref[0]
+    gidx = jax.lax.broadcasted_iota(jnp.int32, (groups, ids.shape[1]), 0)
+    onehot_t = (ids == gidx).astype(compute_dtype)
     limbs = limbs_ref[:].astype(compute_dtype)
-    if compute_dtype == jnp.bfloat16:
-        out_ref[0] = jnp.dot(onehot.T, limbs,
-                             preferred_element_type=jnp.float32)
-    else:
-        out_ref[0] = jnp.dot(onehot.T, limbs,
-                             precision=jax.lax.Precision.HIGHEST,
-                             preferred_element_type=jnp.float32)
+    precision = (None if compute_dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    out_ref[0] = jnp.dot(onehot_t, limbs, precision=precision,
+                         preferred_element_type=jnp.float32)
 
 
 def limb_partial_sums(ids: jax.Array, limbs: jax.Array, groups: int,
-                      interpret: bool | None = None,
+                      *, interpret: bool,
                       compute_dtype=jnp.float32) -> jax.Array:
     """(tiles, G, L) f32 per-tile partial sums of `limbs` grouped by
     `ids` (int32; out-of-range ids contribute nothing). Rows pad to the
@@ -147,15 +124,12 @@ def limb_partial_sums(ids: jax.Array, limbs: jax.Array, groups: int,
     `limbs` may arrive at any integer/float lane dtype whose values the
     MXU operand dtype holds exactly (int16 8-bit limbs for the bf16
     narrow form, f32 13-bit limbs for the wide form)."""
-    if interpret is None:
-        interpret = not pallas_supported()
     n, L = limbs.shape
     pad = (-n) % _SUM_TILE
     if pad:
         ids = jnp.pad(ids, (0, pad), constant_values=groups)
         limbs = jnp.pad(limbs, ((0, pad), (0, 0)))
-    total = ids.shape[0]
-    tiles = total // _SUM_TILE
+    tiles = ids.shape[0] // _SUM_TILE
     kernel = functools.partial(_limb_sum_kernel, groups=groups,
                                compute_dtype=compute_dtype)
     if limbs.dtype not in (jnp.int16, jnp.bfloat16):
@@ -163,9 +137,9 @@ def limb_partial_sums(ids: jax.Array, limbs: jax.Array, groups: int,
     return pl.pallas_call(
         kernel,
         grid=(tiles,),
-        in_specs=[pl.BlockSpec((_SUM_TILE,), lambda i: (i,)),
-                  pl.BlockSpec((_SUM_TILE, L), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, groups, L), lambda i: (i, 0, 0)),
+        in_specs=[pl.BlockSpec((1, 1, _SUM_TILE), lambda i: (i, _Z, _Z)),
+                  pl.BlockSpec((_SUM_TILE, L), lambda i: (i, _Z))],
+        out_specs=pl.BlockSpec((1, groups, L), lambda i: (i, _Z, _Z)),
         out_shape=jax.ShapeDtypeStruct((tiles, groups, L), jnp.float32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), limbs)
+    )(ids.astype(jnp.int32).reshape(tiles, 1, _SUM_TILE), limbs)

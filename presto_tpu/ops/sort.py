@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..block import Batch, Block
-from .keys import key_words
+from .keys import key_words, lex_sort
 
 __all__ = ["SortKey", "sort_batch", "top_n", "sort_permutation"]
 
@@ -58,7 +58,7 @@ def sort_permutation(batch: Batch, keys: Sequence[SortKey]) -> jnp.ndarray:
         operands.extend(_column_words(batch.column(sk.channel),
                                       sk.descending, sk.nulls_last))
     operands.append(jnp.arange(n, dtype=jnp.int32))
-    out = jax.lax.sort(operands, num_keys=len(operands) - 1, is_stable=True)
+    out = lex_sort(operands, num_keys=len(operands) - 1, is_stable=True)
     return out[-1]
 
 

@@ -35,7 +35,7 @@ import numpy as np
 from .. import types as T
 from ..block import (Batch, Block, Column, DictionaryColumn, Int128Column,
                      StringColumn)
-from .keys import key_words
+from .keys import key_words, lex_sort
 from .sort import SortKey, _column_words
 
 __all__ = ["WindowSpec", "window"]
@@ -90,7 +90,7 @@ def window(batch: Batch, partition_channels: Sequence[int],
                                     sk.nulls_last))
     lead = jnp.where(batch.active, np.uint64(0), np.uint64(1))
     ops = [lead, *pwords, *owords, pos.astype(jnp.int32)]
-    sorted_ops = jax.lax.sort(ops, num_keys=len(ops) - 1, is_stable=True)
+    sorted_ops = lex_sort(ops, num_keys=len(ops) - 1, is_stable=True)
     perm = sorted_ops[-1]
     s_active = sorted_ops[0] == 0
     s_pwords = sorted_ops[1:1 + len(pwords)]

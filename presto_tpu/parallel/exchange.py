@@ -26,6 +26,7 @@ import numpy as np
 from ..block import (Batch, Block, Column, DictionaryColumn, Int128Column,
                      StringColumn)
 from ..expr.functions import combine_hash, hash64_block
+from ..ops.keys import lex_sort
 
 __all__ = ["exchange_by_hash", "exchange_by_range", "broadcast_build",
            "gather_to_root"]
@@ -152,7 +153,7 @@ def exchange_by_range(batch: Batch, sort_keys, axis_name: str,
 
     # draw evenly spaced samples from the locally ordered active rows
     act_word = jnp.where(batch.active, jnp.uint64(0), jnp.uint64(1))
-    local_sorted = jax.lax.sort([act_word] + words, num_keys=1 + nw)[1:]
+    local_sorted = lex_sort([act_word] + words, num_keys=1 + nw)[1:]
     count = jnp.sum(batch.active.astype(jnp.int64))
     s = samples_per_worker
     pos = ((jnp.arange(s, dtype=jnp.int64) * 2 + 1) * count) // (2 * s)

@@ -209,7 +209,7 @@ class ResourceGroup:
             self._cv.notify_all()
 
 
-# the latency-class taxonomy (admission-to-SLO): interactive point
+# the latency classes (admission-to-SLO): interactive point
 # lookups preempt dashboard refreshes preempt batch scans. Limits are
 # per-class concurrency + queue depth; the shared root caps the tree.
 LATENCY_CLASSES = ("interactive", "dashboard", "batch")

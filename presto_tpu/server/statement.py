@@ -235,6 +235,8 @@ class StatementServer:
     # -- lifecycle ------------------------------------------------------
 
     def start(self):
+        from ..utils.compile_cache import setup_compile_cache
+        setup_compile_cache()
         from ..connectors.system import register_statement_server
         register_statement_server(self)  # system.queries introspection
         self._thread = threading.Thread(target=self._httpd.serve_forever,

@@ -1385,6 +1385,8 @@ class TpuWorkerServer:
                 shared_secret=shared_secret)
 
     def start(self):
+        from ..utils.compile_cache import setup_compile_cache
+        setup_compile_cache()
         self._thread = threading.Thread(target=self.httpd.serve_forever,
                                         daemon=True)
         self._thread.start()

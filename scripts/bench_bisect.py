@@ -7,7 +7,7 @@ Times the SAME staged data through three q1 kernel variants at HEAD:
              representation, exactness waived)
   f64acc     sums -> double (pure float64 accumulate, lower bound)
 
-Run with scripts/_cpu.py armor (relay may be down):
+Runs on the CPU (it imports scripts/_cpu.py):
     python scripts/bench_bisect.py [sf] [iters]
 """
 

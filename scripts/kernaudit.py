@@ -18,9 +18,8 @@ on the source line an eqn traces to), and baseline policy
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")  # the gate only traces
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import _cpu  # noqa: E402,F401  (the gate only traces: the shared CPU armor)
 
 from presto_tpu.audit.cli import main  # noqa: E402
 

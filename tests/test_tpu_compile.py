@@ -1,0 +1,183 @@
+"""The chip's compiler, without the chip: what the smoke runs must compile.
+
+Section 2 of the on-chip-measurement guide: the TPU compiler installed
+here compiles for a DESCRIBED v5e:2x2. These tests hand it the Pallas
+kernels and the whole statement programs chip_smoke.py sends, at the
+shapes TPC-H SF1 produces, with x64 on as in production and the backend
+question (`ops.device.on_tpu`) steered to its TPU answer. A compile
+that passes is not a chip run -- it says Mosaic and XLA:TPU accept the
+program, nothing about results or time.
+
+Everything that loads the TPU's library happens inside the module
+fixture, in this process, after a test of this file has started:
+xdist gives the file to one worker and only that worker takes
+libtpu's lock.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import presto_tpu  # noqa: F401  (x64 on before any array exists)
+from presto_tpu.ops import device
+from presto_tpu.ops import pallas_kernels as pk
+
+SF = 1.0
+LINEITEM_ROWS = 6_000_000  # this generator's SF1 lineitem (4 per order)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on device 0 of a described v5e:2x2, with
+    the persistent compile cache off for the module: a compile for a
+    described chip is written to it but cannot be read back without
+    one, and the next run would warn."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def traced_for_tpu(monkeypatch):
+    """Trace-time branches read ops.device.on_tpu(), which says False
+    here; steer it in the test, never through an option of the program."""
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# -- the Pallas kernels alone ---------------------------------------------
+
+# (limb lane dtype, L, MXU operand dtype): what the SQL-text q1 hands
+# limb_partial_sums at SF1 (test_q1_program_... re-derives and pins it)
+Q1_LIMB_FORMS = {"bf16": (jnp.int16, 34, jnp.bfloat16),
+                 "f32": (jnp.float32, 19, jnp.float32)}
+
+
+@pytest.mark.parametrize("form", sorted(Q1_LIMB_FORMS))
+def test_limb_partial_sums_compiles_at_q1_sf1_shapes(one_chip, form):
+    lane, L, compute = Q1_LIMB_FORMS[form]
+    fn = jax.jit(lambda i, l: pk.limb_partial_sums(
+        i, l, 16, interpret=False, compute_dtype=compute))
+    compiled = fn.lower(_shape((LINEITEM_ROWS,), jnp.int32, one_chip),
+                        _shape((LINEITEM_ROWS, L), lane, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# the string columns TPC-H q9/q13/q16/q20 search, at SF1 row counts
+LIKE_COLUMNS = [("part", "name", 200_000), ("part", "type", 200_000),
+                ("orders", "comment", 1_500_000),
+                ("supplier", "comment", 10_000)]
+
+
+@pytest.mark.parametrize("table,column,rows", LIKE_COLUMNS)
+def test_contains_bytes_compiles_at_tpch_widths(one_chip, table, column,
+                                                rows):
+    from presto_tpu.connectors import tpch
+    width = tpch.column_type(table, column).max_length
+    fn = jax.jit(lambda c, l: pk.contains_bytes(c, l, b"sleep",
+                                                interpret=False))
+    compiled = fn.lower(_shape((rows, width), jnp.uint8, one_chip),
+                        _shape((rows,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lex_sort_compiles_as_one_single_key_sort(one_chip, traced_for_tpu):
+    """The pass-per-word form exists to bound the compile: 7 key words
+    over 64Ki rows as ONE multi-key sort did not compile in ten minutes
+    (test_q3_join_program_compiles is the real-size guard; this one
+    pins the form just above the row threshold, where it is cheap)."""
+    from presto_tpu.ops.keys import _ONE_SORT_MAX_ROWS, lex_sort
+    rows = 2 * _ONE_SORT_MAX_ROWS
+    fn = jax.jit(lambda *ops: lex_sort(ops, num_keys=7))
+    text = fn.lower(*[_shape((rows,), jnp.uint64, one_chip)] * 7,
+                    _shape((rows,), jnp.int32, one_chip)).compile().as_text()
+    assert "while" in text  # the scan over key words
+
+
+# -- whole statement programs, as the SQL front door builds them ----------
+
+def _program(text, sharding):
+    """(fn, abstract scan batches): the CompiledPlan.fn the front door
+    builds for `text` at SF1 with the server's defaults (no hand hints),
+    and its scan batches as shapes -- dtypes and string widths from a
+    64-row staged slice, rows from the table's SF1 capacity."""
+    from presto_tpu.connectors import catalog
+    from presto_tpu.exec.planner import compile_plan
+    from presto_tpu.exec.regions import partition_regions
+    from presto_tpu.exec.runner import _scan_batch, prepare_plan
+    from presto_tpu.sql import plan_sql
+    root = prepare_plan(plan_sql(text), sf=SF)
+    assert len(partition_regions(root, session=None, sf=SF,
+                                 mesh=None).regions) == 1
+    plan = compile_plan(root, None, 1 << 16)
+    batches = []
+    for node in plan.scan_nodes:
+        rows = catalog(node.connector).table_row_count(node.table, SF)
+        cap = -(-rows // 8) * 8
+        tiny = _scan_batch(node, SF, None, 8, scan_range=(0, 64))
+        batches.append(jax.tree.map(
+            lambda x: _shape((cap,) + x.shape[1:], x.dtype, sharding), tiny))
+    return plan.fn, tuple(batches)
+
+
+def _compile(text, sharding):
+    fn, batches = _program(text, sharding)
+    compiled = jax.jit(lambda b: fn(b)).lower(batches).compile()
+    mem = compiled.memory_analysis()
+    print(f"args {mem.argument_size_in_bytes / 1e6:.1f} MB, "
+          f"temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    # one program's arguments and scratch must fit the 16 GB chip
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    return compiled.as_text()
+
+
+def test_q1_program_compiles_with_the_pallas_kernel(one_chip, traced_for_tpu,
+                                                    monkeypatch):
+    from presto_tpu.ops.aggregation import last_smallg_form
+    from presto_tpu.queries.tpch_sql import tpch_query
+    seen = []
+    real = pk.limb_partial_sums
+
+    def spy(ids, limbs, groups, **kw):
+        seen.append((limbs.shape, limbs.dtype, groups, kw["interpret"],
+                     kw["compute_dtype"]))
+        return real(ids, limbs, groups, **kw)
+
+    monkeypatch.setattr(pk, "limb_partial_sums", spy)
+    text = _compile(tpch_query(1).text, one_chip)
+    assert "tpu_custom_call" in text
+    assert last_smallg_form() == "pallas-bf16"
+    lane, L, compute = Q1_LIMB_FORMS["bf16"]
+    assert seen == [((LINEITEM_ROWS, L), lane, 16, False, compute)]
+
+
+def test_q6_program_compiles(one_chip, traced_for_tpu):
+    from presto_tpu.queries.tpch_sql import tpch_query
+    _compile(tpch_query(6).text, one_chip)
+
+
+def test_q3_join_program_compiles(one_chip, traced_for_tpu):
+    from presto_tpu.queries.tpch_sql import tpch_query
+    _compile(tpch_query(3).text, one_chip)
+
+
+def test_like_count_compiles_with_the_pallas_kernel(one_chip,
+                                                    traced_for_tpu):
+    assert "tpu_custom_call" in _compile(
+        "SELECT count(*) FROM part WHERE name LIKE '%sleep%'", one_chip)
