@@ -232,8 +232,8 @@ def run_one_chip(args, devices, on_tpu: bool) -> bool:
 def run_four_chips(args, devices) -> bool:
     """ONLY the path across chips and what it is compared with: q3 and
     q1 as one SPMD program over a 4-device mesh vs one device."""
-    import presto_tpu
     from presto_tpu.parallel import make_mesh
+    from presto_tpu.sql import sql
     if len(devices) < 4:
         raise SystemExit(f"--chips 4 needs 4 devices, JAX has "
                          f"{len(devices)}")
@@ -244,7 +244,7 @@ def run_four_chips(args, devices) -> bool:
         for label, m in (("mesh", mesh), ("one_device", None)):
             for temp in ("cold", "warm"):
                 t0 = time.time()
-                res = presto_tpu.sql(text, sf=args.sf, mesh=m)
+                res = sql(text, sf=args.sf, mesh=m)
                 line[f"{label}_{temp}_wall_s"] = round(time.time() - t0, 3)
             line[label] = res.rows()
             if m is not None:

@@ -39,13 +39,6 @@ _jax.config.update("jax_enable_x64", True)
 __version__ = "0.1.0"
 
 
-def sql(query_text, **kwargs):
-    """Top-level convenience: run SQL against the built-in catalogs.
-    See presto_tpu.sql.sql for parameters."""
-    from .sql import sql as _sql
-    return _sql(query_text, **kwargs)
-
-
 def connect(**kwargs):
     """PEP-249 connection (presto_tpu.dbapi.connect)."""
     from . import dbapi

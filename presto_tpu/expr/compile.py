@@ -134,12 +134,6 @@ def _like(a: StringColumn, pattern: str) -> jnp.ndarray:
         if pat == b"":
             return lengths == 0
         return jnp.ones(n, dtype=bool)
-    if len(segments) == 1 and not anchored_left and not anchored_right \
-            and b"_" not in segments[0]:
-        # plain '%needle%': the substring search, which has a Pallas
-        # form on TPU (ops/pallas_kernels.contains_bytes)
-        from .functions import contains_pattern
-        return contains_pattern(a, segments[0])
 
     def seg_match_windows(seg: bytes):
         """(N, windows) bool: seg matches at window start i ('_' = any)."""

@@ -690,30 +690,6 @@ def _trim(ret, a: StringColumn):
     return StringColumn(out, ln, a.nulls, ret)
 
 
-def contains_pattern(a: StringColumn, needle: bytes):
-    """Vectorized substring search (LIKE '%needle%'). Traced for a TPU
-    this is the compiled Pallas VMEM-tiled kernel
-    (ops/pallas_kernels.py); every other backend gets the XLA form
-    below, which materializes the window gather."""
-    L = max(len(needle), 1)
-    n, w = a.chars.shape
-    if L > w:
-        return jnp.zeros(n, dtype=bool)
-    from ..ops import device
-    if device.on_tpu():
-        from ..ops.pallas_kernels import contains_bytes
-        return contains_bytes(a.chars, a.lengths, needle, interpret=False)
-    pat = jnp.asarray(bytearray(needle), dtype=jnp.uint8)
-    windows = w - L + 1
-    idx = (jnp.arange(windows, dtype=jnp.int32)[:, None]
-           + jnp.arange(L, dtype=jnp.int32)[None, :])  # (windows, L)
-    g = a.chars[:, idx]  # (N, windows, L)
-    match = jnp.all(g == pat[None, None, :], axis=2)  # (N, windows)
-    # window must end within the string
-    ok = (jnp.arange(windows, dtype=jnp.int32)[None, :] + L) <= a.lengths[:, None]
-    return jnp.any(match & ok, axis=1)
-
-
 @register("starts_with")
 def _starts_with(ret, a: StringColumn, b: StringColumn):
     # compare b against a's head; pad a if the needle is wider

@@ -1,10 +1,9 @@
 """THE question the engine asks the device, asked in one place.
 
 Every trace-time choice of a TPU-only form -- the scatter-free small-G
-kernels and bf16 MXU operands (ops/aggregation.py), the Pallas kernels
-(ops/pallas_kernels.py via aggregation and expr/functions), the
-pass-per-word sort (ops/keys.lex_sort) -- reads `on_tpu()`, so two call
-sites can never disagree about the device. Call it as
+kernels, bf16 MXU operands and the Pallas kernel they reach
+(ops/aggregation.py, ops/pallas_kernels.py) -- reads `on_tpu()`, so two
+call sites can never disagree about the device. Call it as
 `device.on_tpu()` (module attribute), so a test that steers a CPU trace
 down the TPU branch patches one name.
 """

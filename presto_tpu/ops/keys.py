@@ -32,41 +32,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..block import Block, Column, DictionaryColumn, Int128Column, StringColumn
-from . import device
 
 _SIGN = np.uint64(1 << 63)
 
 __all__ = ["key_words", "num_key_words", "lex_sort"]
-
-# The TPU compiler's cost for ONE lax.sort grows roughly with the square
-# of the 32-bit words its comparator reads, once the rows outgrow the
-# small-array sorter: on the v5e host a 1-key uint64 stable sort of 64Ki
-# rows compiles in 33 s, 2 keys in 66 s, 4 keys in ~300 s (sandbox
-# compile), and TPC-H q3's 7-word group-by sort did not finish in 10
-# minutes. Up to this many rows a multi-key sort still compiles in
-# seconds (7 keys x 2048 rows: 3.8 s).
-_ONE_SORT_MAX_ROWS = 2048
-
 
 def lex_sort(operands: Sequence[jnp.ndarray], num_keys: int,
              is_stable: bool = False) -> List[jnp.ndarray]:
     """`jax.lax.sort(operands, num_keys=...)` for key WORDS (uint64),
     same contract, same result up to the order of fully equal keys.
 
-    Traced for a TPU with more than one key word over more than
-    `_ONE_SORT_MAX_ROWS` rows it runs as least-significant-word-first
-    passes of ONE single-key stable sort inside a `lax.scan` (the
-    textbook LSD construction: a stable sort by the last word, then the
-    one before, ... is the lexicographic stable sort). The compiler
-    then sees one small comparator whatever the key width -- 49 s for
-    7 words x 64Ki rows where the single sort took over ten minutes --
-    at the price of one pass per word at run time. Every other backend
-    keeps the single multi-key sort."""
+    More than one key word runs as least-significant-word-first passes
+    of ONE single-key stable sort inside a `lax.scan` (the textbook LSD
+    construction: a stable sort by the last word, then the one before,
+    ... is the lexicographic stable sort), so the compiler sees one
+    small comparator whatever the key width. XLA:TPU's cost for ONE
+    multi-key sort grows roughly with the square of the 32-bit words
+    its comparator reads: on the v5e host a 1-key uint64 stable sort of
+    64Ki rows compiles in 33 s, 2 keys in 66 s, 4 keys in ~300 s, and
+    TPC-H q3's 7-word group-by sort did not finish in 10 minutes, where
+    this form took 49 s. The price is one pass per word at run time."""
     operands = list(operands)
-    n = operands[0].shape[0]
-    if not (device.on_tpu() and num_keys > 1 and n > _ONE_SORT_MAX_ROWS):
-        return list(jax.lax.sort(operands, num_keys=num_keys,
-                                 is_stable=is_stable))
+    if num_keys == 1:
+        return list(jax.lax.sort(operands, num_keys=1, is_stable=is_stable))
     assert all(k.dtype == jnp.uint64 for k in operands[:num_keys]), \
         "lex_sort keys are uint64 key words"
 
@@ -75,7 +63,13 @@ def lex_sort(operands: Sequence[jnp.ndarray], num_keys: int,
                                is_stable=True)
         return perm, None
 
-    perm, _ = jax.lax.scan(one_pass, jnp.arange(n, dtype=jnp.int32),
+    # the identity permutation, seeded from an operand so that inside a
+    # shard_map the carry varies over the same mesh axes going in as
+    # coming out
+    n = operands[0].shape[0]
+    identity = (jnp.zeros_like(operands[0], dtype=jnp.int32)
+                + jnp.arange(n, dtype=jnp.int32))
+    perm, _ = jax.lax.scan(one_pass, identity,
                            jnp.stack(operands[:num_keys])[::-1])
     return [o[perm] for o in operands]
 

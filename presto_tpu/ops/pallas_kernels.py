@@ -1,17 +1,13 @@
-"""Pallas TPU kernels for the hot ops XLA alone stages through HBM.
-
-Reference surface: the tight per-row loops the reference compiles to
-JVM bytecode/Velox SIMD for LIKE and substring search
-(operator/scalar/StringFunctions.java, LikeFunctions), and the
+"""Pallas TPU kernel for the hot op XLA alone stages through HBM: the
 grouped-sum inner loop of the small-table aggregation.
 
-The engine calls these only when `ops.aggregation.on_tpu()` says the
-program is being traced for the chip, and always compiled
-(`interpret=False`): a kernel Mosaic refuses fails the query, it never
-falls back. `interpret=True` exists for the CPU tests of the kernels'
-numerics and nothing else. Both kernels are independent of the x64
-switch the package turns on (32-bit index maps, 32-bit lanes inside),
-and tests/test_tpu_compile.py compiles them for a described v5e at the
+The engine calls it only when `ops.device.on_tpu()` says the program
+is being traced for the chip, and always compiled (`interpret=False`):
+a kernel Mosaic refuses fails the query, it never falls back.
+`interpret=True` exists for the CPU tests of the kernel's numerics and
+nothing else. The kernel is independent of the x64 switch the package
+turns on (32-bit index maps, 32-bit lanes inside), and
+tests/test_tpu_compile.py compiles it for a described v5e at the
 shapes TPC-H SF1 produces.
 """
 
@@ -24,66 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["contains_bytes", "limb_partial_sums"]
+__all__ = ["limb_partial_sums"]
 
 # BlockSpec index maps must return 32-bit values: under x64 a Python 0
 # traces as i64 and Mosaic cannot legalize the map's func.return
 _Z = np.int32(0)
-
-_TILE = 1024
-
-
-def _contains_kernel(chars_ref, lengths_ref, out_ref, *, pattern: tuple):
-    """One tile of rows, rows on LANES: chars (W, TILE) uint8, lengths
-    and the 0/1 result (1, TILE) int32. Window starts run along the
-    sublanes, so the k-th pattern byte is a static sublane-offset slice
-    and the any-window reduction leaves a lane-dense row."""
-    c = chars_ref[:].astype(jnp.int32)
-    w, tile = c.shape
-    L = len(pattern)
-    windows = w - L + 1
-    m = c[0:windows, :] == pattern[0]
-    for k in range(1, L):
-        m = m & (c[k:k + windows, :] == pattern[k])
-    start = jax.lax.broadcasted_iota(jnp.int32, (windows, tile), 0)
-    m = m & ((start + L) <= lengths_ref[0])  # window ends inside the row
-    out_ref[0] = jnp.max(m.astype(jnp.int32), axis=0, keepdims=True)
-
-
-def contains_bytes(chars: jax.Array, lengths: jax.Array, needle: bytes,
-                   *, interpret: bool) -> jax.Array:
-    """(N,) bool: needle appears within the first lengths[i] bytes of
-    row i. The pattern is baked into the kernel (LIKE patterns are plan
-    constants). The (N, W) column is transposed once by XLA so the
-    kernel sees rows on lanes; N pads to the tile."""
-    n, w = chars.shape
-    if not needle:  # every string contains the empty string
-        return jnp.ones(n, dtype=bool)
-    if len(needle) > w:
-        return jnp.zeros(n, dtype=bool)
-    pad = (-n) % _TILE
-    if pad:
-        chars = jnp.pad(chars, ((0, pad), (0, 0)))
-        lengths = jnp.pad(lengths, (0, pad))
-    total = chars.shape[0]
-    tiles = total // _TILE
-    kernel = functools.partial(_contains_kernel,
-                               pattern=tuple(bytearray(needle)))
-    out = pl.pallas_call(
-        kernel,
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec((w, _TILE), lambda i: (_Z, i)),
-                  pl.BlockSpec((1, 1, _TILE), lambda i: (i, _Z, _Z))],
-        out_specs=pl.BlockSpec((1, 1, _TILE), lambda i: (i, _Z, _Z)),
-        out_shape=jax.ShapeDtypeStruct((tiles, 1, _TILE), jnp.int32),
-        interpret=interpret,
-    )(chars.T, lengths.astype(jnp.int32).reshape(tiles, 1, _TILE))
-    return out.reshape(total)[:n] != 0
-
-
-# ---------------------------------------------------------------------------
-# Fused limb-sum group-by partials (the small-table aggregation hot op)
-# ---------------------------------------------------------------------------
 
 _SUM_TILE = 1024
 

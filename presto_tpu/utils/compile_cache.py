@@ -22,14 +22,15 @@ _DEFAULT_DIR = os.path.join(
 
 def setup_compile_cache() -> str:
     """Idempotent. Returns the directory the cache lives in."""
+    import jax
+    # every program is worth keeping wherever the cache lives: the
+    # engine's cost on a cold start is almost entirely compiles, small
+    # ones included. These are thresholds, not a directory.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
     os.makedirs(_DEFAULT_DIR, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
-    # every program is worth keeping: the engine's cost on a cold
-    # start is almost entirely compiles, small ones included
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return _DEFAULT_DIR

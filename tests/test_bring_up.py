@@ -13,8 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import presto_tpu
-from presto_tpu.ops import device
+import presto_tpu  # noqa: F401  (x64 on before any array exists)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -42,14 +41,11 @@ def test_every_tpu_branch_asks_the_one_helper():
     assert hits == ["ops/device.py"]
 
 
-def test_interpret_is_a_required_keyword_of_both_kernels():
+def test_interpret_is_a_required_keyword_of_the_kernel():
     from presto_tpu.ops import pallas_kernels as pk
     with pytest.raises(TypeError):
         pk.limb_partial_sums(jnp.zeros(8, jnp.int32),
                              jnp.zeros((8, 2), jnp.float32), 4)
-    with pytest.raises(TypeError):
-        pk.contains_bytes(jnp.zeros((8, 4), jnp.uint8),
-                          jnp.zeros(8, jnp.int32), b"x")
 
 
 @pytest.mark.parametrize("compute", ["bf16", "f32"])
@@ -69,43 +65,40 @@ def test_limb_partial_sums_forms_agree_with_numpy(compute):
     assert (np.asarray(parts).astype(np.int64).sum(axis=0) == want).all()
 
 
-def test_like_substring_routes_through_contains_pattern(monkeypatch):
-    """`LIKE '%x%'` is the substring search (the op with a Pallas form
-    on TPU); anchored / wildcard patterns keep the general matcher."""
-    from presto_tpu.expr import functions
-    seen = []
-    real = functions.contains_pattern
-    monkeypatch.setattr(functions, "contains_pattern",
-                        lambda a, needle: seen.append(needle) or
-                        real(a, needle))
-    q = "SELECT count(*) FROM part WHERE name LIKE '{}'"
-    n_sub = presto_tpu.sql(q.format("%sleep%"), sf=0.01).rows()[0][0]
-    assert seen == [b"sleep"] and n_sub > 0
-    presto_tpu.sql(q.format("sleep%"), sf=0.01)
-    presto_tpu.sql(q.format("%sle_p%"), sf=0.01)
-    assert seen == [b"sleep"]
-
-
-def test_front_door_function_answers_every_call():
-    """`presto_tpu.sql(text)` used to work exactly once per process:
-    importing the subpackage rebound the attribute to the module."""
+@pytest.mark.parametrize("pattern,matcher", [
+    ("%sleep%", lambda s: "sleep" in s),
+    ("sleep%", lambda s: s.startswith("sleep")),
+    ("%sle_p%", lambda s: any(s[i:i + 3] == "sle" and s[i + 4:i + 5] == "p"
+                              for i in range(len(s)))),
+])
+def test_like_through_the_front_door_equals_python(pattern, matcher):
+    """One LIKE path (`expr/compile._like`) for every pattern shape,
+    called twice through the one front door `presto_tpu.sql.sql`."""
+    from presto_tpu.connectors import tpch
+    from presto_tpu.sql import sql
+    names = tpch.generate_columns("part", 0.01, ["name"])["name"]
+    want = sum(matcher(s) for s in names)
+    q = f"SELECT count(*) FROM part WHERE name LIKE '{pattern}'"
     for _ in range(2):
-        assert presto_tpu.sql("SELECT count(*) FROM region",
-                              sf=0.01).rows() == [(5,)]
-    assert callable(presto_tpu.sql.plan_sql)
+        assert sql(q, sf=0.01).rows() == [(want,)]
 
 
 # -- the pass-per-word sort -------------------------------------------------
 
+def _key_operands(n, num_keys, seed=4):
+    rng = np.random.default_rng(seed)
+    return [jnp.asarray(rng.integers(0, 3, n).astype(np.uint64))
+            for _ in range(num_keys)] + [jnp.arange(n, dtype=jnp.int32)]
+
+
 @pytest.mark.parametrize("stable", [True, False])
-def test_lex_sort_tpu_form_equals_the_single_sort(monkeypatch, stable):
+@pytest.mark.parametrize("n", [64, 4096])
+def test_lex_sort_passes_equal_the_single_sort(n, stable):
+    """One path on every backend and at every size: one single-key
+    stable sort inside a scan over the key words."""
     from presto_tpu.ops import keys
-    rng = np.random.default_rng(4)
-    n = 2 * keys._ONE_SORT_MAX_ROWS
-    ops = [jnp.asarray(rng.integers(0, 3, n).astype(np.uint64))
-           for _ in range(3)] + [jnp.arange(n, dtype=jnp.int32)]
+    ops = _key_operands(n, 3)
     want = jax.lax.sort(ops, num_keys=3, is_stable=True)
-    monkeypatch.setattr(device, "on_tpu", lambda: True)
     jaxpr = str(jax.make_jaxpr(
         lambda *o: keys.lex_sort(o, num_keys=3, is_stable=stable))(*ops))
     assert "scan" in jaxpr and jaxpr.count("sort[") == 1
@@ -114,27 +107,44 @@ def test_lex_sort_tpu_form_equals_the_single_sort(monkeypatch, stable):
         assert (np.asarray(g) == np.asarray(w)).all()
 
 
-def test_lex_sort_small_or_single_key_stays_one_sort(monkeypatch):
+def test_lex_sort_traces_inside_a_checked_shard_map():
+    """The scan's carry must vary over the mesh axes going in as it
+    does coming out: a default (check_vma=True) shard_map refuses a
+    carry seeded from a plain arange. The engine's own shard_maps set
+    check_vma=False and could not see that."""
+    from jax.sharding import PartitionSpec as P
     from presto_tpu.ops import keys
-    monkeypatch.setattr(device, "on_tpu", lambda: True)
-    small = [jnp.zeros(64, jnp.uint64)] * 3 + [jnp.arange(64)]
-    big1 = [jnp.zeros(1 << 13, jnp.uint64), jnp.arange(1 << 13)]
-    for ops, nk in ((small, 3), (big1, 1)):
-        jaxpr = str(jax.make_jaxpr(
-            lambda *o: keys.lex_sort(o, num_keys=nk))(*ops))
-        assert "scan" not in jaxpr
+    from presto_tpu.parallel import make_mesh
+    n, shards = 4096, 4
+    ops = _key_operands(n, 3)
+    got = jax.jit(jax.shard_map(
+        lambda *o: tuple(keys.lex_sort(o, num_keys=3)),
+        mesh=make_mesh(shards), in_specs=P("workers"),
+        out_specs=P("workers")))(*ops)
+    got = [np.asarray(g) for g in got]
+    for i in range(shards):
+        sl = slice(i * n // shards, (i + 1) * n // shards)
+        want = jax.lax.sort([np.asarray(o)[sl] for o in ops], num_keys=3,
+                            is_stable=True)
+        for g, w in zip(got, want):
+            assert (g[sl] == np.asarray(w)).all()
 
 
 # -- the compile cache ------------------------------------------------------
 
-def test_compile_cache_env_placement_sets_nothing(monkeypatch, tmp_path):
+def test_compile_cache_env_placement_sets_no_directory(monkeypatch,
+                                                       tmp_path):
+    """The environment places the cache, the code sets no other
+    directory -- but keeps every program there too: the thresholds are
+    the same wherever the cache lives."""
     from presto_tpu.utils import compile_cache
-    calls = []
+    calls = {}
     monkeypatch.setattr(jax.config, "update",
-                        lambda *a: calls.append(a))
+                        lambda name, value: calls.update({name: value}))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.setup_compile_cache() == str(tmp_path)
-    assert calls == []
+    assert calls == {"jax_persistent_cache_min_compile_time_secs": 0.0,
+                     "jax_persistent_cache_min_entry_size_bytes": 0}
 
 
 def test_compile_cache_default_is_a_fixed_path_in_the_checkout(monkeypatch):
@@ -143,6 +153,8 @@ def test_compile_cache_default_is_a_fixed_path_in_the_checkout(monkeypatch):
     want = os.path.join(REPO, ".cache", "jax")
     assert compile_cache.setup_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
 
 
 # -- entry scripts: the chip or nothing -------------------------------------

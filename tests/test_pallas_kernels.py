@@ -1,50 +1,3 @@
-import numpy as np
-import pytest
-
-from presto_tpu import types as T
-from presto_tpu.block import from_numpy
-from presto_tpu.expr.functions import contains_pattern
-from presto_tpu.ops.pallas_kernels import contains_bytes
-
-
-def make_col(strings, width=None):
-    col = from_numpy(T.varchar(width or 32),
-                     np.array(strings, dtype=object))
-    return col
-
-
-@pytest.mark.parametrize("needle", [b"PROMO", b"x", b"special requests"])
-def test_contains_matches_reference_impl(needle):
-    rng = np.random.default_rng(5)
-    words = ["PROMO BRUSHED TIN", "STANDARD POLISHED", "xylophone",
-             "the special requests sleep", "", "PROM", "special request",
-             "aaaaaaaaaaaaaaaaaaaaaaaaaaaaaa"]
-    strings = [words[i] for i in rng.integers(0, len(words), 700)]
-    col = make_col(strings)
-    got = np.asarray(contains_bytes(col.chars, col.lengths, needle,
-                                    interpret=True))
-    want = np.asarray(contains_pattern(col, needle))
-    np.testing.assert_array_equal(got, want)
-    # python oracle
-    py = np.array([needle.decode() in s for s in strings])
-    np.testing.assert_array_equal(got, py)
-
-
-def test_contains_needle_wider_than_column():
-    col = make_col(["abc", "defg"])
-    got = np.asarray(contains_bytes(col.chars, col.lengths, b"x" * 64,
-                                    interpret=True))
-    assert not got.any()
-
-
-def test_contains_interior_nul_and_lengths():
-    # bytes past lengths must not match
-    col = make_col(["PROMO", "PRO"])
-    got = np.asarray(contains_bytes(col.chars, col.lengths, b"PROMO",
-                                    interpret=True))
-    assert list(got) == [True, False]
-
-
 def test_limb_partial_sums_matches_oracle_and_einsum_form(monkeypatch):
     """The fused Pallas group-sum partials (interpret mode off-TPU)
     must equal both a numpy oracle and the XLA einsum form's totals."""

@@ -2,7 +2,7 @@
 
 Section 2 of the on-chip-measurement guide: the TPU compiler installed
 here compiles for a DESCRIBED v5e:2x2. These tests hand it the Pallas
-kernels and the whole statement programs chip_smoke.py sends, at the
+kernel and the whole statement programs chip_smoke.py sends, at the
 shapes TPC-H SF1 produces, with x64 on as in production and the backend
 question (`ops.device.on_tpu`) steered to its TPU answer. A compile
 that passes is not a chip run -- it says Mosaic and XLA:TPU accept the
@@ -79,31 +79,13 @@ def test_limb_partial_sums_compiles_at_q1_sf1_shapes(one_chip, form):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# the string columns TPC-H q9/q13/q16/q20 search, at SF1 row counts
-LIKE_COLUMNS = [("part", "name", 200_000), ("part", "type", 200_000),
-                ("orders", "comment", 1_500_000),
-                ("supplier", "comment", 10_000)]
-
-
-@pytest.mark.parametrize("table,column,rows", LIKE_COLUMNS)
-def test_contains_bytes_compiles_at_tpch_widths(one_chip, table, column,
-                                                rows):
-    from presto_tpu.connectors import tpch
-    width = tpch.column_type(table, column).max_length
-    fn = jax.jit(lambda c, l: pk.contains_bytes(c, l, b"sleep",
-                                                interpret=False))
-    compiled = fn.lower(_shape((rows, width), jnp.uint8, one_chip),
-                        _shape((rows,), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_lex_sort_compiles_as_one_single_key_sort(one_chip, traced_for_tpu):
+def test_lex_sort_compiles_as_one_single_key_sort(one_chip):
     """The pass-per-word form exists to bound the compile: 7 key words
     over 64Ki rows as ONE multi-key sort did not compile in ten minutes
     (test_q3_join_program_compiles is the real-size guard; this one
-    pins the form just above the row threshold, where it is cheap)."""
-    from presto_tpu.ops.keys import _ONE_SORT_MAX_ROWS, lex_sort
-    rows = 2 * _ONE_SORT_MAX_ROWS
+    pins the form at a size where it is cheap)."""
+    from presto_tpu.ops.keys import lex_sort
+    rows = 4096
     fn = jax.jit(lambda *ops: lex_sort(ops, num_keys=7))
     text = fn.lower(*[_shape((rows,), jnp.uint64, one_chip)] * 7,
                     _shape((rows,), jnp.int32, one_chip)).compile().as_text()
@@ -177,7 +159,19 @@ def test_q3_join_program_compiles(one_chip, traced_for_tpu):
     _compile(tpch_query(3).text, one_chip)
 
 
-def test_like_count_compiles_with_the_pallas_kernel(one_chip,
-                                                    traced_for_tpu):
-    assert "tpu_custom_call" in _compile(
-        "SELECT count(*) FROM part WHERE name LIKE '%sleep%'", one_chip)
+# the string columns TPC-H q9/q13/q16/q20 search, with the patterns
+# those queries' texts use (widths 55, 25, 79, 101 at SF1 row counts)
+LIKE_STATEMENTS = {
+    "part.name": "SELECT count(*) FROM part WHERE name LIKE '%sleep%'",
+    "part.type": "SELECT count(*) FROM part WHERE type LIKE 'PROMO%'",
+    "orders.comment": "SELECT count(*) FROM orders "
+                      "WHERE comment NOT LIKE '%special%requests%'",
+    "supplier.comment": "SELECT count(*) FROM supplier "
+                        "WHERE comment LIKE '%carefully%deposits%'",
+}
+
+
+@pytest.mark.parametrize("column", sorted(LIKE_STATEMENTS))
+def test_like_count_compiles_at_tpch_widths(one_chip, traced_for_tpu,
+                                            column):
+    _compile(LIKE_STATEMENTS[column], one_chip)
