@@ -71,6 +71,7 @@ from ..expr import ir as E
 from ..expr.compile import bound_params
 from ..plan import nodes as N
 from ..utils.locks import OrderedLock
+from .stats import collecting, stage
 
 __all__ = ["BATCHING_ENV", "batching_enabled", "parameterize_plan",
            "BatchingExecutor", "get_batching_executor",
@@ -499,7 +500,11 @@ class BatchingExecutor:
                     catalog: Optional[str] = None):
         """Plan `text`, and when it is batchable and a batch forms,
         execute it batched and return this query's QueryResult. Returns
-        None whenever the normal serial path should run instead."""
+        None whenever the normal serial path should run instead. Past
+        the hot-shape check the call is the statement's ``batch`` stage:
+        ``batch.prepare`` is this path's own plan_sql + prepare_plan
+        (memoized by exact text), ``batch.wait`` the formation window
+        or the wait for a leader."""
         if not batching_enabled(session):
             return None
         hot_min = self._hot_min(session)
@@ -511,11 +516,22 @@ class BatchingExecutor:
             # -- one-off ad-hoc statements cost one regex here, not a
             # second full planning
             return None
+        with stage("batch"):
+            return self._try_batched(
+                text, sf=sf, session=session, query_id=query_id,
+                trace_id=trace_id, max_groups=max_groups,
+                join_capacity=join_capacity, catalog=catalog)
+
+    def _try_batched(self, text: str, *, sf: float, session: Dict,
+                     query_id: str, trace_id, max_groups: Optional[int],
+                     join_capacity: Optional[int],
+                     catalog: Optional[str]):
         try:
-            prepared, template, values, key = self._prepare(
-                text, sf=sf, session=session,
-                max_groups=max_groups, join_capacity=join_capacity,
-                catalog=catalog)
+            with stage("batch.prepare"):
+                prepared, template, values, key = self._prepare(
+                    text, sf=sf, session=session,
+                    max_groups=max_groups, join_capacity=join_capacity,
+                    catalog=catalog)
         except Exception:  # noqa: BLE001 - unparseable/unsupported SQL:
             # the serial path owns producing the real error
             return None
@@ -545,7 +561,9 @@ class BatchingExecutor:
 
         if not leader:
             # follower: the leader executes for us
-            if not entry.event.wait(self.follower_timeout_s):
+            with stage("batch.wait"):
+                led = entry.event.wait(self.follower_timeout_s)
+            if not led:
                 return None  # leader wedged: run serial (duplicate-safe)
             if entry.error is not None:
                 raise entry.error
@@ -557,17 +575,18 @@ class BatchingExecutor:
         # batches execute, this one keeps collecting; max_form_s
         # bounds the wait)
         t_form = time.time()
-        while True:
-            g.full.wait(window_s)
-            with self._lock:
-                if len(g.entries) >= max_batch:
-                    break
-                elapsed = time.time() - t_form
-                if elapsed >= window_s and \
-                        self._inflight.get(key, 0) < self.max_inflight:
-                    break
-                if elapsed >= self.max_form_s:
-                    break
+        with stage("batch.wait"):
+            while True:
+                g.full.wait(window_s)
+                with self._lock:
+                    if len(g.entries) >= max_batch:
+                        break
+                    elapsed = time.time() - t_form
+                    if elapsed >= window_s and \
+                            self._inflight.get(key, 0) < self.max_inflight:
+                        break
+                    if elapsed >= self.max_form_s:
+                        break
         with self._lock:
             g.sealed = True
             if self._forming.get(key) is g:
@@ -983,10 +1002,13 @@ class BatchingExecutor:
         from .runner import run_query
         for m in entries:
             try:
-                m.result = run_query(
-                    m.root, sf=sf, session=m.session,
-                    query_id=m.query_id, prepared=True,
-                    trace_id=m.trace_id)
+                # every member's own collector, not the leader's: this
+                # thread runs them all
+                with collecting(None):
+                    m.result = run_query(
+                        m.root, sf=sf, session=m.session,
+                        query_id=m.query_id, prepared=True,
+                        trace_id=m.trace_id)
             except BaseException as e:  # noqa: BLE001 - deliver to the
                 m.error = e             # member's waiting thread
 

@@ -308,7 +308,10 @@ def record_hop(hop: str, nbytes: int, seconds: float,
 class timed_hop:
     """``with timed_hop("connector_read") as t: ...; t.bytes = n`` --
     records the hop on exit with the measured wall, on the monotonic
-    :func:`now_us` clock the interval ledger shares."""
+    :func:`now_us` clock the interval ledger shares. The interval is
+    also a span of the statement's seam (exec/stats.py): a child of the
+    open stage in the collector's record, ``presto:<hop>`` in the
+    profiler's trace."""
 
     def __init__(self, hop: str, nbytes: int = 0, split_id: int = -1):
         self.hop = hop
@@ -316,11 +319,15 @@ class timed_hop:
         self.split_id = split_id
 
     def __enter__(self):
+        from .stats import span
+        self._span = span(self.hop)
+        self._span.__enter__()
         self.t0_us = now_us()
         return self
 
     def __exit__(self, *exc):
         end = now_us()
+        self._span.__exit__(*exc)
         record_hop(self.hop, self.bytes, (end - self.t0_us) / 1e6,
                    end_us=end, split_id=self.split_id)
         return False

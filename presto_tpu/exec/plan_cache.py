@@ -38,6 +38,7 @@ import numpy as np
 from ..plan import nodes as N
 from ..utils.locks import OrderedLock
 from .planner import CompiledPlan, compile_plan
+from .stats import note
 
 __all__ = ["plan_fingerprint", "cached_compile", "cache_stats",
            "clear_plan_cache", "KERNEL_MODE_ENVS"]
@@ -181,7 +182,9 @@ def cached_compile(root: N.PlanNode, mesh, default_join_capacity: int,
     compiling at most once per (structure, mesh, capacities, scale).
     Join-free plans are capacity-insensitive: their key ignores
     `default_join_capacity`, so fused scan/agg regions never fragment
-    the cache across join-capacity configurations."""
+    the cache across join-capacity configurations. A hit or a miss is
+    counted on the statement that asked (``QueryStats.counters``) as
+    well as on the process."""
     global _hits, _misses
     cap_key = default_join_capacity if _capacity_sensitive(root) else None
     key = (plan_fingerprint(root), _mesh_key(mesh), cap_key,
@@ -191,8 +194,11 @@ def cached_compile(root: N.PlanNode, mesh, default_join_capacity: int,
         if entry is not None:
             _cache.move_to_end(key)
             _hits += 1
-            return entry.plan, entry.fn, entry.call_lock
-        _misses += 1
+        else:
+            _misses += 1
+    note("plan_cache_hits" if entry is not None else "plan_cache_misses")
+    if entry is not None:
+        return entry.plan, entry.fn, entry.call_lock
     # compile outside the cache lock (pure python closure-building, fast;
     # the expensive XLA work happens lazily at first dispatch)
     plan = compile_plan(root, mesh, default_join_capacity,

@@ -75,6 +75,21 @@ def _collect_scans(node: N.PlanNode, out: List[N.PlanNode], _seen=None):
         _collect_scans(s, out, _seen)
 
 
+def _preorder(root: N.PlanNode) -> Dict[int, int]:
+    """id(node) -> its structural pre-order index (a shared subtree
+    counts once, where it is first met): stable across plannings of one
+    SQL text, which `node.id`, a process-wide counter, is not."""
+    index: Dict[int, int] = {}
+
+    def walk(n):
+        if id(n) not in index:
+            index[id(n)] = len(index)
+            for s in n.sources:
+                walk(s)
+    walk(root)
+    return index
+
+
 def compile_plan(root: N.PlanNode, mesh=None,
                  default_join_capacity: int = 1 << 16,
                  exchange_slot_scale: int = 1) -> CompiledPlan:
@@ -82,9 +97,21 @@ def compile_plan(root: N.PlanNode, mesh=None,
     per-destination slot capacity (clamped at the sender's row capacity,
     where overflow is impossible): the runner's overflow->rerun policy
     passes 1, 2, 4, ... until the plan fits -- the memory-feedback
-    analog of the reference's reserve/revoke loop."""
+    analog of the reference's reserve/revoke loop.
+
+    Every device op the program lowers to is named by where it came
+    from: one ``<NodeType>.<k>`` scope per plan node on the path down
+    to the operator that emitted it (`k` the node's pre-order index),
+    then the ``ops/`` function; both follow from the plan's
+    structure, which the plan cache keys, so a hit names its ops as a
+    miss would.
+    Which region of which statement a program ran for is not in its
+    names (a cached program serves many): the ``dispatch`` span around
+    its call says so. Scopes are op metadata: the compiled program does
+    not depend on them."""
     scans: List[N.PlanNode] = []
     _collect_scans(root, scans)
+    order = _preorder(root)
     axis = WORKERS_AXIS
     dist = mesh is not None
 
@@ -99,7 +126,8 @@ def compile_plan(root: N.PlanNode, mesh=None,
         key = id(node)
         if key in _lower_memo:
             return _lower_memo[key]
-        out = _lower(node, inputs)
+        with jax.named_scope(f"{type(node).__name__}.{order[key]}"):
+            out = _lower(node, inputs)
         _lower_memo[key] = out
         return out
 

@@ -27,7 +27,7 @@ from .. import types as T
 from ..block import Batch, batch_from_numpy, to_numpy
 from ..plan import nodes as N
 from .planner import compile_plan
-from .stats import QueryStats, RuntimeStats, StatsCollector, collecting
+from .stats import QueryStats, RuntimeStats, StatsCollector, joining, stage
 
 __all__ = ["run_query", "prepare_plan", "QueryResult"]
 
@@ -95,20 +95,18 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
     connector_read (host column materialization), narrow_cast (the
     staging-time range re-proof), device_put (host -> HBM staging,
     the bytes QueryStats' staging stage counts)."""
-    from .datapath import now_us, record_hop, timed_hop
+    from .datapath import timed_hop
     from .memory import batch_bytes
     phys = getattr(node, "physical_dtypes", None)
     if not phys or not any(phys) or not hasattr(conn, "generate_columns"):
         # the connector stages straight to a device batch: the whole
         # read+put attributes to connector_read (coarse by design --
         # connectors wanting finer hops expose generate_columns)
-        t0 = now_us()
-        b = conn.generate_batch(node.table, sf, node.columns,
-                                start=start, count=count,
-                                capacity=capacity)
-        end = now_us()
-        record_hop("connector_read", batch_bytes(b), (end - t0) / 1e6,
-                   end_us=end)
+        with timed_hop("connector_read") as t_read:
+            b = conn.generate_batch(node.table, sf, node.columns,
+                                    start=start, count=count,
+                                    capacity=capacity)
+            t_read.bytes = batch_bytes(b)
         return b
     from ..plan.widths import checked_physical_dtypes
     with timed_hop("connector_read") as t_read:
@@ -222,15 +220,13 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         # the connector stages straight to device, so the whole
         # read+put attributes to connector_read (the ledger must never
         # show zero bytes for a staged scan)
-        from .datapath import now_us, record_hop
+        from .datapath import timed_hop
         from .memory import batch_bytes
-        t0 = now_us()
-        b = conn.generate_batch(node.table, sf, node.columns,
-                                start=start, count=count, capacity=cap,
-                                predicate=tuple(node.pushdown))
-        end = now_us()
-        record_hop("connector_read", batch_bytes(b), (end - t0) / 1e6,
-                   end_us=end)
+        with timed_hop("connector_read") as t_read:
+            b = conn.generate_batch(node.table, sf, node.columns,
+                                    start=start, count=count, capacity=cap,
+                                    predicate=tuple(node.pushdown))
+            t_read.bytes = batch_bytes(b)
         return b
     return stage_scan_split(conn, node, sf, start, count, cap)
 
@@ -371,7 +367,8 @@ def run_query(root: N.PlanNode, sf: float = 0.01, mesh=None,
     tl = TimelineLedger(query_id=query_id,
                         enabled=timeline_enabled(session))
     try:
-        with _dp_recording(dp), _acc_recording(acc), _tl_recording(tl):
+        with joining(query_id, trace_id) as collector, \
+                _dp_recording(dp), _acc_recording(acc), _tl_recording(tl):
             res = _run_query_inner(
                 root, sf=sf, mesh=mesh, capacity_hints=capacity_hints,
                 default_join_capacity=default_join_capacity,
@@ -379,7 +376,8 @@ def run_query(root: N.PlanNode, sf: float = 0.01, mesh=None,
                 remote_sources=remote_sources, memory_pool=memory_pool,
                 query_id=query_id, session=session,
                 hbm_budget_bytes=hbm_budget_bytes, prepared=prepared,
-                trace_id=trace_id, prog=prog, dp=dp, acc=acc, tl=tl)
+                trace_id=trace_id, prog=prog, dp=dp, acc=acc, tl=tl,
+                collector=collector)
     except BaseException:
         prog.release(state="FAILED")
         raise
@@ -399,7 +397,9 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                      hbm_budget_bytes: Optional[int] = None,
                      prepared: bool = False,
                      trace_id=None, prog=None, dp=None,
-                     acc=None, tl=None) -> QueryResult:
+                     acc=None, tl=None,
+                     collector: Optional[StatsCollector] = None
+                     ) -> QueryResult:
     # write/DDL roots execute their source on device, then write
     # host-side (TableWriterOperator.java:76 analog -- the sink is a
     # host effect, fed by one DMA-out of the computed rows)
@@ -410,29 +410,34 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         acl = get_access_control()
         if acl is not None:
             acl.check_plan(root, (session or {}).get("user", ""))
-        return _run_write_root(
+        res = _run_write_root(
             inner_root, sf=sf, mesh=mesh, capacity_hints=capacity_hints,
             default_join_capacity=default_join_capacity,
             split_rows=split_rows, scan_ranges=scan_ranges,
             remote_sources=remote_sources, memory_pool=memory_pool,
             query_id=query_id, session=session,
             hbm_budget_bytes=hbm_budget_bytes, trace_id=trace_id)
-    if not prepared:
-        root = prepare_plan(root, sf=sf, mesh=mesh, session=session)
-    if prog is not None:
-        prog.advance(stage="plan")
+        # the inner SELECT joined this statement's collector: its
+        # stages and the sink's `write` are one document
+        res.query_stats = collector.stats
+        return res
+    t_query0 = time.time()
+    with stage("plan"):
+        if not prepared:
+            with stage("plan.prepare"):
+                root = prepare_plan(root, sf=sf, mesh=mesh, session=session)
+        if prog is not None:
+            prog.advance(stage="plan")
+        # access control: the analysis-time boundary (AccessControlManager
+        # checkCanSelectFromColumns / write checks) -- enforced on the
+        # plan before anything touches data
+        from ..server.access import get_access_control
+        acl = get_access_control()
+        if acl is not None:
+            acl.check_plan(root, (session or {}).get("user", ""))
     from ..utils.config import session_flag, session_value
     refine = session_flag(session, "stats_capacity_refinement", True)
-    # access control: the analysis-time boundary (AccessControlManager
-    # checkCanSelectFromColumns / write checks) -- enforced on the plan
-    # before anything touches data
-    from ..server.access import get_access_control
-    acl = get_access_control()
-    if acl is not None:
-        acl.check_plan(root, (session or {}).get("user", ""))
     stats = RuntimeStats()
-    collector = StatsCollector(query_id)
-    t_query0 = time.time()
     hbm_budget = hbm_budget_bytes
     if hbm_budget is None and session is not None:
         hbm_budget = session.get("hbm_budget_bytes")
@@ -452,9 +457,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     # the full state table cannot fit the budget: grouped
                     # execution with per-bucket host offload (the
                     # SpillableHashAggregationBuilder path)
-                    with stats.timed("spilled_exec_s"), \
-                            collecting(collector), \
-                            collector.stage("execute"):
+                    with stage("execute"):
                         out_b = run_spilled_agg(
                             root, sf, split_rows, hbm_budget, stats,
                             spill_dir=spill_dir,
@@ -465,8 +468,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                                           root, trace_id, dp=dp,
                                           acc=acc, tl=tl, sf=sf)
                     return res
-            with stats.timed("streaming_exec_s"), collecting(collector), \
-                    collector.stage("execute"):
+            with stage("execute"):
                 r = run_streaming_agg(root, sf, split_rows)
             if bool(np.asarray(r.overflow)):
                 raise RuntimeError("streaming aggregation overflowed "
@@ -500,48 +502,50 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     # profiler demotion) run the general region executor below.
     from .plan_cache import plan_fingerprint
     from .regions import fusion_memory, partition_regions
-    rplan = partition_regions(root, session=session, sf=sf, mesh=mesh)
     from .. import failpoints
-    if failpoints.ARMED and rplan.fused and mesh is None \
-            and len(rplan.regions) == 1 and rplan.regions[0].ops > 1:
-        try:
-            failpoints.hit("fusion.demote")
-        except Exception as e:  # noqa: BLE001 - any injected error class
-            # forced demotion mid-query (chaos/bisection): the fused
-            # span demotes and THIS query already runs materialized
-            fusion_memory().demote(
-                plan_fingerprint(rplan.regions[0].root),
-                f"failpoint ({type(e).__name__})")
-            # the shared demotion counter (both paths) + the forced-
-            # path discriminator, correlated by the flight event reason
-            stats.add("fusion_demotions", 1)
-            stats.add("fusion_forced_demotions", 1)
-            collector.note("fusion_demotions")
-            from ..server.flight_recorder import record_event
-            record_event("fusion_demotion", query_id=query_id,
-                         reason="failpoint")
-            rplan = partition_regions(root, session=session, sf=sf,
-                                      mesh=mesh)
-    multi_region = len(rplan.regions) > 1
-    if multi_region:
-        stats.add("fusion_regions", len(rplan.regions))
-        collector.note("fusion_regions", len(rplan.regions))
-        plan = jfn = call_lock = None
-        fp = None
-        scan_leaves: List[N.PlanNode] = []
-        from .planner import _collect_scans
-        _collect_scans(root, scan_leaves)
-    elif use_cache:
-        plan, jfn, call_lock = _compile_any(root, mesh,
-                                            default_join_capacity, 1, True)
-        root = plan.root  # canonical tree: node ids match plan.scan_nodes
-        fp = plan_fingerprint(root)
-        scan_leaves = plan.scan_nodes
-    else:
-        plan, jfn, call_lock = _compile_any(root, mesh,
-                                            default_join_capacity, 1, False)
-        fp = None
-        scan_leaves = plan.scan_nodes
+    with stage("plan"):
+        rplan = partition_regions(root, session=session, sf=sf, mesh=mesh)
+        if failpoints.ARMED and rplan.fused and mesh is None \
+                and len(rplan.regions) == 1 and rplan.regions[0].ops > 1:
+            try:
+                failpoints.hit("fusion.demote")
+            except Exception as e:  # noqa: BLE001 - any injected class
+                # forced demotion mid-query (chaos/bisection): the fused
+                # span demotes and THIS query already runs materialized
+                fusion_memory().demote(
+                    plan_fingerprint(rplan.regions[0].root),
+                    f"failpoint ({type(e).__name__})")
+                # the shared demotion counter (both paths) + the forced-
+                # path discriminator, correlated by the flight event reason
+                stats.add("fusion_demotions", 1)
+                stats.add("fusion_forced_demotions", 1)
+                collector.note("fusion_demotions")
+                from ..server.flight_recorder import record_event
+                record_event("fusion_demotion", query_id=query_id,
+                             reason="failpoint")
+                rplan = partition_regions(root, session=session, sf=sf,
+                                          mesh=mesh)
+        multi_region = len(rplan.regions) > 1
+        if multi_region:
+            stats.add("fusion_regions", len(rplan.regions))
+            collector.note("fusion_regions", len(rplan.regions))
+            plan = jfn = call_lock = None
+            fp = None
+            scan_leaves: List[N.PlanNode] = []
+            from .planner import _collect_scans
+            _collect_scans(root, scan_leaves)
+        elif use_cache:
+            plan, jfn, call_lock = _compile_any(
+                root, mesh, default_join_capacity, 1, True)
+            # canonical tree: node ids match plan.scan_nodes
+            root = plan.root
+            fp = plan_fingerprint(root)
+            scan_leaves = plan.scan_nodes
+        else:
+            plan, jfn, call_lock = _compile_any(
+                root, mesh, default_join_capacity, 1, False)
+            fp = None
+            scan_leaves = plan.scan_nodes
     # continuous per-kernel profiling (exec/profiler.py): every executed
     # program is attributed by its plan-cache fingerprint -- computed
     # here even for the fragment tier's uncached compiles (scan ranges /
@@ -572,7 +576,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         dyn_on = True if v is None else bool(v)
     if dyn_on and mesh is None:
         from .dynfilter import collect_dynamic_filters
-        with stats.timed("dynamic_filter_collect_s"):
+        with stage("dynfilter"):
             dyn_filters = collect_dynamic_filters(root, sf)
         if dyn_filters:
             stats.add("dynamic_filters", sum(len(v)
@@ -595,7 +599,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
             prog.set_planned(len(scan_leaves))
             prog.advance(stage="staging")
         from .timeline import split_scope
-        with stats.timed("scan_stage_s"), collector.stage("staging"):
+        with stage("staging"):
             batches = []
             for si, s in enumerate(scan_leaves):
                 t_scan0 = time.time()
@@ -704,8 +708,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     if prog is not None:
         prog.advance(stage="execute")
     try:
-        with stats.timed("execute_s"), collecting(collector), \
-                collector.stage("execute"):
+        with stage("execute"):
             if multi_region:
                 # region executor: each pipeline region dispatches as
                 # its own program; boundaries are HBM-resident Batch
@@ -722,7 +725,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                  scale, plan) = _dispatch_ladder(
                     root, plan, jfn, call_lock, batches, mesh,
                     default_join_capacity, use_cache, fp, stats,
-                    adaptive_off, refine, prog)
+                    adaptive_off, refine, prog, rplan.regions[0].tag)
         # XLA compile cost (compile-time captured via jax.monitoring; a
         # plan-cache hit naturally reports zero) + the program's
         # FLOPs / bytes-accessed from cost_analysis, memoized per plan.
@@ -789,7 +792,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                    max(device_s - (compile_us or 0) / 1e6, 0.0))
         if prog is not None:
             prog.advance(stage="fetch")
-        with stats.timed("fetch_s"), collector.stage("fetch"):
+        with stage("fetch"):
             res = _batch_to_result(out, root)
     finally:
         # always drain the per-query peak (success AND failure paths):
@@ -838,7 +841,7 @@ _MAX_CAPACITY_SCALE = 1 << 10
 def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                      mesh, default_join_capacity: int, use_cache: bool,
                      fp: Optional[str], stats, adaptive_off: bool,
-                     refine: bool, prog):
+                     refine: bool, prog, tag: str):
     """The overflow->rerun dispatch loop for ONE compiled program (a
     whole fused plan or a single pipeline region).
 
@@ -850,6 +853,12 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
     reserve/revoke -- here it recompiles with bigger static buckets
     instead. Under the region executor only the overflowing REGION
     re-dispatches; upstream regions' materialized outputs are reused.
+
+    Each turn is two stages of the ambient collector, children of
+    ``execute`` with the region's `tag`: ``dispatch`` (the call of the
+    jitted program until it returns: flatten, jit-cache lookup,
+    trace/lower/compile or cache read on a miss, enqueue) and
+    ``device_wait`` (block_until_ready plus the overflow flag's read).
 
     Returns (out, device_s, dispatch_fn, call_lock, cap_scale, scale,
     plan)."""
@@ -867,25 +876,28 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             1, use_cache)
         stats.add("capacity_feedback_scale", cap_scale)
     from .datapath import now_us as _now_us
+    region = {"region": tag}
     while True:
         t_disp0 = _now_us()
-        if jfn is None:
-            fn = jax.jit(plan.fn)
-            dispatch_fn = fn
-            out, overflow = fn(tuple(batches))
-        else:
-            dispatch_fn = jfn
-            with call_lock:  # serialize trace-time closure state
-                out, overflow = jfn(tuple(batches))
-        jax.block_until_ready(out)
-        # host-observed device occupancy of this dispatch: the
-        # block_until_ready delta around the existing sync point is the
-        # only per-kernel timing one fused program exposes -- on the
-        # monotonic now_us clock the timeline intervals share
-        device_s += (_now_us() - t_disp0) / 1e6
+        with stage("dispatch", region):
+            if jfn is None:
+                fn = jax.jit(plan.fn)
+                dispatch_fn = fn
+                out, overflow = fn(tuple(batches))
+            else:
+                dispatch_fn = jfn
+                with call_lock:  # serialize trace-time closure state
+                    out, overflow = jfn(tuple(batches))
+        with stage("device_wait", region):
+            jax.block_until_ready(out)
+            # host-observed device occupancy of this dispatch: the
+            # block_until_ready delta around the existing sync point is
+            # the only per-kernel timing one fused program exposes -- on
+            # the monotonic now_us clock the timeline intervals share
+            device_s += (_now_us() - t_disp0) / 1e6
+            flags = int(np.asarray(overflow))
         if prog is not None:  # each landed dispatch advances
             prog.advance()
-        flags = int(np.asarray(overflow))
         if flags == 0:
             if cap_scale > 1 and fp:
                 _CAPACITY_FEEDBACK[fp] = cap_scale
@@ -1047,15 +1059,18 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                                      reason=str(e)[:200])
             if prep is not None:
                 from .datapath import now_us as _now_us
+                region = {"region": reg.tag}
                 t_don0 = _now_us()
-                with (call_lock if call_lock is not None
-                      else contextlib.nullcontext()):
-                    out, overflow = prep.dispatch(rbatches)
-                jax.block_until_ready(out)
-                dev_s = (_now_us() - t_don0) / 1e6
+                with stage("dispatch", region):
+                    with (call_lock if call_lock is not None
+                          else contextlib.nullcontext()):
+                        out, overflow = prep.dispatch(rbatches)
+                with stage("device_wait", region):
+                    jax.block_until_ready(out)
+                    dev_s = (_now_us() - t_don0) / 1e6
+                    oflags = int(np.asarray(overflow))
                 if prog is not None:
                     prog.advance()
-                oflags = int(np.asarray(overflow))
                 if oflags:  # unreachable: whitelist admits no overflow op
                     raise RuntimeError(
                         f"donated region {reg.tag} set overflow flags "
@@ -1077,7 +1092,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                     _dispatch_ladder(
                         reg.root, plan, jfn, call_lock, rbatches, None,
                         default_join_capacity, use_cache, rfp, stats,
-                        adaptive_off, refine, prog)
+                        adaptive_off, refine, prog, reg.tag)
             if cost_on and collector is not None and dispatch_fn is not None:
                 # per-region XLA cost analysis: the fused path's FLOPs /
                 # bytes-accessed split, summed region by region so EXPLAIN
@@ -1214,8 +1229,9 @@ def _finalize_query_stats(collector: StatsCollector, res: "QueryResult",
                           root: Optional[N.PlanNode],
                           trace_id=None, dp=None, acc=None, tl=None,
                           sf: float = 0.01) -> None:
-    """Close out the structured stats for one run_query invocation and
-    emit one tracer span per collected stage. `peak_reserved_bytes` is
+    """Close out the structured stats for one run_query invocation (its
+    spans go to the tracer when the collector's owner is done with it:
+    ``stats.joining``). `peak_reserved_bytes` is
     the pool high-water mark the caller already drained. `dp` is the
     invocation's datapath ledger: its hop map rides QueryStats.datapath
     (stitching worker slices through the task-status path) and the
@@ -1287,27 +1303,6 @@ def _finalize_query_stats(collector: StatsCollector, res: "QueryResult",
             qs.accuracy = _acc_merge(qs.accuracy, recs)
             _acc_finalize(collector.query_id, recs)
     res.query_stats = qs
-    # trace_id is either a plain grouping string (legacy) or a
-    # TraceContext carrying (trace id, parent span id): with a context,
-    # stage spans become children of the propagated task/query span so
-    # the distributed trace stitches with valid parent edges
-    from ..server.tracing import TraceContext
-    if isinstance(trace_id, TraceContext):
-        collector.emit_spans(trace_id.trace_id,
-                             parent_id=trace_id.span_id)
-    else:
-        collector.emit_spans(trace_id or collector.query_id)
-    # per-stage latency distributions (/v1/metrics histograms): each
-    # stage's wall feeds the process histogram, exemplar'd with this
-    # query's trace id so a p99 execute spike links to its waterfall
-    from ..server.metrics import observe_histogram
-    tid = trace_id.trace_id if isinstance(trace_id, TraceContext) \
-        else (trace_id or collector.query_id)
-    for name, st in qs.stages.items():
-        if st.wall_us:
-            observe_histogram("presto_tpu_stage_seconds",
-                              st.wall_us / 1e6, labels={"stage": name},
-                              trace_id=tid)
 
 
 def _compile_any(root: N.PlanNode, mesh, default_join_capacity: int,
@@ -1329,7 +1324,10 @@ def _count_result(rows: int, name: str = "rows") -> QueryResult:
 
 
 def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
-    """Execute a DdlNode / TableFinishNode / TableWriterNode root.
+    """Execute a DdlNode / TableFinishNode / TableWriterNode root. The
+    host-side sink, from the inner SELECT's result in hand to the table
+    published, is the statement's ``write`` stage; the inner SELECT's
+    own stages are its siblings, not its children.
 
     Local + mesh tiers run the whole write under one TableFinish
     (staged handle, atomic publish). On the HTTP tier the fragmenter
@@ -1358,14 +1356,15 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
             changed = np.asarray(res.columns[-1]).astype(bool) & \
                 ~np.asarray(res.nulls[-1], dtype=bool)
             affected = int(changed.sum())
-            if node.kind == "delete":
-                keep = ~changed
-                cols = [c[keep] for c in res.columns[:ncols]]
-                nulls = [n[keep] for n in res.nulls[:ncols]]
-            else:
-                cols = list(res.columns[:ncols])
-                nulls = list(res.nulls[:ncols])
-            mod.replace_table(node.table, cols, nulls)
+            with stage("write"):
+                if node.kind == "delete":
+                    keep = ~changed
+                    cols = [c[keep] for c in res.columns[:ncols]]
+                    nulls = [n[keep] for n in res.nulls[:ncols]]
+                else:
+                    cols = list(res.columns[:ncols])
+                    nulls = list(res.nulls[:ncols])
+                mod.replace_table(node.table, cols, nulls)
         return _count_result(affected)
 
     if isinstance(node, N.TableWriterNode):
@@ -1373,8 +1372,9 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
         mod = catalog(node.connector)
         h = mod.begin_insert(node.table)
         try:
-            mod.append(h, res.columns, res.nulls)
-            rows = mod.finish_insert(h)
+            with stage("write"):
+                mod.append(h, res.columns, res.nulls)
+                rows = mod.finish_insert(h)
         except BaseException:
             mod.abort_insert(h)
             raise
@@ -1395,8 +1395,9 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
         try:
             res = run_query(N.OutputNode(src.source, src.column_names),
                             **kw)
-            mod.append(h, res.columns, res.nulls)
-            rows = mod.finish_insert(h)
+            with stage("write"):
+                mod.append(h, res.columns, res.nulls)
+                rows = mod.finish_insert(h)
         except BaseException:
             mod.abort_insert(h)
             raise

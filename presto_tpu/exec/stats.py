@@ -43,6 +43,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .accuracy import NodeAccuracy, merge_record_maps, \
     record_map_from_json, record_map_to_json
 from .datapath import HopStats, hop_map_from_json, hop_map_to_json, \
@@ -51,7 +53,7 @@ from .timeline import TimelineSlice
 
 __all__ = ["RuntimeStats", "timed", "OperatorStats", "StageStats",
            "QueryStats", "StatsCollector", "current_collector",
-           "collecting"]
+           "collecting", "joining", "stage", "span", "note"]
 
 
 @dataclasses.dataclass
@@ -322,26 +324,52 @@ class QueryStats:
 
 
 class StatsCollector:
-    """Per-query collection context. Stage timings are recorded as
-    (start, end) spans so the tracer can render one span per stage;
-    compile durations from jax.monitoring land on whichever stage is
+    """Per-statement collection context: the one span seam.
+
+    The module's ``stage(name)`` and ``span(name)`` time an interval on
+    the ambient collector and hand it to two sinks: the collector's span
+    record ``(name, start_s, end_s, attrs, span, parent)`` -- summed
+    into ``QueryStats.stages`` for a stage and shipped to ``/v1/trace``
+    by :meth:`close` -- and the profiler's trace, as a ``TraceMe`` named
+    ``presto:<name>`` (nanoseconds while no profile runs), so the
+    program's spans share the device trace's clock and can never
+    disagree with ``QueryStats`` about what they cover. ``parent`` is
+    the span that was open on the calling thread when this one opened.
+    Compile durations from jax.monitoring land on whichever stage is
     open when XLA compiles (the execute dispatch), attributed to the
     ``compile`` stage."""
 
     def __init__(self, query_id: str = "query"):
         self.query_id = query_id
         self.stats = QueryStats()
-        self.spans: List[tuple] = []  # (stage, start_s, end_s, attrs)
+        # (name, start_s, end_s, attrs, span, parent): span numbers are
+        # this collector's own; close() maps them to span ids
+        self.spans: List[tuple] = []
+        self._next_span = 0
         self._compile_s = 0.0
         self._lock = threading.Lock()
 
-    # -- stage spans ----------------------------------------------------
+    # -- spans ------------------------------------------------------------
 
-    def stage(self, name: str, **fields):
-        return _StageTimer(self, name, fields)
+    def _new_span(self) -> int:
+        with self._lock:
+            self._next_span += 1
+            return self._next_span
+
+    def _record_span(self, name: str, start_s: float, end_s: float,
+                     attrs: Optional[dict] = None,
+                     span: Optional[int] = None,
+                     parent: Optional[int] = None) -> None:
+        if span is None:
+            span = self._new_span()
+        with self._lock:
+            self.spans.append((name, start_s, end_s, dict(attrs or {}),
+                               span, parent))
 
     def record_stage(self, name: str, start_s: float, end_s: float,
-                     **fields) -> None:
+                     attrs: Optional[dict] = None,
+                     span: Optional[int] = None,
+                     parent: Optional[int] = None, **fields) -> None:
         wall = _us(end_s - start_s)
         with self._lock:
             st = self.stats.stages.get(name)
@@ -352,7 +380,8 @@ class StatsCollector:
             st.invocations += 1
             for k, v in fields.items():
                 setattr(st, k, getattr(st, k) + v)
-            self.spans.append((name, start_s, end_s, dict(fields)))
+        self._record_span(name, start_s, end_s,
+                          {**fields, **(attrs or {})}, span, parent)
 
     def bump_stage(self, name: str, **fields) -> None:
         """Add to a stage's summed fields without opening a timing span
@@ -380,9 +409,9 @@ class StatsCollector:
         (anchors the synthetic compile span inside the execute window
         it actually happened in)."""
         with self._lock:
-            for sname, start_s, _end, _attrs in reversed(self.spans):
-                if sname == name:
-                    return start_s
+            for rec in reversed(self.spans):
+                if rec[0] == name:
+                    return rec[1]
         return None
 
     def operator(self, node_id: str, node_type: str = "", **fields) -> None:
@@ -402,33 +431,80 @@ class StatsCollector:
             self.stats.counters[name] = \
                 self.stats.counters.get(name, 0) + delta
 
-    def emit_spans(self, trace_id: Optional[str] = None,
-                   parent_id: Optional[str] = None) -> None:
-        """Ship collected stage spans through the tracing emission seam
-        (one span per stage boundary, each a child of `parent_id` --
-        the enclosing task/query span). emit_span delivers to the
-        process tracer AND any thread-local SpanBuffer, and never
-        raises (broken tracers are counted, not fatal)."""
-        from ..server.tracing import emit_span
-        tid = trace_id or self.query_id
-        for name, start_s, end_s, attrs in self.spans:
-            emit_span(tid, f"stage.{name}", start_s, end_s,
-                      {k: v for k, v in attrs.items()},
-                      parent_id=parent_id)
+    def close(self, trace=None) -> None:
+        """Called once, by whoever created the collector, when the
+        statement's last span has closed. Ships the collected spans
+        through the tracing emission seam, each under the span that
+        caused it; the top-level ones hang under the enclosing
+        task/query span. `trace` is a TraceContext (trace id + that
+        parent span), a plain grouping string (legacy) or None (the
+        query id). emit_span delivers to the process tracer AND any
+        thread-local SpanBuffer, and never raises (broken tracers are
+        counted, not fatal). Each stage's wall then feeds the
+        ``presto_tpu_stage_seconds`` histogram of /v1/metrics,
+        exemplar'd with the trace id so a p99 execute spike links to
+        its waterfall."""
+        from ..server.metrics import observe_histogram
+        from ..server.tracing import TraceContext, emit_span, new_span_id
+        if isinstance(trace, TraceContext):
+            tid, root = trace.trace_id, trace.span_id
+        else:
+            tid, root = trace or self.query_id, None
+        with self._lock:
+            spans = list(self.spans)
+        ids = {rec[4]: new_span_id() for rec in spans}
+        for name, start_s, end_s, attrs, span, parent in spans:
+            emit_span(tid, f"stage.{name}", start_s, end_s, attrs,
+                      span_id=ids[span],
+                      parent_id=ids.get(parent, root))
+        with self._lock:
+            walls = [(name, st.wall_us)
+                     for name, st in self.stats.stages.items()]
+        for name, wall_us in walls:
+            if wall_us:
+                observe_histogram("presto_tpu_stage_seconds",
+                                  wall_us / 1e6, labels={"stage": name},
+                                  trace_id=tid)
 
 
-class _StageTimer:
-    def __init__(self, collector: StatsCollector, name: str, fields: dict):
+class _SpanTimer:
+    def __init__(self, collector: Optional[StatsCollector], name: str,
+                 fields: dict, attrs: Optional[dict], is_stage: bool):
         self.c = collector
         self.name = name
         self.fields = fields
+        self.attrs = attrs
+        self.is_stage = is_stage
 
     def __enter__(self):
+        c = self.c
+        if c is not None:
+            stack = _open_spans()
+            self.parent = stack[-1][1] if stack and stack[-1][0] is c \
+                else None
+            self.span = c._new_span()
+            stack.append((c, self.span, self.name))
+        self._trace = TraceAnnotation("presto:" + self.name,
+                                      **(self.attrs or {}))
+        self._trace.__enter__()
         self.t0 = time.time()
         return self
 
     def __exit__(self, *exc):
-        self.c.record_stage(self.name, self.t0, time.time(), **self.fields)
+        t1 = time.time()
+        self._trace.__exit__(*exc)
+        c = self.c
+        if c is None:
+            return False
+        stack = _open_spans()
+        if stack and stack[-1][:2] == (c, self.span):
+            stack.pop()
+        if self.is_stage:
+            c.record_stage(self.name, self.t0, t1, self.attrs, self.span,
+                           self.parent, **self.fields)
+        else:
+            c._record_span(self.name, self.t0, t1, self.attrs, self.span,
+                           self.parent)
         return False
 
 
@@ -437,6 +513,37 @@ _tls = threading.local()
 
 def current_collector() -> Optional[StatsCollector]:
     return getattr(_tls, "collector", None)
+
+
+def _open_spans() -> list:
+    """This thread's open spans, innermost last: (collector, span,
+    name)."""
+    stack = getattr(_tls, "open", None)
+    if stack is None:
+        stack = _tls.open = []
+    return stack
+
+
+def stage(name: str, attrs: Optional[dict] = None, **fields):
+    """Open a span of the ambient collector that is also summed into
+    ``QueryStats.stages[name]``; `fields` add to the stage's counters,
+    `attrs` ride the span. With no collector ambient, only the
+    profiler's annotation."""
+    return _SpanTimer(current_collector(), name, fields, attrs, True)
+
+
+def span(name: str, attrs: Optional[dict] = None):
+    """A span of the ambient collector that is recorded and annotated
+    but summed nowhere (the datapath hops: their sums live in
+    ``QueryStats.datapath``); see stage."""
+    return _SpanTimer(current_collector(), name, {}, attrs, False)
+
+
+def note(name: str, delta: int = 1) -> None:
+    """Bump a counter of the ambient collector, where there is one."""
+    c = current_collector()
+    if c is not None:
+        c.note(name, delta)
 
 
 class collecting:
@@ -456,6 +563,33 @@ class collecting:
         return False
 
 
+class joining:
+    """The statement's collector for this thread: the ambient one where
+    a caller up the stack opened it (the statement server, ``sql()``,
+    an outer ``run_query``), else a new one that is ambient inside the
+    block and shipped to the tracer when the block ends cleanly.
+    `trace` is what :meth:`StatsCollector.close` takes."""
+
+    def __init__(self, query_id: str = "query", trace=None):
+        self.query_id = query_id
+        self.trace = trace
+        self._own: Optional[collecting] = None
+
+    def __enter__(self) -> StatsCollector:
+        c = current_collector()
+        if c is None:
+            self._own = collecting(StatsCollector(self.query_id))
+            c = self._own.__enter__()
+        return c
+
+    def __exit__(self, exc_type, *exc):
+        if self._own is not None:
+            self._own.__exit__(exc_type, *exc)
+            if exc_type is None:
+                self._own.collector.close(self.trace)
+        return False
+
+
 _listener_installed = False
 _listener_lock = threading.Lock()
 
@@ -468,17 +602,25 @@ _listener_lock = threading.Lock()
 # sequential top-level phases; the runner additionally clamps the sum
 # to the enclosing execute wall as a backstop against nested-jit
 # lowering overlap.
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _COMPILE_EVENTS = frozenset([
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
+    _BACKEND_COMPILE,
 ])
 
 
 def _ensure_compile_listener() -> None:
-    """Register the process-wide jax.monitoring listener exactly once.
-    Durations route to the calling thread's ambient collector (jit
-    compiles on the dispatching thread), so concurrent queries don't
-    cross-attribute."""
+    """Register the process-wide jax.monitoring listeners exactly once.
+    Durations and counts route to the calling thread's ambient
+    collector (jit compiles on the dispatching thread), so concurrent
+    queries don't cross-attribute. ``xla_compiles`` counts the backend
+    compile calls of the whole statement (a persistent-cache read is
+    one too) and ``compile_cache_reads`` those the cache answered: a
+    statement of a warmed server reads 0 and 0, one that reads 1 and 1
+    traces a program anew every time (a fresh ``jax.jit``, a shape that
+    varies) and is saved by the cache, and the difference is what XLA
+    built. The benchmark's per-layer metrics of the same names are
+    their mean per statement."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
@@ -490,10 +632,23 @@ def _ensure_compile_listener() -> None:
                 if name not in _COMPILE_EVENTS:
                     return
                 c = current_collector()
-                if c is not None:
+                if c is None:
+                    return
+                if name == _BACKEND_COMPILE:
+                    c.note("xla_compiles")
+                # the seconds are the `compile` stage's, carved out of
+                # the `execute` wall they fall in: a planner's constant
+                # fold compiles too, and is counted, not carved
+                if any(col is c and open_name == "execute"
+                       for col, _span, open_name in _open_spans()):
                     c.add_compile_seconds(float(seconds))
 
+            def _on_event(name, **_kw):
+                if name == "/jax/compilation_cache/cache_hits":
+                    note("compile_cache_reads")
+
             _mon.register_event_duration_secs_listener(_on_duration)
+            _mon.register_event_listener(_on_event)
         except Exception:  # noqa: BLE001 - telemetry must never break exec
             pass
         _listener_installed = True

@@ -511,6 +511,7 @@ def _sum128(ids, col, live, max_groups: int):
     return combine_limb_totals_128(jnp.stack(totals, axis=-1))
 
 
+@jax.named_scope("_group_ids_hash")
 def _group_ids_hash(words, active: jnp.ndarray, max_groups: int):
     """Hash-slot kernel for large tables (see module docstring)."""
     n = active.shape[0]
@@ -563,6 +564,7 @@ def _group_ids_hash(words, active: jnp.ndarray, max_groups: int):
     return ids, perm_first, num_groups, overflow
 
 
+@jax.named_scope("_group_ids_sort")
 def _group_ids_sort(key_cols: Sequence[Block], active: jnp.ndarray,
                     max_groups: int):
     """Sort-based variant of _group_ids (kept for A/B measurement):
@@ -814,6 +816,7 @@ def _sorted_states(spec: AggSpec, scol, live, start, end, new_seg,
     raise NotImplementedError(f"sorted-mode aggregate {spec.name!r}")
 
 
+@jax.named_scope("_group_by_sorted")
 def _group_by_sorted(batch: Batch, key_channels, aggs, max_groups: int
                      ) -> "GroupByResult":
     """Sorted-mode group_by (see block comment above)."""

@@ -102,6 +102,7 @@ def _combined_key(cols: Sequence[Block], active) -> Tuple[jnp.ndarray, jnp.ndarr
 _MAXW = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
+@jax.named_scope("_sort_build")
 def _sort_build(b_words: List[jnp.ndarray], b_usable: jnp.ndarray,
                 payload: Optional[jnp.ndarray]):
     """Sort build rows so the word arrays are globally sorted AND
@@ -120,6 +121,7 @@ def _sort_build(b_words: List[jnp.ndarray], b_usable: jnp.ndarray,
     return sorted_words, sorted_payload
 
 
+@jax.named_scope("_pack_ranks")
 def _pack_ranks(build_words: List[jnp.ndarray], probe_words: List[jnp.ndarray]):
     """Reduce multi-word keys to single int64 ranks, exactly.
 
@@ -153,6 +155,7 @@ def _pack_ranks(build_words: List[jnp.ndarray], probe_words: List[jnp.ndarray]):
     return b_rank, p_rank
 
 
+@jax.named_scope("hash_join")
 def hash_join(probe: Batch, build: Batch,
               probe_key_channels: Sequence[int],
               build_key_channels: Sequence[int],
@@ -269,6 +272,7 @@ def hash_join(probe: Batch, build: Batch,
 from ..block import gather_block as _gather  # shared row gather
 
 
+@jax.named_scope("semi_join_mask")
 def semi_join_mask(probe: Batch, build: Batch,
                    probe_key_channels: Sequence[int],
                    build_key_channels: Sequence[int],

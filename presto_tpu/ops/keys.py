@@ -37,6 +37,7 @@ _SIGN = np.uint64(1 << 63)
 
 __all__ = ["key_words", "num_key_words", "lex_sort"]
 
+@jax.named_scope("lex_sort")
 def lex_sort(operands: Sequence[jnp.ndarray], num_keys: int,
              is_stable: bool = False) -> List[jnp.ndarray]:
     """`jax.lax.sort(operands, num_keys=...)` for key WORDS (uint64),

@@ -83,4 +83,5 @@ def limb_partial_sums(ids: jax.Array, limbs: jax.Array, groups: int,
         out_specs=pl.BlockSpec((1, groups, L), lambda i: (i, _Z, _Z)),
         out_shape=jax.ShapeDtypeStruct((tiles, groups, L), jnp.float32),
         interpret=interpret,
+        name="limb_partial_sums",
     )(ids.astype(jnp.int32).reshape(tiles, 1, _SUM_TILE), limbs)

@@ -71,6 +71,7 @@ def sort_batch(batch: Batch, keys: Sequence[SortKey]) -> Batch:
                  batch.active[perm])
 
 
+@jax.named_scope("top_n")
 def top_n(batch: Batch, keys: Sequence[SortKey], n: int) -> Batch:
     """TopN: sorted prefix of n rows (static output capacity n)."""
     s = sort_batch(batch, keys)

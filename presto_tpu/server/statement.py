@@ -22,6 +22,7 @@ reference's JSON conventions (decimals/dates/timestamps as strings).
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import re
 import threading
@@ -34,6 +35,7 @@ import numpy as np
 
 from .. import failpoints
 from .. import types as T
+from ..exec.stats import QueryStats, StatsCollector, collecting, stage
 from ..transaction import TransactionManager
 from ..utils.locks import OrderedLock
 from .dispatcher import Dispatcher, QueryRejected
@@ -114,6 +116,11 @@ class _Query:
         self.user = user
         self.txn_id = txn_id
         self.machine = QueryStateMachine(query_id)
+        # the statement's one collector, opened where the POST lands:
+        # every span from here to the last rendered row is recorded on
+        # it (exec/stats.py), `queue` being the first
+        self.created_at = time.time()
+        self.collector = StatsCollector(query_id)
         # this query's trace identity: the client's propagated trace id
         # when an X-Presto-Trace header arrived, else the query id
         # itself (so GET /v1/trace/{queryId} resolves without a lookup
@@ -272,9 +279,11 @@ class StatementServer:
         # coordinator session analog of X-Presto-Prepared-Statement)
         from ..sql.statements import PreparedStatements
         user = self._user_of(query_id)
-        pre = preprocess(text, catalog=session_values.get("catalog", "tpch"),
-                         prepared=self._prepared.setdefault(
-                             user, PreparedStatements()))
+        with stage("plan"):
+            pre = preprocess(
+                text, catalog=session_values.get("catalog", "tpch"),
+                prepared=self._prepared.setdefault(
+                    user, PreparedStatements()))
         if pre.ack is not None:
             from ..exec.runner import QueryResult
             return QueryResult([], [], [pre.ack], 0)
@@ -566,6 +575,27 @@ class StatementServer:
             q.machine.to_failed(_error_doc(name, f"{type(e).__name__}: {e}"))
 
     def _run_engine(self, q: _Query):
+        """The statement on its engine thread, under its collector:
+        `queue` is POST accepted to here (thread start, admission)."""
+        q.collector.record_stage("queue", q.created_at, time.time())
+        with collecting(q.collector):
+            return self._run_statement(q)
+
+    def _close_stats(self, q: _Query, res=None) -> None:
+        """The last step before FINISHED: the client's final document
+        carries the collector's stats, and /v1/trace its spans. A batch
+        member's or a custom executor's own document takes the server's
+        stages (queue, batch, render) and counters in."""
+        qs = q.collector.stats
+        own = getattr(res, "query_stats", None)
+        if isinstance(own, QueryStats) and own is not qs:
+            qs = dataclasses.replace(own.merge(qs),
+                                     task_count=own.task_count)
+        q.result_stats = own if own is not None \
+            and not isinstance(own, QueryStats) else qs
+        q.collector.close(q.trace_ctx)
+
+    def _run_statement(self, q: _Query):
         if failpoints.ARMED:
             # hang = a wedged statement tier (the client poll deadline's
             # test surface); error = a query failed before planning
@@ -589,6 +619,7 @@ class StatementServer:
             q.columns = [{"name": "Query Plan", "type": "varchar"}]
             q.rows = [[line] for line in text.splitlines()]
             q.machine.to_finishing()
+            self._close_stats(q)
             q.machine.to_finished()
             return
         q.machine.to_running()
@@ -616,7 +647,6 @@ class StatementServer:
             if res.types and res.types[0].base == "bigint" and \
                     res.row_count == 1:
                 q.update_count = int(res.columns[0][0])
-        q.result_stats = getattr(res, "query_stats", None)
         from ..exec.batching import batch_size_of
         q.batch_size = batch_size_of(q.id)
         q.columns = [{"name": n, "type": str(t)}
@@ -626,12 +656,14 @@ class StatementServer:
         _BOUNDED_BY = {"rendered": "final result rows (protocol "
                                    "rendering)"}
         rendered = []
-        for i in range(res.row_count):
-            rendered.append([
-                render_value(res.columns[c][i], bool(res.nulls[c][i]),
-                             res.types[c])
-                for c in range(len(res.types))])
+        with stage("render"):
+            for i in range(res.row_count):
+                rendered.append([
+                    render_value(res.columns[c][i],
+                                 bool(res.nulls[c][i]), res.types[c])
+                    for c in range(len(res.types))])
         q.rows = rendered
+        self._close_stats(q, res)
         q.machine.to_finished()
         return res
 

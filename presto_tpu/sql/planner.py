@@ -2690,17 +2690,24 @@ def sql(query_text: str, sf: float = 0.01, mesh=None,
         max_groups: int = 1 << 16, join_capacity: Optional[int] = None,
         catalog: Optional[str] = None, **kwargs):
     """One-call SQL execution over the session catalogs: the query-runner
-    front door (DistributedQueryRunner.execute analog)."""
+    front door (DistributedQueryRunner.execute analog). Parsing and
+    planning are the statement's ``plan`` stage (child ``plan.sql``), on
+    the ambient collector where the statement server opened one, else
+    on one of this call's own."""
     from ..exec import run_query
+    from ..exec.stats import joining, stage
     from .statements import _DEFAULT_PREPARED, preprocess
-    pre = preprocess(query_text, catalog=catalog or "tpch",
-                     prepared=_DEFAULT_PREPARED)
-    if pre.ack is not None:
-        from ..exec.runner import QueryResult
-        return QueryResult([], [], [pre.ack], 0)
-    query_text = pre.text
-    root = plan_sql(query_text, max_groups=max_groups,
-                    join_capacity=join_capacity, catalog=catalog)
-    if join_capacity is not None:
-        kwargs.setdefault("default_join_capacity", join_capacity)
-    return run_query(root, sf=sf, mesh=mesh, **kwargs)
+    with joining(kwargs.get("query_id", "query"), kwargs.get("trace_id")):
+        with stage("plan"):
+            pre = preprocess(query_text, catalog=catalog or "tpch",
+                             prepared=_DEFAULT_PREPARED)
+            if pre.ack is not None:
+                from ..exec.runner import QueryResult
+                return QueryResult([], [], [pre.ack], 0)
+            with stage("plan.sql"):
+                root = plan_sql(pre.text, max_groups=max_groups,
+                                join_capacity=join_capacity,
+                                catalog=catalog)
+        if join_capacity is not None:
+            kwargs.setdefault("default_join_capacity", join_capacity)
+        return run_query(root, sf=sf, mesh=mesh, **kwargs)

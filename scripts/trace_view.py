@@ -11,6 +11,9 @@ span tree on the trace's time axis with critical-path attribution --
   python scripts/trace_view.py http://127.0.0.1:8080 --query 20260730_ab12
   python scripts/trace_view.py spans.jsonl --trace query.deadbeef
   python scripts/trace_view.py spans.jsonl            # lists trace ids
+  python scripts/trace_view.py --xplane t.xplane.pb   # a profiler trace:
+      device seconds by the program's scope (region/operator/ops function)
+      and, per statement, device idle seconds by presto:<span>
 
 Exit codes: 0 rendered, 1 trace not found / empty, 2 source unreadable.
 """
@@ -25,7 +28,8 @@ import urllib.request
 sys.path.insert(0, os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
 
-from presto_tpu.traceview import fetch_trace, render_waterfall  # noqa: E402
+from presto_tpu.traceview import device_time_by_scope, fetch_trace, \
+    render_scopes, render_waterfall  # noqa: E402
 
 
 def load_jsonl(path: str, trace_id: str = None):
@@ -60,8 +64,12 @@ def load_jsonl(path: str, trace_id: str = None):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="trace_view")
-    ap.add_argument("source", help="trace URL, coordinator base URL "
-                                   "(with --query), or spans JSONL file")
+    ap.add_argument("source", nargs="?",
+                    help="trace URL, coordinator base URL "
+                         "(with --query), or spans JSONL file")
+    ap.add_argument("--xplane", default=None, metavar="FILE",
+                    help="a profiler trace (.xplane.pb[.gz]): device "
+                         "time by scope, idle time by presto: span")
     ap.add_argument("--query", default=None,
                     help="query id: source is a coordinator/worker base "
                          "URL, fetch its /v1/trace/{query}")
@@ -69,8 +77,13 @@ def main(argv=None) -> int:
                     help="trace id to pick out of a JSONL file")
     ap.add_argument("--width", type=int, default=72)
     args = ap.parse_args(argv)
+    if (args.xplane is None) == (args.source is None):
+        ap.error("give a trace source or --xplane FILE")
 
     try:
+        if args.xplane is not None:
+            print(render_scopes(device_time_by_scope(args.xplane)))
+            return 0
         if args.source.startswith(("http://", "https://")):
             doc = fetch_trace(args.source, args.query)
         else:
