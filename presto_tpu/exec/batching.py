@@ -71,6 +71,7 @@ from ..expr import ir as E
 from ..expr.compile import bound_params
 from ..plan import nodes as N
 from ..utils.locks import OrderedLock
+from .planner import split_flags
 from .stats import collecting, stage
 
 __all__ = ["BATCHING_ENV", "batching_enabled", "parameterize_plan",
@@ -844,7 +845,7 @@ class BatchingExecutor:
                 jax.block_until_ready(out)
             finally:
                 prog.release(state="FINISHED")
-            flags = np.asarray(overflow)
+            flags, steps = split_flags(np.asarray(overflow))
             if int(flags.max()) != 0:
                 # a member overflowed a static bucket: the serial
                 # ladder owns adaptive reruns; collapse the whole batch
@@ -861,7 +862,7 @@ class BatchingExecutor:
             self._serial_fallback(entries, sf, "error")
             return
         device_us = int((time.time() - t0) * 1e6)
-        self._fan_out(out, plan, entries, device_us)
+        self._fan_out(out, plan, entries, device_us, steps)
         self._account(key, entries, device_us)
 
     def _stage_inputs(self, key, plan, sf: float) -> list:
@@ -926,7 +927,7 @@ class BatchingExecutor:
         return tuple(out)
 
     def _fan_out(self, out, plan, entries: List[_Pending],
-                 device_us: int) -> None:
+                 device_us: int, search_steps) -> None:
         """Slice the batched output back into per-member QueryResults
         (member i owns batch row i -- ordering is positional by
         construction). ONE host conversion covers the whole batch;
@@ -950,6 +951,8 @@ class BatchingExecutor:
             qs.output_rows = res.row_count
             qs.counters["batched_queries"] = 1
             qs.counters["batch_size"] = nbatch
+            if search_steps[i]:
+                qs.counters["join_search_steps"] = int(search_steps[i])
             res.query_stats = qs
             res.stats = {"batch": {"size": float(nbatch),
                                    "device_us": float(device_us)}}

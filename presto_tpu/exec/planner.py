@@ -41,12 +41,23 @@ from ..parallel.exchange import (broadcast_build, exchange_by_hash,
 from ..parallel.mesh import WORKERS_AXIS
 from ..plan import nodes as N
 
-__all__ = ["compile_plan", "CompiledPlan"]
+__all__ = ["compile_plan", "CompiledPlan", "split_flags"]
+
+# the status word a program returns beside its batch: overflow flags in
+# the low bits, the joins' binary-search trips from this bit up
+FLAG_BITS = 8
+
+
+def split_flags(word):
+    """The status word, taken apart on the host: (overflow flags: bit0
+    hard, bit1 exchange slots; join_search_steps). `word` is an int, or
+    the array of them a vmapped program returns."""
+    return word & ((1 << FLAG_BITS) - 1), word >> FLAG_BITS
 
 
 @dataclasses.dataclass
 class CompiledPlan:
-    """fn(scans: Dict[node_id, Batch]) -> (Batch, overflow_flag).
+    """fn(scans: Dict[node_id, Batch]) -> (Batch, status word).
     `scan_nodes` lists the TableScanNode/ValuesNode leaves in the order
     their batches must be supplied; distributed plans expect each scan
     batch shard-able along axis 0 by the mesh."""
@@ -192,6 +203,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
             r = hash_join(probe, build, node.left_keys, node.right_keys,
                           cap, node.join_type, node.right_output_channels)
             _note_overflow(r.overflow)
+            search_steps.append(r.search_steps)
             return r.batch
         if isinstance(node, N.SemiJoinNode):
             src = lower(node.source, inputs)
@@ -206,7 +218,8 @@ def compile_plan(root: N.PlanNode, mesh=None,
             fk = node.filtering_key if isinstance(node.filtering_key, list) \
                 else [node.filtering_key]
             m, mnull = semi_join_mask(src, filt, sk, fk,
-                                      node.null_keys_match)
+                                      node.null_keys_match,
+                                      steps_out=search_steps)
             from ..block import Column
             return Batch(src.columns + (Column(m, mnull, T.BOOLEAN),),
                          src.active)
@@ -358,6 +371,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
         raise TypeError(type(node))
 
     overflow_box: List = []
+    search_steps: List = []  # one trip count per join lookup lowered
     _lower_memo: Dict[int, Batch] = {}
 
     def _note_overflow(flag, scalable: bool = False):
@@ -368,6 +382,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
 
     def run(scan_batches: Sequence[Batch]):
         overflow_box.clear()
+        search_steps.clear()
         _lower_memo.clear()
         inputs = {n.id: b for n, b in zip(scans, scan_batches)}
         out = lower(root, inputs)
@@ -378,11 +393,16 @@ def compile_plan(root: N.PlanNode, mesh=None,
                 slots = slots | f
             else:
                 hard = hard | f
+        steps = sum(search_steps, jnp.zeros((), dtype=jnp.int32))
         if dist:
             hard = jax.lax.psum(hard.astype(jnp.int32), axis) > 0
             slots = jax.lax.psum(slots.astype(jnp.int32), axis) > 0
-        # bitmask: bit0 = hard (non-scalable), bit1 = exchange slots
-        return out, hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
+            steps = jax.lax.pmax(steps, axis)  # the deepest shard's
+        # one word, one host read: bit0 = hard (non-scalable), bit1 =
+        # exchange slots, bits 8 and up = the joins' binary-search trips
+        # (the counter join_search_steps; `split_flags` takes it apart)
+        return out, (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
+                     + (steps << FLAG_BITS))
 
     if dist:
         in_specs = tuple(P(WORKERS_AXIS) for _ in scans)

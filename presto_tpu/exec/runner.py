@@ -26,8 +26,9 @@ import numpy as np
 from .. import types as T
 from ..block import Batch, batch_from_numpy, to_numpy
 from ..plan import nodes as N
-from .planner import compile_plan
-from .stats import QueryStats, RuntimeStats, StatsCollector, joining, stage
+from .planner import compile_plan, split_flags
+from .stats import (QueryStats, RuntimeStats, StatsCollector, joining, note,
+                    stage)
 
 __all__ = ["run_query", "prepare_plan", "QueryResult"]
 
@@ -838,6 +839,16 @@ _CAPACITY_FEEDBACK: Dict[str, int] = {}
 _MAX_CAPACITY_SCALE = 1 << 10
 
 
+def _read_status(word) -> int:
+    """The one host read of the word a program returns beside its batch:
+    its overflow flags come back, the trips its joins' lookups took go
+    to the statement's counters."""
+    flags, steps = split_flags(int(np.asarray(word)))
+    if steps:
+        note("join_search_steps", steps)
+    return flags
+
+
 def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                      mesh, default_join_capacity: int, use_cache: bool,
                      fp: Optional[str], stats, adaptive_off: bool,
@@ -895,7 +906,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # the only per-kernel timing one fused program exposes -- on
             # the monotonic now_us clock the timeline intervals share
             device_s += (_now_us() - t_disp0) / 1e6
-            flags = int(np.asarray(overflow))
+            flags = _read_status(overflow)
         if prog is not None:  # each landed dispatch advances
             prog.advance()
         if flags == 0:
@@ -1068,7 +1079,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                 with stage("device_wait", region):
                     jax.block_until_ready(out)
                     dev_s = (_now_us() - t_don0) / 1e6
-                    oflags = int(np.asarray(overflow))
+                    oflags = _read_status(overflow)
                 if prog is not None:
                     prog.advance()
                 if oflags:  # unreachable: whitelist admits no overflow op

@@ -6,12 +6,19 @@ JoinCompiler's generated hash strategies, and the join plan nodes
 (JoinNode INNER/LEFT/RIGHT/FULL, SemiJoinNode).
 
 TPU-first redesign: no pointer-chasing hash table. The build side is
-SORTED by key words once (MXU-friendly O(n log n) on device); probes
-binary-search via jnp.searchsorted (vectorized, log n gathers). 1:N
-matches expand through a static-capacity prefix-sum expansion:
+SORTED by key words once (O(n log n) on device), and a probe row looks
+its key up ONCE (`_match_ranges`): a bucket directory over the sorted
+keys, indexed by the high bits of key - min (Velox HashTable's array
+mode, without a second code path), brackets the key to a handful of
+build rows; a binary search runs only as deep as the fullest bracket
+needs (1 trip on dense keys, never more than log n); the end of the
+match range is read off the build side's run ends. A gather pass over
+the probe is the unit of cost on the chip: four 32-bit ones here where
+keys are dense, against 2 x log n of 64 bits. 1:N matches expand
+through a static-capacity prefix-sum expansion:
 
-  start[i] = searchsorted_left(build, probe_i)
-  cnt[i]   = searchsorted_right - start  (0 for null/missing keys)
+  start[i], end[i] = _match_ranges(build, probe_i)
+  cnt[i]   = end - start  (0 for null/missing keys)
   off      = exclusive_cumsum(cnt)
   out row k maps back to probe row via searchsorted(off, k), and to
   build row start[row] + (k - off[row])
@@ -21,11 +28,11 @@ shows up in the output's active mask and an `overflow` flag when the
 out_capacity bucket is too small (exec layer re-runs bigger, the
 LookupJoinOperator yield/rebatch analog).
 
-Sort order on multiple words: lexicographic. searchsorted works on a
-single key, so the word tuple is reduced to a single total-order rank:
-build rows get rank = their sorted position; probes find their rank by
-stacked binary search over each word level. For the common 1-2 word
-case (bigint keys) this is one searchsorted call.
+Sort order on multiple words: lexicographic. The lookup works on a
+single key, so a multi-word tuple is first reduced to a single
+total-order rank (`_pack_ranks`: one union sort per word), and the
+dense ranks go through the same lookup. The common one-word case
+(bigint keys) looks its word up directly.
 """
 
 from __future__ import annotations
@@ -49,10 +56,12 @@ class JoinResult:
     batch: Batch          # probe columns ++ build columns
     num_rows: jnp.ndarray
     overflow: jnp.ndarray
+    search_steps: jnp.ndarray  # binary-search trips the lookups took
 
 
 jax.tree_util.register_dataclass(JoinResult,
-                                 data_fields=["batch", "num_rows", "overflow"],
+                                 data_fields=["batch", "num_rows", "overflow",
+                                              "search_steps"],
                                  meta_fields=[])
 
 
@@ -155,6 +164,120 @@ def _pack_ranks(build_words: List[jnp.ndarray], probe_words: List[jnp.ndarray]):
     return b_rank, p_rank
 
 
+def _halves(words: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """uint64 words as (high, low) uint32 lanes."""
+    return (words >> 32).astype(jnp.uint32), words.astype(jnp.uint32)
+
+
+@jax.named_scope("_match_ranges")
+def _match_ranges(sorted_keys: jnp.ndarray, n_usable: jnp.ndarray,
+                  queries: jnp.ndarray):
+    """The lookup that turns a probe key into its match range. For each
+    query, [start, end) are the positions of its key among the first
+    `n_usable` rows of `sorted_keys` (uint64, ascending; the rows behind
+    them are `_sort_build`'s MAX-masked tail and never match): the
+    integers searchsorted(side="left") / (side="right") give once
+    clamped to n_usable, as int32. Also returns `steps`, the
+    binary-search trips taken, a device scalar.
+
+    1. A directory of D = 2**ceil(log2 nb) buckets over the usable keys'
+       range: bucket(key) = (key - min) >> shift, monotone in the key,
+       so directory[b] = first sorted position whose bucket is >= b, and
+       a query's range lies inside [directory[b(q)], directory[b(q) + 1]].
+       O(nb + D), no pass over the queries.
+    2. A lower-bound search inside that bracket, as deep as the fullest
+       bracket needs: 1 trip where keys are dense (orderkeys, partkeys),
+       the log of the longest run of equal keys where they repeat,
+       ceil(log2 nb) at worst (one far outlier: a plain search's depth).
+    3. `end` without a second search: the search remembers whether the
+       row it settles on holds the query's key; if so its run of equal
+       keys ends where the build side says (`run_end`, O(nb)).
+
+    A pass over the queries costs by its gathers, 7.6 ns an index for
+    one 32-bit lane on the chip (a uint64 lane: 19-29), so keys are
+    read as two uint32 halves and only the bracket's low end is looked
+    up: four such gathers a query where keys are dense. (Rows of 2-4
+    lanes gather at 2.3-5.3 ns an index, but XLA:TPU then pads the
+    result to 512 bytes a query when the table is small: PERF.md.)
+    """
+    nb = sorted_keys.shape[0]
+    if nb == 0:
+        zero = jnp.zeros(queries.shape, dtype=jnp.int32)
+        return zero, zero, jnp.zeros((), dtype=jnp.int32)
+    if nb == 1:
+        # a second, tail row: XLA:CPU folds the cumsum of a ONE-update
+        # scatter to a wrong constant (met with one query, jax 0.9)
+        sorted_keys = jnp.concatenate([sorted_keys, sorted_keys])
+        nb = 2
+    log2d = max((nb - 1).bit_length(), 1)
+    n = n_usable.astype(jnp.int32)
+    pos = jnp.arange(nb, dtype=jnp.int32)
+
+    kmin = sorted_keys[0]
+    kmax = sorted_keys[jnp.maximum(n - 1, 0)]
+    span_bits = 64 - jax.lax.clz(kmax - kmin)
+    shift = jnp.maximum(span_bits, log2d) - log2d  # uint64, as the keys
+
+    def bucket(keys):
+        return ((jnp.clip(keys, kmin, kmax) - kmin) >> shift) \
+            .astype(jnp.int32)
+
+    # the build rows' buckets ascend with their position, tail included
+    # (clipped to kmax's bucket, weight 0)
+    hist = jnp.zeros(2 ** log2d + 1, dtype=jnp.int32).at[
+        bucket(sorted_keys) + 1].add((pos < n).astype(jnp.int32),
+                                     indices_are_sorted=True)
+    directory = jnp.cumsum(hist, dtype=jnp.int32)
+    fullest = jnp.max(directory[1:] - directory[:-1])
+    steps = 32 - jax.lax.clz(fullest)  # ceil(log2(fullest + 1))
+
+    # run_end[i]: one past the last usable position holding row i's key,
+    # through run ids (cumsum is the one scan XLA:TPU compiles in
+    # seconds: 6 s at 1.5M rows where cummin takes 30)
+    prev = jnp.concatenate([sorted_keys[:1], sorted_keys[:-1]])
+    run = jnp.cumsum(((sorted_keys != prev) | (pos == n)).astype(jnp.int32),
+                     dtype=jnp.int32)
+    run_start = jnp.full(nb + 1, nb, dtype=jnp.int32).at[run].min(
+        pos, indices_are_sorted=True)
+    run_end = jnp.minimum(run_start[run + 1], n)
+
+    # the bracket's low end is looked up; its high end is at most
+    # `fullest` rows on, and the rows between the true one and that
+    # belong to higher buckets: greater keys, so the search is the same
+    lo = directory[bucket(queries)]
+    hi = jnp.minimum(lo + fullest, n)
+    high, low = _halves(sorted_keys)
+    q_high, q_low = _halves(queries)
+
+    def halve(_, state):
+        lo, hi, hit = state
+        mid = jnp.minimum((lo + hi) >> 1, nb - 1)  # == nb once closed
+        k_high, k_low = high[mid], low[mid]
+        left = (lo < hi) & ((k_high > q_high)
+                            | ((k_high == q_high) & (k_low >= q_low)))
+        return (jnp.where((lo < hi) & ~left, mid + 1, lo),
+                jnp.where(left, mid, hi),
+                jnp.where(left, (k_high == q_high) & (k_low == q_low), hit))
+
+    # hit: the row settled on holds the query's key; none yet (lo > hi
+    # is all False, and as varying as the carry under shard_map)
+    start, _, hit = jax.lax.fori_loop(0, steps, halve, (lo, hi, lo > hi))
+    end = jnp.where(hit, run_end[jnp.minimum(start, nb - 1)], start)
+    return start, end, steps
+
+
+def _lookup(sorted_words: Sequence[jnp.ndarray], usable: jnp.ndarray,
+            query_words: Sequence[jnp.ndarray]):
+    """`_match_ranges` of `query_words` in one side's `_sort_build`
+    output; several words a key go through `_pack_ranks` first."""
+    n_usable = jnp.sum(usable, dtype=jnp.int32)
+    if len(query_words) == 1:
+        return _match_ranges(sorted_words[0], n_usable, query_words[0])
+    ranks, q_ranks = _pack_ranks(list(sorted_words), list(query_words))
+    return _match_ranges(ranks.astype(jnp.uint64), n_usable,
+                         q_ranks.astype(jnp.uint64))
+
+
 @jax.named_scope("hash_join")
 def hash_join(probe: Batch, build: Batch,
               probe_key_channels: Sequence[int],
@@ -191,18 +314,8 @@ def hash_join(probe: Batch, build: Batch,
     # sort build by key words (unusable rows masked to MAX, sorted last)
     sb_words, b_perm = _sort_build(b_words, b_usable,
                                    jnp.arange(nb, dtype=jnp.int32))
-    n_build_usable = jnp.sum(b_usable.astype(jnp.int64))
-
-    if len(p_words) == 1:
-        start = jnp.searchsorted(sb_words[0], p_words[0], side="left")
-        end = jnp.searchsorted(sb_words[0], p_words[0], side="right")
-    else:
-        b_rank, p_rank = _pack_ranks(list(sb_words), list(p_words))
-        start = jnp.searchsorted(b_rank, p_rank, side="left")
-        end = jnp.searchsorted(b_rank, p_rank, side="right")
-    # clamp matches into the usable (sorted-front) region
-    start = jnp.minimum(start, n_build_usable)
-    end = jnp.minimum(end, n_build_usable)
+    # match ranges inside the usable (sorted-front) region
+    start, end, steps = _lookup(sb_words, b_usable, p_words)
 
     cnt = jnp.where(p_usable, end - start, 0).astype(jnp.int64)
     if join_type in ("left", "full"):
@@ -216,16 +329,8 @@ def hash_join(probe: Batch, build: Batch,
     if outer_build:
         # reverse probe: does any usable probe row carry this build key?
         sp_words, _ = _sort_build(p_words, p_usable, None)
-        n_probe_usable = jnp.sum(p_usable.astype(jnp.int64))
-        if len(b_words) == 1:
-            bs = jnp.searchsorted(sp_words[0], b_words[0], side="left")
-            be = jnp.searchsorted(sp_words[0], b_words[0], side="right")
-        else:
-            sp_rank, bq_rank = _pack_ranks(list(sp_words), list(b_words))
-            bs = jnp.searchsorted(sp_rank, bq_rank, side="left")
-            be = jnp.searchsorted(sp_rank, bq_rank, side="right")
-        bs = jnp.minimum(bs, n_probe_usable)
-        be = jnp.minimum(be, n_probe_usable)
+        bs, be, steps2 = _lookup(sp_words, p_usable, b_words)
+        steps = steps + steps2
         b_matched = b_usable & (be > bs)
         unmatched = build.active & ~b_matched
         u = unmatched.astype(jnp.int64)
@@ -266,7 +371,7 @@ def hash_join(probe: Batch, build: Batch,
         g = _gather(c, brow, build_valid)
         out_cols.append(g)
     out = Batch(tuple(out_cols), all_valid)
-    return JoinResult(out, total2, overflow)
+    return JoinResult(out, total2, overflow, steps)
 
 
 from ..block import gather_block as _gather  # shared row gather
@@ -276,7 +381,8 @@ from ..block import gather_block as _gather  # shared row gather
 def semi_join_mask(probe: Batch, build: Batch,
                    probe_key_channels: Sequence[int],
                    build_key_channels: Sequence[int],
-                   null_keys_match: bool = False
+                   null_keys_match: bool = False,
+                   steps_out: Optional[list] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """SemiJoinNode analog: per-probe-row 'key IN build side' with SQL
     three-valued semantics. Returns (match, null_flag):
@@ -289,7 +395,9 @@ def semi_join_mask(probe: Batch, build: Batch,
 
     With null_keys_match=True, NULL keys compare EQUAL (IS NOT DISTINCT
     FROM) and null_flag is always False -- the INTERSECT/EXCEPT and
-    mark-distinct membership semantics."""
+    mark-distinct membership semantics.
+
+    The lookup's binary-search trip count is appended to `steps_out`."""
     p_keys = [probe.column(c) for c in probe_key_channels]
     b_keys = [build.column(c) for c in build_key_channels]
     p_keys, b_keys = _align_key_widths(p_keys, b_keys)
@@ -303,16 +411,9 @@ def semi_join_mask(probe: Batch, build: Batch,
         p_words, p_usable = _combined_key(p_keys, probe.active)
         b_words, b_usable = _combined_key(b_keys, build.active)
     sb_words, _ = _sort_build(b_words, b_usable, None)
-    n_usable = jnp.sum(b_usable.astype(jnp.int64))
-    if len(p_words) == 1:
-        start = jnp.searchsorted(sb_words[0], p_words[0], side="left")
-        end = jnp.searchsorted(sb_words[0], p_words[0], side="right")
-    else:
-        b_rank, p_rank = _pack_ranks(list(sb_words), list(p_words))
-        start = jnp.searchsorted(b_rank, p_rank, side="left")
-        end = jnp.searchsorted(b_rank, p_rank, side="right")
-    start = jnp.minimum(start, n_usable)
-    end = jnp.minimum(end, n_usable)
+    start, end, steps = _lookup(sb_words, b_usable, p_words)
+    if steps_out is not None:
+        steps_out.append(steps)
     match = p_usable & (end > start)
     if null_keys_match:
         return match, jnp.zeros_like(match)

@@ -297,3 +297,45 @@ def test_a_plan_cache_hit_under_another_tag_is_not_mislabelled():
     assert fn0 is fn7 and second.get("plan_cache_hits") == 1
     assert spans0 == [("dispatch", "R0"), ("device_wait", "R0")]
     assert spans7 == [("dispatch", "R7"), ("device_wait", "R7")]
+
+
+Q6 = ("SELECT sum(extendedprice * discount) FROM lineitem "
+      "WHERE shipdate >= date '1994-01-01' AND shipdate < date '1995-01-01' "
+      "AND discount BETWEEN 0.05 AND 0.07 AND quantity < 24")
+
+
+@pytest.mark.parametrize("text,joins", [(Q3, 2), (Q6, 0)],
+                         ids=["q3", "q6"])
+def test_join_search_steps_ride_the_status_word(text, joins):
+    """The trips `_match_ranges` took leave the device in the word the
+    program already returns (bits 8 and up) and land in the statement's
+    counters over the protocol; a statement without a join has none."""
+    with StatementServer(sf=0.01) as srv:
+        stats = execute(srv.url, text).stats["queryStats"]
+    steps = stats["counters"].get("join_search_steps")
+    if not joins:
+        assert steps is None
+    else:
+        # at least a trip a join, at most a whole binary search of the
+        # largest build capacity (the default join capacity, 2**16) each
+        assert joins <= steps <= joins * 16
+    assert stats["stages"]["device_wait"]["invocations"] == \
+        stats["stages"]["dispatch"]["invocations"]
+
+
+def test_a_hard_overflow_still_reruns_under_the_steps():
+    """Bits 0-1 of the status word stay the ladder's: a join capacity
+    too small by 16x reruns twice (x4 each) and answers as the roomy
+    run does; each of the three dispatches adds its trips."""
+    text = ("SELECT count(*), sum(o.totalprice) FROM orders o "
+            "JOIN customer c ON o.custkey = c.custkey "
+            "WHERE c.nationkey < 20")
+    roomy = sql(text, sf=0.01)
+    tight = sql(text, sf=0.01, join_capacity=1024)
+    assert tight.rows() == roomy.rows()
+    assert roomy.query_stats.stages["dispatch"].invocations == 1
+    assert tight.query_stats.stages["dispatch"].invocations == 3
+    assert tight.stats["capacity_reruns"]["count"] == 2
+    once = roomy.query_stats.counters["join_search_steps"]
+    assert once >= 1
+    assert tight.query_stats.counters["join_search_steps"] == 3 * once
