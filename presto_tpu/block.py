@@ -41,7 +41,8 @@ import numpy as np
 from . import types as T
 
 __all__ = ["Column", "StringColumn", "DictionaryColumn", "Int128Column",
-           "Batch", "Block", "from_numpy", "to_numpy", "concat_batches"]
+           "Batch", "Block", "HostStrings", "from_numpy", "to_numpy",
+           "concat_batches"]
 
 
 def _register(cls, data_fields, meta_fields):
@@ -279,6 +280,120 @@ _register(Batch, ["columns", "active"], [])
 # Host <-> device staging
 # --------------------------------------------------------------------------
 
+class HostStrings:
+    """A string column on the host in the device's own encoding:
+    `chars` (n, L) uint8, zero beyond each row's length, and `lengths`
+    (n,) int32 -- what a `StringColumn` holds, as numpy. The generator
+    makes it, the memory connector stores it, staging hands it to the
+    device and a result comes back as it: no Python string per row
+    anywhere between. Python strings are made where someone asks for
+    one (`col[i]`, iteration, `np.asarray(col)`, a comparison with a
+    str): where a client's rows are rendered, and in tests. A NULL
+    row is an empty row; the null mask travels beside the column."""
+
+    __slots__ = ("chars", "lengths")
+    dtype = np.dtype(object)   # what np.asarray(col) gives
+
+    def __init__(self, chars: np.ndarray, lengths: np.ndarray):
+        self.chars = chars
+        self.lengths = lengths
+
+    @classmethod
+    def from_objects(cls, values) -> "HostStrings":
+        """Encode Python strings (None: an empty row), one at a time:
+        for what arrives as Python objects (VALUES rows, API callers)."""
+        if isinstance(values, HostStrings):
+            return values
+        encoded = [b"" if v is None else
+                   v if isinstance(v, bytes) else str(v).encode("utf-8")
+                   for v in values]
+        lengths = np.fromiter((len(b) for b in encoded), dtype=np.int32,
+                              count=len(encoded))
+        width = max(int(lengths.max()) if len(encoded) else 0, 1)
+        chars = np.zeros((len(encoded), width), dtype=np.uint8)
+        flat = np.frombuffer(b"".join(encoded), dtype=np.uint8)
+        if len(flat):
+            rows = np.repeat(np.arange(len(encoded)), lengths)
+            starts = np.cumsum(lengths) - lengths
+            chars[rows, np.arange(len(flat)) - np.repeat(starts, lengths)] = flat
+        return cls(chars, lengths)
+
+    @classmethod
+    def concat(cls, parts: Sequence["HostStrings"]) -> "HostStrings":
+        width = max((p.chars.shape[1] for p in parts), default=1)
+        n = sum(len(p) for p in parts)
+        chars = np.zeros((n, width), dtype=np.uint8)
+        at = 0
+        for p in parts:
+            chars[at:at + len(p), :p.chars.shape[1]] = p.chars
+            at += len(p)
+        return cls(chars, np.concatenate([p.lengths for p in parts])
+                   if parts else np.zeros(0, dtype=np.int32))
+
+    def __len__(self) -> int:
+        return self.chars.shape[0]
+
+    @property
+    def shape(self):
+        return (self.chars.shape[0],)
+
+    @property
+    def nbytes(self) -> int:
+        return self.chars.nbytes + self.lengths.nbytes
+
+    def __getitem__(self, key):
+        if isinstance(key, (int, np.integer)):
+            return self.chars[key, :self.lengths[key]].tobytes().decode(
+                "utf-8", "replace")
+        return HostStrings(self.chars[key], self.lengths[key])
+
+    def __iter__(self):
+        return iter(self.to_objects())
+
+    def to_objects(self) -> np.ndarray:
+        """The rows as an object array of Python str: the rendering
+        boundary. numpy's bytes view drops the zero padding in one
+        pass; a row it cuts short (a NUL inside the string) is read
+        again by its length."""
+        n, width = self.chars.shape
+        raw = np.ascontiguousarray(self.chars).view(f"S{width}").reshape(n)
+        out = np.char.decode(raw, "utf-8", "replace").astype(object) \
+            if n else np.empty(0, dtype=object)
+        for i in np.flatnonzero(np.char.str_len(raw) != self.lengths):
+            out[i] = self[int(i)]
+        return out
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.to_objects()
+        return out if dtype is None else out.astype(dtype)
+
+    def astype(self, dtype):
+        return self.to_objects().astype(dtype)
+
+    def tolist(self) -> list:
+        return self.to_objects().tolist()
+
+    def _equals(self, other) -> np.ndarray:
+        if isinstance(other, str):
+            lit = np.frombuffer(other.encode("utf-8"), dtype=np.uint8)
+            if len(lit) > self.chars.shape[1]:
+                return np.zeros(len(self), dtype=bool)
+            return (self.lengths == len(lit)) & \
+                (self.chars[:, :len(lit)] == lit).all(axis=1)
+        return self.to_objects() == np.asarray(other)
+
+    def __eq__(self, other):
+        return self._equals(other)
+
+    def __ne__(self, other):
+        return ~self._equals(other)
+
+    __hash__ = None
+
+    def __repr__(self):
+        return f"HostStrings(n={len(self)}, width={self.chars.shape[1]})"
+
+
 def _pad(arr: np.ndarray, capacity: int, fill=0) -> np.ndarray:
     n = arr.shape[0]
     if n == capacity:
@@ -305,7 +420,8 @@ def from_numpy(ty: T.Type, values: np.ndarray, nulls: Optional[np.ndarray] = Non
                capacity: Optional[int] = None,
                physical_dtype=None) -> Block:
     """Stage a host column to a device Block. For string types `values`
-    must be an object/str numpy array or a (N, L) uint8 matrix; for
+    is a `HostStrings` (staged as it is), an object/str numpy array
+    (encoded here, row by row) or a (N, L) uint8 matrix; for
     array types, an object array of Python lists (None elements = null,
     None rows = null array).
 
@@ -396,21 +512,15 @@ def from_numpy(ty: T.Type, values: np.ndarray, nulls: Optional[np.ndarray] = Non
     n = values.shape[0]
     capacity = capacity or n
     if nulls is None:
-        if values.dtype == object:
+        if values.dtype == object and not isinstance(values, HostStrings):
             nulls = np.array([v is None for v in values], dtype=bool)
         else:
             nulls = np.zeros(n, dtype=bool)
     nulls = _pad_cast(nulls, capacity, bool, fill=True)
     if ty.is_string and values.dtype != np.uint8:
-        encoded = [str(v).encode("utf-8") if v is not None else b"" for v in values]
-        max_len = max((len(b) for b in encoded), default=1) or 1
-        chars = np.zeros((n, max_len), dtype=np.uint8)
-        lengths = np.zeros(n, dtype=np.int32)
-        for i, b in enumerate(encoded):
-            chars[i, : len(b)] = np.frombuffer(b, dtype=np.uint8)
-            lengths[i] = len(b)
-        return StringColumn(jnp.asarray(_pad(chars, capacity)),
-                            jnp.asarray(_pad(lengths, capacity)),
+        enc = HostStrings.from_objects(values)  # itself, where it is one
+        return StringColumn(jnp.asarray(_pad(enc.chars, capacity)),
+                            jnp.asarray(_pad(enc.lengths, capacity)),
                             jnp.asarray(nulls), ty)
     if ty.is_string:
         # length = position after the last nonzero byte (strings may
@@ -458,8 +568,8 @@ def batch_from_numpy(types: Sequence[T.Type], arrays: Sequence[np.ndarray],
 
 
 def to_numpy(block: Block) -> Tuple[np.ndarray, np.ndarray]:
-    """Fetch (values, nulls) to host. Strings come back as an object
-    array; arrays as an object array of Python lists."""
+    """Fetch (values, nulls) to host. Strings come back as they are
+    held, a `HostStrings`; arrays as an object array of Python lists."""
     if isinstance(block, DictionaryColumn):
         return to_numpy(block.decode())
     if isinstance(block, ArrayColumn):
@@ -474,11 +584,9 @@ def to_numpy(block: Block) -> Tuple[np.ndarray, np.ndarray]:
                 for j in range(lengths[i])]
         return out, nulls
     if isinstance(block, StringColumn):
-        chars = np.asarray(block.chars)
-        lengths = np.asarray(block.lengths)
-        vals = np.array([chars[i, : lengths[i]].tobytes().decode("utf-8", "replace")
-                         for i in range(chars.shape[0])], dtype=object)
-        return vals, np.asarray(block.nulls)
+        return (HostStrings(np.asarray(block.chars),
+                            np.asarray(block.lengths)),
+                np.asarray(block.nulls))
     if isinstance(block, Int128Column):
         from .int128 import int128_to_python
         vals = int128_to_python(np.asarray(block.hi), np.asarray(block.lo))

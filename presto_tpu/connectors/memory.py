@@ -6,7 +6,10 @@ MemoryPageSinkProvider appends, reads scan the stored pages). This
 engine's version stores numpy column vectors host-side; scans stage
 them into HBM Batches exactly like the generator connectors, so the
 whole read pipeline (stats, dynamic filtering, mesh sharding) treats a
-written table no differently from tpch/tpcds.
+written table no differently from tpch/tpcds. A string column is stored
+as it is staged, bytes and lengths (`block.HostStrings`): a page that a
+writer appends and a split that a scan reads are both slices of arrays,
+with no Python string per row on either way.
 
 Write protocol (the TableWriter/TableFinish contract):
     h = begin_insert(table[, create_columns=...])   # per query
@@ -27,7 +30,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from .. import types as T
-from ..block import batch_from_numpy
+from ..block import HostStrings, batch_from_numpy
 
 __all__ = ["SCHEMA", "create_table", "drop_table", "reset",
            "table_row_count", "generate_columns", "generate_batch",
@@ -39,12 +42,13 @@ class _Table:
     def __init__(self, columns: List[str], types: List[T.Type]):
         self.columns = list(columns)
         self.types = list(types)
-        # one numpy array + null mask per column; object dtype for
-        # strings/long decimals/arrays, native dtypes otherwise
-        self.values: List[np.ndarray] = [
-            np.array([], dtype=_storage_dtype(t)) for t in types]
+        # one column + null mask per column: HostStrings for strings,
+        # object dtype for long decimals/arrays, native dtypes otherwise
+        self.values: List[np.ndarray] = [_stored(t, []) for t in types]
         self.nulls: List[np.ndarray] = [
             np.array([], dtype=bool) for _ in types]
+        # column index -> (lo, hi) or None, of the arrays now published
+        self.ranges: Dict[int, Optional[tuple]] = {}
 
     @property
     def row_count(self) -> int:
@@ -52,10 +56,22 @@ class _Table:
 
 
 def _storage_dtype(ty: T.Type):
-    if ty.is_string or ty.base in ("array", "map", "row") or \
+    if ty.base in ("array", "map", "row") or \
             (ty.is_decimal and not ty.is_short_decimal):
         return object
     return ty.to_dtype()
+
+
+def _stored(ty: T.Type, chunks: Sequence):
+    """`chunks` of one column as the one array the table keeps."""
+    if ty.is_string:
+        return HostStrings.concat(
+            [HostStrings.from_objects(c) for c in chunks])
+    dt = _storage_dtype(ty)
+    if not chunks:
+        return np.array([], dtype=dt)
+    return np.concatenate([_to_object(c) if dt == object
+                           else np.asarray(c, dtype=dt) for c in chunks])
 
 
 _lock = threading.RLock()
@@ -163,8 +179,18 @@ def generate_columns(table: str, sf: float, columns: Sequence[str],
         out = {}
         for c in columns:
             i = t.columns.index(c)
-            out[c] = t.values[i][start:start + count].copy()
+            out[c] = _view(t.values[i][start:start + count])
         return out
+
+
+def _view(col):
+    """A scan's slice of a published column. Published arrays are
+    replaced (finish_insert, replace_table) and never written, so a
+    scan reads them in place: the view is read-only, and a caller that
+    would write gets numpy's error instead of another table's rows."""
+    if isinstance(col, np.ndarray):
+        col.flags.writeable = False
+    return col
 
 
 def column_range(table: str, column: str, sf: float = 0.0):
@@ -172,20 +198,28 @@ def column_range(table: str, column: str, sf: float = 0.0):
     execution stats). None for empty/all-null/non-integer columns --
     width inference then refuses to narrow. Exact at plan time; the
     staging-time guard (plan/widths.checked_physical_dtypes) covers
-    any write racing plan and execution."""
+    any write racing plan and execution. Read once per published
+    column: every publish (finish_insert, replace_table) starts the
+    table's ranges anew."""
     with _lock:
         t = _tables.get(table)
         if t is None:
             raise KeyError(f"no memory table {table!r}")
         i = t.columns.index(column)
+        if i in t.ranges:
+            return t.ranges[i]
         vals = t.values[i]
         nulls = t.nulls[i]
-    if vals.dtype == object or vals.dtype.kind not in "iu":
-        return None
-    live = vals[~nulls]
-    if not len(live):
-        return None
-    return (int(live.min()), int(live.max()))
+        ranges = t.ranges
+    found = None
+    if vals.dtype != object and vals.dtype.kind in "iu":
+        live = vals[~nulls] if nulls.any() else vals
+        if len(live):
+            found = (int(live.min()), int(live.max()))
+    with _lock:
+        if t.ranges is ranges:  # no publish since the arrays were read
+            ranges[i] = found
+    return found
 
 
 def generate_nulls(table: str, columns: Sequence[str], start: int = 0,
@@ -194,7 +228,7 @@ def generate_nulls(table: str, columns: Sequence[str], start: int = 0,
         t = _tables[table]
         n = t.row_count
         count = n - start if count is None else count
-        return {c: t.nulls[t.columns.index(c)][start:start + count].copy()
+        return {c: _view(t.nulls[t.columns.index(c)][start:start + count])
                 for c in columns}
 
 
@@ -253,7 +287,9 @@ def append(handle: str, columns: Sequence[np.ndarray],
                 f"{len(t.columns)}")
         n = len(columns[0]) if len(columns) else 0
         for i, col in enumerate(columns):
-            st["values"][i].append(np.asarray(col))
+            st["values"][i].append(
+                HostStrings.from_objects(col) if t.types[i].is_string
+                else np.asarray(col))
             st["nulls"][i].append(
                 np.asarray(nulls[i], dtype=bool) if nulls is not None
                 else np.zeros(n, dtype=bool))
@@ -261,25 +297,23 @@ def append(handle: str, columns: Sequence[np.ndarray],
 
 
 def finish_insert(handle: str) -> int:
-    """Atomic publish of every staged chunk; returns rows written."""
+    """Atomic publish of every staged chunk; returns rows written.
+    Column by column, each column's chunks let go as soon as they are
+    one array: the peak is the table and one column more."""
     with _lock:
         table = _pending[handle]["table"]
     with write_lock(table), _lock:
         st = _pending.pop(handle)
         t = _tables[st["table"]]
-        rows = 0
-        for i in range(len(t.columns)):
-            chunks = st["values"][i]
-            if not chunks:
-                continue
-            add = np.concatenate([np.asarray(c, dtype=t.values[i].dtype)
-                                  for c in chunks]) \
-                if t.values[i].dtype != object else \
-                np.concatenate([_to_object(c) for c in chunks])
-            t.values[i] = np.concatenate([t.values[i], add])
-            t.nulls[i] = np.concatenate(
-                [t.nulls[i], np.concatenate(st["nulls"][i])])
         rows = sum(len(c) for c in st["values"][0]) if t.columns else 0
+        values, nulls = [], []
+        for i, ty in enumerate(t.types):
+            chunks, st["values"][i] = st["values"][i], None
+            values.append(_stored(ty, [t.values[i]] + chunks)
+                          if chunks else t.values[i])
+            nulls.append(np.concatenate([t.nulls[i]] + st["nulls"][i])
+                         if chunks else t.nulls[i])
+        t.values, t.nulls, t.ranges = values, nulls, {}
         _bump_version(st["table"])
         return rows
 
@@ -314,13 +348,9 @@ def replace_table(name: str, columns: Sequence[np.ndarray],
                 f"rewrite arity {len(columns)} != table arity "
                 f"{len(t.columns)}")
         old = t.row_count
-        for i in range(len(t.columns)):
-            if t.values[i].dtype == object:
-                t.values[i] = _to_object(columns[i])
-            else:
-                t.values[i] = np.asarray(columns[i],
-                                         dtype=t.values[i].dtype)
-            t.nulls[i] = np.asarray(nulls[i], dtype=bool)
+        t.values = [_stored(ty, [c]) for ty, c in zip(t.types, columns)]
+        t.nulls = [np.asarray(n, dtype=bool) for n in nulls]
+        t.ranges = {}
         _bump_version(name)
         return old
 

@@ -16,7 +16,10 @@ lines per order -- the spec's 1..7 average 4; fixed fan-out keeps row
 ranges addressable in O(1)). Value distributions (dates, quantities,
 discounts, return flags) follow the spec's ranges so the standard
 queries' selectivities are realistic; string columns (comments, names)
-are dictionary-encoded deterministic phrases, not dbgen grammar text.
+are deterministic phrases, not dbgen grammar text. A string column is
+made as it is held everywhere below the client: a `HostStrings` (bytes
+and lengths), by row gathers of the encoded choices and vectorised
+digit formatting; no Python string per row.
 
 Decimals are generated as scaled int64 (cents) matching
 presto_tpu.types decimal mapping.
@@ -24,13 +27,16 @@ presto_tpu.types decimal mapping.
 
 from __future__ import annotations
 
+import functools
+import os
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ... import types as T
-from ...block import Batch, batch_from_numpy
+from ...block import Batch, HostStrings, batch_from_numpy
 
 # ---------------------------------------------------------------------------
 # Schema (TPC-H spec 1.4; types as Presto's tpch connector exposes them)
@@ -177,23 +183,86 @@ def _uniform(table, column, idx, lo, hi):
     return (_h(table, column, idx) % np.uint64(hi - lo + 1)).astype(np.int64) + lo
 
 
-def _strings(values: Sequence[str]) -> np.ndarray:
-    return np.array(values, dtype=object)
+@functools.lru_cache(maxsize=None)
+def _encoded(choices: Tuple[str, ...]) -> HostStrings:
+    return HostStrings.from_objects(choices)
 
 
-def _pick(table, column, idx, choices: Sequence[str]) -> np.ndarray:
-    codes = (_h(table, column, idx) % np.uint64(len(choices))).astype(np.int64)
-    return _strings(choices)[codes]
+def _choose(choices: Sequence[str], codes: np.ndarray) -> HostStrings:
+    """Row i is choices[codes[i]]."""
+    return _encoded(tuple(choices))[codes]
 
 
-def _comment(table, idx, nwords=4, max_chars: Optional[int] = None) -> np.ndarray:
-    parts = [_pick(table, f"comment{k}", idx, _COMMENT_WORDS) for k in range(nwords)]
-    out = parts[0].astype(str)
-    for p in parts[1:]:
-        out = np.char.add(np.char.add(out, " "), p.astype(str))
-    if max_chars is not None:
-        out = out.astype(f"<U{max_chars}")  # dbgen-style truncation to the declared width
-    return out.astype(object)
+def _codes(table, column, idx, n: int) -> np.ndarray:
+    return (_h(table, column, idx) % np.uint64(n)).astype(np.int64)
+
+
+def _pick(table, column, idx, choices: Sequence[str]) -> HostStrings:
+    return _choose(choices, _codes(table, column, idx, len(choices)))
+
+
+@functools.lru_cache(maxsize=None)
+def _phrases(nwords: int) -> Tuple[str, ...]:
+    """Every phrase of `nwords` comment words, the first word slowest."""
+    out = ("",)
+    for _ in range(nwords):
+        out = tuple(f"{p} {w}" if p else w
+                    for p in out for w in _COMMENT_WORDS)
+    return out
+
+
+def _joined(left: HostStrings, right: HostStrings) -> HostStrings:
+    """left + ' ' + right, row by row. Rows of one left length move as
+    one block, and a phrase has few lengths."""
+    n, wl = left.chars.shape
+    wr = right.chars.shape[1]
+    chars = np.zeros((n, wl + 1 + wr), dtype=np.uint8)
+    chars[:, :wl] = left.chars
+    for at in np.unique(left.lengths):
+        rows = np.flatnonzero(left.lengths == at)
+        chars[rows, at] = ord(" ")
+        chars[rows, at + 1:at + 1 + wr] = right.chars[rows]
+    return HostStrings(chars, left.lengths + 1 + right.lengths)
+
+
+def _comment(table, idx, nwords=4, max_chars: Optional[int] = None
+             ) -> HostStrings:
+    """`nwords` hashed words joined by spaces, cut to `max_chars` as
+    dbgen cuts to the declared width. Up to three words at a time are
+    one gather from the table of their phrases (29**3 rows)."""
+    nw = len(_COMMENT_WORDS)
+    out = None
+    for k0 in range(0, nwords, 3):
+        code = np.zeros(len(idx), dtype=np.int64)
+        take = min(3, nwords - k0)
+        for k in range(k0, k0 + take):
+            code = code * nw + _codes(table, f"comment{k}", idx, nw)
+        part = _choose(_phrases(take), code)
+        out = part if out is None else _joined(out, part)
+    if max_chars is not None and out.chars.shape[1] > max_chars:
+        out = HostStrings(np.ascontiguousarray(out.chars[:, :max_chars]),
+                          np.minimum(out.lengths, max_chars))
+    return out
+
+
+def _digits(num: np.ndarray, width: int) -> np.ndarray:
+    """(n, width) ASCII digits of `num`, zero-filled on the left."""
+    num = num.astype(np.int64)
+    if len(num) and int(num.max()) >= 10 ** width:
+        raise ValueError(f"{int(num.max())} needs more than {width} digits")
+    powers = 10 ** np.arange(width - 1, -1, -1, dtype=np.int64)
+    return ((num[:, None] // powers) % 10 + ord("0")).astype(np.uint8)
+
+
+def _fixed(n: int, *pieces) -> HostStrings:
+    """Rows of one width from pieces laid side by side: a str is the
+    same bytes in every row, an (n, w) uint8 matrix a column of its
+    own."""
+    cols = [np.broadcast_to(np.frombuffer(p.encode(), dtype=np.uint8),
+                            (n, len(p.encode())))
+            if isinstance(p, str) else p for p in pieces]
+    chars = np.concatenate(cols, axis=1)
+    return HostStrings(chars, np.full(n, chars.shape[1], dtype=np.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -210,25 +279,20 @@ def _retail_price(pkey: np.ndarray) -> np.ndarray:
     return (90000 + (pkey % 200001) + 100 * (pkey % 1000)).astype(np.int64)
 
 
-def _numbered(prefix: str, num: np.ndarray, width: int = 9) -> np.ndarray:
+def _numbered(prefix: str, num: np.ndarray, width: int = 9) -> HostStrings:
     """Vectorized 'Prefix#000000042' formatting."""
-    digits = np.char.zfill(num.astype(np.int64).astype(str), width)
-    return np.char.add(f"{prefix}#", digits).astype(object)
+    return _fixed(len(num), f"{prefix}#", _digits(num, width))
 
 
-def _phone(table: str, idx: np.ndarray) -> np.ndarray:
+def _phone(table: str, idx: np.ndarray) -> HostStrings:
     """Spec: country code = nationkey + 10 (uses the SAME nationkey hash as
     the table's nationkey column so phone and nationkey stay consistent)."""
     nk = _uniform(table, "nationkey", idx, 0, 24)
     h = _h(table, "phone", idx).astype(np.int64)
-    cc = (10 + nk).astype(str)
-    p1 = (h % 900 + 100).astype(str)
-    p2 = ((h >> 10) % 900 + 100).astype(str)
-    p3 = ((h >> 20) % 9000 + 1000).astype(str)
-    out = cc
-    for part in (p1, p2, p3):
-        out = np.char.add(np.char.add(out, "-"), part)
-    return out.astype(object)
+    return _fixed(len(idx), _digits(10 + nk, 2), "-",
+                  _digits(h % 900 + 100, 3), "-",
+                  _digits((h >> 10) % 900 + 100, 3), "-",
+                  _digits((h >> 20) % 9000 + 1000, 4))
 
 
 def _gen_lineitem(column: str, idx: np.ndarray, sf: float) -> np.ndarray:
@@ -266,10 +330,12 @@ def _gen_lineitem(column: str, idx: np.ndarray, sf: float) -> np.ndarray:
         if column == "receiptdate":
             return receipt.astype(np.int32)
         if column == "returnflag":
-            ra = _pick("lineitem", "returnflag", idx, ["R", "A"])
-            return np.where(receipt <= _CUTOFF_1995_06_17, ra, "N").astype(object)
+            ra = _codes("lineitem", "returnflag", idx, 2)
+            return _choose(["R", "A", "N"],
+                           np.where(receipt <= _CUTOFF_1995_06_17, ra, 2))
         if column == "linestatus":
-            return np.where(ship > _CUTOFF_1995_06_17, "O", "F").astype(object)
+            return _choose(["O", "F"],
+                           np.where(ship > _CUTOFF_1995_06_17, 0, 1))
     if column == "shipinstruct":
         return _pick("lineitem", "shipinstruct", idx, _INSTRUCTS)
     if column == "shipmode":
@@ -333,11 +399,12 @@ def _gen_part(column: str, idx: np.ndarray, sf: float) -> np.ndarray:
         return _comment("part", idx, 3)
     if column == "mfgr":
         m = _uniform("part", "mfgr", idx, 1, 5)
-        return np.array([f"Manufacturer#{v}" for v in m], dtype=object)
+        return _choose([f"Manufacturer#{v}" for v in range(1, 6)], m - 1)
     if column == "brand":
         m = _uniform("part", "mfgr", idx, 1, 5)
         b = _uniform("part", "brand", idx, 1, 5)
-        return np.array([f"Brand#{mm}{bb}" for mm, bb in zip(m, b)], dtype=object)
+        return _choose([f"Brand#{mm}{bb}" for mm in range(1, 6)
+                        for bb in range(1, 6)], (m - 1) * 5 + (b - 1))
     if column == "type":
         return _pick("part", "type", idx, P_TYPES)
     if column == "size":
@@ -390,7 +457,7 @@ def _gen_nation(column: str, idx: np.ndarray, sf: float) -> np.ndarray:
     if column == "nationkey":
         return idx.astype(np.int64)
     if column == "name":
-        return _strings(_NATIONS)[idx]
+        return _choose(_NATIONS, idx)
     if column == "regionkey":
         return np.array(_NATION_REGION, dtype=np.int64)[idx]
     if column == "comment":
@@ -402,7 +469,7 @@ def _gen_region(column: str, idx: np.ndarray, sf: float) -> np.ndarray:
     if column == "regionkey":
         return idx.astype(np.int64)
     if column == "name":
-        return _strings(_REGIONS)[idx]
+        return _choose(_REGIONS, idx)
     if column == "comment":
         return _comment("region", idx, 4)
     raise KeyError(f"region.{column}")
@@ -419,17 +486,54 @@ _GENERATORS = {
 # Public API
 # ---------------------------------------------------------------------------
 
+_CHUNK_ROWS = 1 << 19   # rows a task makes at a time: its temporaries are
+_THREADS = min(8, os.cpu_count() or 1)  # a few MB, which the allocator reuses
+
+
 def generate_columns(table: str, sf: float, columns: Sequence[str],
                      start: int = 0, count: Optional[int] = None
                      ) -> Dict[str, np.ndarray]:
-    """Generate host columns for rows [start, start+count) of `table`."""
+    """Generate host columns for rows [start, start+count) of `table`.
+    A large split is made in chunks of rows, side by side on a few
+    threads (a column is numpy passes over the row indices, which
+    release the interpreter lock), each chunk written into its place
+    in the split's columns: the passes' temporaries stay small enough
+    to be reused, where a whole split's would each be fresh memory."""
     total = table_row_count(table, sf)
     if count is None:
         count = total - start
     assert 0 <= start and start + count <= total, (start, count, total)
-    idx = np.arange(start, start + count, dtype=np.int64)
     gen = _GENERATORS[table]
-    return {c: gen(c, idx, sf) for c in columns}
+
+    def make(lo: int, n: int) -> List[np.ndarray]:
+        idx = np.arange(lo, lo + n, dtype=np.int64)
+        return [gen(c, idx, sf) for c in columns]
+
+    if count <= _CHUNK_ROWS:
+        return dict(zip(columns, make(start, count)))
+    out = [_like(one, count) for one in make(start, 1)]
+
+    def fill(at: int) -> None:
+        n = min(_CHUNK_ROWS, count - at)
+        for whole, part in zip(out, make(start + at, n)):
+            if isinstance(whole, HostStrings):
+                whole.chars[at:at + n] = part.chars
+                whole.lengths[at:at + n] = part.lengths
+            else:
+                whole[at:at + n] = part
+    with ThreadPoolExecutor(_THREADS) as pool:
+        list(pool.map(fill, range(0, count, _CHUNK_ROWS)))
+    return dict(zip(columns, out))
+
+
+def _like(one, count: int):
+    """An uninitialised column of `count` rows of the kind of `one`. A
+    string column's width is its generator's, whatever the rows."""
+    if isinstance(one, HostStrings):
+        return HostStrings(
+            np.empty((count, one.chars.shape[1]), dtype=np.uint8),
+            np.empty(count, dtype=np.int32))
+    return np.empty(count, dtype=one.dtype)
 
 
 def generate_batch(table: str, sf: float, columns: Sequence[str],

@@ -70,6 +70,10 @@ class CompiledPlan:
     # output names) through THIS tree, not the structurally-equal twin
     # the caller handed in (ids differ across plannings)
     root: "N.PlanNode" = None
+    # argument shapes -> the device memory XLA planned for the
+    # executable those shapes compiled to (runner._program_hbm_bytes):
+    # kept here so that it lives and dies with the cached executable
+    hbm_bytes: Dict[tuple, int] = dataclasses.field(default_factory=dict)
 
 
 def _collect_scans(node: N.PlanNode, out: List[N.PlanNode], _seen=None):

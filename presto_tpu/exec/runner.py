@@ -15,6 +15,7 @@ without changing lowered kernels.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -28,7 +29,7 @@ from ..block import Batch, batch_from_numpy, to_numpy
 from ..plan import nodes as N
 from .planner import compile_plan, split_flags
 from .stats import (QueryStats, RuntimeStats, StatsCollector, joining, note,
-                    stage)
+                    note_max, span, stage)
 
 __all__ = ["run_query", "prepare_plan", "QueryResult"]
 
@@ -188,7 +189,9 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
                                              dyn_filters)
         if stats is not None:
             stats.add("dynamic_filter_rows_pruned", pruned)
-            stats.add("dynamic_filter_rows_staged", int(keep.sum()))
+            stats.add("dynamic_filter_rows_staged", len(keep) - pruned)
+        if not pruned:
+            keep = slice(None)  # every row stays: nothing to copy out
         arrays = [data[c][keep] for c in node.columns]
         tys = node.column_types
         nrows = len(arrays[0])
@@ -252,6 +255,7 @@ def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
     def _session_on(name: str) -> bool:
         return session_flag(session, name, True)
 
+    _check_schemas(root, sf)
     # rule-based simplification + channel pruning (IterativeOptimizer /
     # PruneUnreferencedOutputs analog): narrows intermediates before
     # stats and distribution decide capacities and exchange widths
@@ -318,6 +322,22 @@ def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
     from .accuracy import stamp_estimates
     stamp_estimates(root, sf)
     return root
+
+
+def _check_schemas(root: N.PlanNode, sf: float) -> None:
+    """A scan that named a schema (`tpch.sf10.lineitem`) is held to the
+    scale this run serves; the connector raises where they differ."""
+    from ..connectors import catalog
+    seen: set = set()
+    todo = [root]
+    while todo:
+        n = todo.pop()
+        if id(n) in seen:
+            continue
+        seen.add(id(n))
+        if isinstance(n, N.TableScanNode) and n.schema is not None:
+            catalog(n.connector).check_schema(n.schema, sf)
+        todo.extend(n.sources)
 
 
 def run_query(root: N.PlanNode, sf: float = 0.01, mesh=None,
@@ -491,10 +511,12 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     remote_sources = remote_sources or {}
     # Compiled-plan cache (exec/plan_cache.py): repeat submissions of a
     # structurally identical plan reuse the jitted executable instead of
-    # re-tracing + re-compiling. Per-node-id kwargs (hints/ranges/remote
-    # sources) refer to THIS plan object's ids, which a cached plan does
-    # not share -- those callers (the fragment tier) compile fresh.
-    use_cache = not hints and not scan_ranges and not remote_sources
+    # re-tracing + re-compiling. Remote sources refer to THIS plan
+    # object's ids, which a cached plan does not share -- those callers
+    # (the fragment tier) compile fresh. Capacity hints and scan ranges
+    # name scans only, and a scan is the same leaf, by position, in
+    # every plan of one fingerprint: they follow the cached plan below.
+    use_cache = not remote_sources
     # Pipeline-region partition (exec/regions.py): the prepared plan
     # becomes 1..N regions, each staged as ONE XLA program. With fusion
     # on and nothing refused/demoted this is a single region -- the
@@ -536,12 +558,20 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
             from .planner import _collect_scans
             _collect_scans(root, scan_leaves)
         elif use_cache:
+            named: List[N.PlanNode] = []
+            if hints or scan_ranges:
+                from .planner import _collect_scans
+                _collect_scans(root, named)
             plan, jfn, call_lock = _compile_any(
                 root, mesh, default_join_capacity, 1, True)
             # canonical tree: node ids match plan.scan_nodes
             root = plan.root
             fp = plan_fingerprint(root)
             scan_leaves = plan.scan_nodes
+            ids = {mine.id: kept.id for mine, kept in zip(named, scan_leaves)}
+            hints = {ids[k]: v for k, v in hints.items() if k in ids}
+            scan_ranges = {ids[k]: v for k, v in scan_ranges.items()
+                           if k in ids}
         else:
             plan, jfn, call_lock = _compile_any(
                 root, mesh, default_join_capacity, 1, False)
@@ -849,6 +879,35 @@ def _read_status(word) -> int:
     return flags
 
 
+def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
+    """Device memory of the program `dispatch_fn` has just run on
+    `batches`: its arguments, outputs and temporaries as XLA's memory
+    analysis of the executable gives them, aliased bytes counted once.
+    Lowering the call again finds the executable jit keeps for these
+    shapes (no trace, no compile), and the answer stays with the
+    compiled plan. Where the executable gives no analysis (one read
+    from a compile cache may not), the allocator's peak: then the
+    process's peak so far, not this program's."""
+    key = tuple((x.shape, str(x.dtype))
+                for x in jax.tree_util.tree_leaves(batches))
+    if key not in plan.hbm_bytes:
+        found = 0
+        try:
+            with call_lock if call_lock is not None \
+                    else contextlib.nullcontext():
+                ma = dispatch_fn.lower(tuple(batches)).compile() \
+                    .memory_analysis()
+            found = int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+                        + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+        except Exception:  # noqa: BLE001 - a backend without the analysis
+            pass
+        if found <= 0:
+            found = int((jax.devices()[0].memory_stats() or {}).get(
+                "peak_bytes_in_use", 0))
+        plan.hbm_bytes[key] = found
+    return plan.hbm_bytes[key]
+
+
 def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                      mesh, default_join_capacity: int, use_cache: bool,
                      fp: Optional[str], stats, adaptive_off: bool,
@@ -888,6 +947,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
         stats.add("capacity_feedback_scale", cap_scale)
     from .datapath import now_us as _now_us
     region = {"region": tag}
+    note("capacity_reruns", 0)  # in every statement's counters, 0 too
     while True:
         t_disp0 = _now_us()
         with stage("dispatch", region):
@@ -907,6 +967,8 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # the monotonic now_us clock the timeline intervals share
             device_s += (_now_us() - t_disp0) / 1e6
             flags = _read_status(overflow)
+        note_max("program_hbm_bytes",
+                 _program_hbm_bytes(plan, dispatch_fn, call_lock, batches))
         if prog is not None:  # each landed dispatch advances
             prog.advance()
         if flags == 0:
@@ -931,6 +993,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             from ..plan.stats import scale_capacities
             cap_scale *= 4
             stats.add("capacity_reruns", 1)
+            note("capacity_reruns")
             exec_root = scale_capacities(root, cap_scale)
             scale = 1
             plan, jfn, call_lock = _compile_any(
@@ -1379,17 +1442,13 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
         return _count_result(affected)
 
     if isinstance(node, N.TableWriterNode):
-        res = run_query(N.OutputNode(node.source, node.column_names), **kw)
         mod = catalog(node.connector)
         h = mod.begin_insert(node.table)
         try:
-            with stage("write"):
-                mod.append(h, res.columns, res.nulls)
-                rows = mod.finish_insert(h)
+            return _count_result(_write_pages(mod, h, node, kw))
         except BaseException:
             mod.abort_insert(h)
             raise
-        return _count_result(rows)
 
     finish: N.TableFinishNode = node
     mod = catalog(finish.connector)
@@ -1404,20 +1463,86 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
             create_columns=finish.create_columns if finish.create else None,
             create_types=finish.create_types if finish.create else None)
         try:
-            res = run_query(N.OutputNode(src.source, src.column_names),
-                            **kw)
-            with stage("write"):
-                mod.append(h, res.columns, res.nulls)
-                rows = mod.finish_insert(h)
+            return _count_result(_write_pages(mod, h, src, kw))
         except BaseException:
             mod.abort_insert(h)
             raise
-        return _count_result(rows)
     # distributed finish: the source plan delivers per-task counts
     res = run_query(N.OutputNode(finish.source, ["rows"]), **kw)
     total = int(sum(int(v) for v, nl in zip(res.columns[0], res.nulls[0])
                     if not nl))
     return _count_result(total)
+
+
+# A page of a paged write may plan this share of the device's memory:
+# the program holds the page as its input and again as its output, XLA
+# its temporaries, and the next page is staged before the last is freed.
+_WRITE_PAGE_SHARE = 8
+
+
+def _write_page_ranges(select: N.OutputNode, kw) -> List[Optional[dict]]:
+    """The pages a writer's SELECT is run in, each the `scan_ranges` and
+    `capacity_hints` of one `run_query`: `[None]`, one page that is the
+    whole SELECT, wherever its source fits beside the program or cannot
+    be cut; else row ranges of its one scan, all of one capacity so that
+    every page runs the same program. What can be cut is a scan under
+    projections and filters: a row's output depends on that row alone.
+    What fits is read from what the engine can observe: the scan's
+    planned bytes against `hbm_budget_bytes` where the caller or the
+    session set one, else against the device's own limit."""
+    scan = select.source
+    while isinstance(scan, (N.ProjectNode, N.FilterNode)):
+        scan = scan.source
+    session = kw.get("session")
+    budget = kw.get("hbm_budget_bytes") or \
+        (session.get("hbm_budget_bytes") if session is not None else None)
+    if not budget:
+        budget = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if not isinstance(scan, N.TableScanNode) or not budget \
+            or kw.get("mesh") is not None or kw.get("scan_ranges") \
+            or kw.get("split_rows") is not None:
+        return [None]
+    from ..connectors import catalog
+    rows = catalog(scan.connector).table_row_count(scan.table, kw["sf"])
+    planned = _planned_scan_bytes(scan, kw["sf"], None, 8, None, {})
+    page_bytes = int(budget) // _WRITE_PAGE_SHARE
+    if planned <= page_bytes or rows <= 8:
+        return [None]
+    per_row = -(-planned // max(rows, 1))
+    page_rows = max(page_bytes // per_row // 8 * 8, 8)
+    return [{"scan_ranges": {scan.id: (at, min(page_rows, rows - at))},
+             "capacity_hints": {scan.id: page_rows}}
+            for at in range(0, rows, page_rows)]
+
+
+def _write_pages(mod, handle: str, writer: N.TableWriterNode, kw) -> int:
+    """The one write path: the writer's SELECT, page by page, into the
+    sink's staged handle, then the publish. A table that fits is the
+    case of one page. Each page's host side is a ``write`` stage with
+    a ``write.page`` span under it, the last one's with the publish
+    (``write.publish``) too; the SELECT's own stages are their siblings.
+    Nothing is visible to a reader before ``finish_insert``; the
+    caller aborts the handle if any page raises."""
+    select = N.OutputNode(writer.source, writer.column_names)
+    rows = nbytes = 0
+    pages = _write_page_ranges(select, kw)
+    for k, page in enumerate(pages):
+        res = run_query(select, **{**kw, **(page or {})})
+        size = _result_bytes(res)
+        with stage("write"):
+            with span("write.page", {"page": k, "rows": res.row_count,
+                                     "bytes": size}):
+                mod.append(handle, res.columns, res.nulls)
+            if k == len(pages) - 1:
+                with span("write.publish"):
+                    published = mod.finish_insert(handle)
+        rows += res.row_count
+        nbytes += size
+        del res
+    note("write_pages", len(pages))
+    note("write_rows", rows)
+    note("write_bytes", nbytes)
+    return published
 
 
 def _planned_scan_bytes(node: N.PlanNode, sf: float,
@@ -1455,6 +1580,11 @@ def _planned_scan_bytes(node: N.PlanNode, sf: float,
 def _batch_to_result(out: Batch, root: N.PlanNode) -> QueryResult:
     act = np.asarray(out.active)
     idx = np.nonzero(act)[0]
+    live = len(idx)
+    if live and idx[-1] == live - 1:
+        # the live rows lead (a scan's page, a sorted or compacted
+        # result): a slice, where picking them would copy each column
+        idx = slice(0, live)
     cols, nulls, types = [], [], []
     for c in range(out.num_columns):
         v, n = to_numpy(out.column(c))
@@ -1472,4 +1602,4 @@ def _batch_to_result(out: Batch, root: N.PlanNode) -> QueryResult:
         types.append(ty)
     names = root.names if isinstance(root, N.OutputNode) else \
         [f"col{i}" for i in range(out.num_columns)]
-    return QueryResult(cols, nulls, names, len(idx), types=types)
+    return QueryResult(cols, nulls, names, live, types=types)

@@ -53,7 +53,7 @@ from .timeline import TimelineSlice
 
 __all__ = ["RuntimeStats", "timed", "OperatorStats", "StageStats",
            "QueryStats", "StatsCollector", "current_collector",
-           "collecting", "joining", "stage", "span", "note"]
+           "collecting", "joining", "stage", "span", "note", "note_max"]
 
 
 @dataclasses.dataclass
@@ -431,6 +431,14 @@ class StatsCollector:
             self.stats.counters[name] = \
                 self.stats.counters.get(name, 0) + delta
 
+    def note_max(self, name: str, value: int) -> None:
+        """Raise a counter to `value` where it is lower: a statement's
+        largest of something (``program_hbm_bytes``). Across tasks the
+        merge law still adds."""
+        with self._lock:
+            self.stats.counters[name] = \
+                max(self.stats.counters.get(name, 0), value)
+
     def close(self, trace=None) -> None:
         """Called once, by whoever created the collector, when the
         statement's last span has closed. Ships the collected spans
@@ -544,6 +552,13 @@ def note(name: str, delta: int = 1) -> None:
     c = current_collector()
     if c is not None:
         c.note(name, delta)
+
+
+def note_max(name: str, value: int) -> None:
+    """Raise a counter of the ambient collector, where there is one."""
+    c = current_collector()
+    if c is not None:
+        c.note_max(name, value)
 
 
 class collecting:

@@ -71,6 +71,10 @@ class TableScanNode(PlanNode):
     # connector range statistics. Staging honors these; every compute
     # site widens before arithmetic, so results stay bit-exact
     physical_dtypes: object = None
+    # the schema the statement named (`tpch.sf10.lineitem`), where it
+    # named one: checked against the scale the server serves when the
+    # plan is prepared (connectors/tpch.check_schema)
+    schema: Optional[str] = None
 
     def output_types(self):
         return list(self.column_types)
@@ -577,6 +581,8 @@ def to_json(n: PlanNode) -> dict:
             j["pushdown"] = list(n.pushdown)
         if n.physical_dtypes is not None:
             j["physicalDtypes"] = list(n.physical_dtypes)
+        if n.schema is not None:
+            j["schema"] = n.schema
         return j
     if isinstance(n, RemoteSourceNode):
         return {**base, "@type": "remotesource",
@@ -692,7 +698,7 @@ def from_json(j: dict) -> PlanNode:
                              [T.parse_type(s) for s in j["columnTypes"]],
                              pushdown=tuple(pd) if pd else None,
                              physical_dtypes=tuple(phys) if phys else None,
-                             **kw)
+                             schema=j.get("schema"), **kw)
     if t == "remotesource":
         return RemoteSourceNode([T.parse_type(s) for s in j["types"]],
                                 j["fragmentId"], **kw)

@@ -206,7 +206,8 @@ def checked_physical_dtypes(phys: Sequence[Optional[str]],
             out.append(None)
             continue
         live = arr
-        if nulls is not None and nulls[i] is not None:
+        if nulls is not None and nulls[i] is not None \
+                and np.any(nulls[i]):  # no null: no copy to look through
             live = arr[~np.asarray(nulls[i], dtype=bool)]
             if not len(live):
                 out.append(dt)  # all-null: any lane holds the mask

@@ -1080,19 +1080,26 @@ def _plan_query(q: P.Query, max_groups: int = 1 << 16,
         # name ("memory.t") names the catalog explicitly.
         from ..connectors import catalogs
         cats = catalogs()
+        # "tpch.sf10.lineitem" names a schema too, where the catalog
+        # has schemas: the scan keeps it, and it is held against the
+        # scale the server serves when the plan is prepared.
         if "." in name:
             cat, bare = name.split(".", 1)
             if cat not in cats:
                 raise KeyError(f"unknown catalog {cat!r}")
             sch = cats[cat].SCHEMA
+            schema = None
+            if "." in bare and hasattr(cats[cat], "schema_scale"):
+                schema, bare = bare.split(".", 1)
+                cats[cat].schema_scale(schema)  # KeyError: no such schema
             if bare not in sch:
                 raise KeyError(f"table {bare!r} not in catalog {cat!r}")
-            return cat, bare, dict(sch[bare])
+            return cat, bare, dict(sch[bare]), schema
         search_path = _SEARCH_PATH.get()
         for cat in search_path:
             sch = cats[cat].SCHEMA
             if name in sch:
-                return cat, name, dict(sch[name])
+                return cat, name, dict(sch[name]), None
         raise KeyError(f"table {name!r} not found in catalogs {search_path}")
 
     table_catalog = {}
@@ -1127,8 +1134,8 @@ def _plan_query(q: P.Query, max_groups: int = 1 << 16,
             table_schemas[t.name] = {}
             derived_plans[t.name] = (N.ValuesNode([], [[]]), [])
         else:
-            cat, bare, sch = find_table(t.name)
-            table_catalog[t.name] = (cat, bare)
+            cat, bare, sch, schema = find_table(t.name)
+            table_catalog[t.name] = (cat, bare, schema)
             table_schemas[t.name] = sch
 
     referenced: Dict[str, List[str]] = {t.name: [] for t in tables}
@@ -1300,8 +1307,8 @@ def _plan_query(q: P.Query, max_groups: int = 1 << 16,
             return sub_node, sub_cols, tys
         cols = referenced[t.name] or [next(iter(table_schemas[t.name]))]
         tys = [table_schemas[t.name][c] for c in cols]
-        cat, bare = table_catalog[t.name]
-        return (N.TableScanNode(cat, bare, cols, tys),
+        cat, bare, schema = table_catalog[t.name]
+        return (N.TableScanNode(cat, bare, cols, tys, schema=schema),
                 cols, tys)
 
     def scan_planned(t: P.TableRef):
@@ -1342,7 +1349,7 @@ def _plan_query(q: P.Query, max_groups: int = 1 << 16,
                 return 0.0
             from ..connectors import catalogs as _cats
             try:
-                cat, bare = table_catalog[t.name]
+                cat, bare, _schema = table_catalog[t.name]
                 return float(_cats()[cat].table_row_count(bare, 1.0))
             except Exception:
                 return 1.0
