@@ -842,6 +842,7 @@ class BatchingExecutor:
                 prog.advance(stage="execute")
                 with call_lock:
                     out, overflow = fn(tuple(batches), params)
+                    expand_steps = plan.expand_steps_of(batches)
                 jax.block_until_ready(out)
             finally:
                 prog.release(state="FINISHED")
@@ -862,7 +863,7 @@ class BatchingExecutor:
             self._serial_fallback(entries, sf, "error")
             return
         device_us = int((time.time() - t0) * 1e6)
-        self._fan_out(out, plan, entries, device_us, steps)
+        self._fan_out(out, plan, entries, device_us, steps, expand_steps)
         self._account(key, entries, device_us)
 
     def _stage_inputs(self, key, plan, sf: float) -> list:
@@ -927,7 +928,7 @@ class BatchingExecutor:
         return tuple(out)
 
     def _fan_out(self, out, plan, entries: List[_Pending],
-                 device_us: int, search_steps) -> None:
+                 device_us: int, search_steps, expand_steps) -> None:
         """Slice the batched output back into per-member QueryResults
         (member i owns batch row i -- ordering is positional by
         construction). ONE host conversion covers the whole batch;
@@ -953,6 +954,8 @@ class BatchingExecutor:
             qs.counters["batch_size"] = nbatch
             if search_steps[i]:
                 qs.counters["join_search_steps"] = int(search_steps[i])
+            if expand_steps is not None:
+                qs.counters["join_expand_steps"] = expand_steps
             res.query_stats = qs
             res.stats = {"batch": {"size": float(nbatch),
                                    "device_us": float(device_us)}}

@@ -22,7 +22,7 @@ spill feedback loop of the reference's Driver yield + revoke).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +74,29 @@ class CompiledPlan:
     # executable those shapes compiled to (runner._program_hbm_bytes):
     # kept here so that it lives and dies with the cached executable
     hbm_bytes: Dict[tuple, int] = dataclasses.field(default_factory=dict)
+    # gather trips a slot of the joins' expansions takes to find its row
+    # (ops/join._slot_rows), summed over the program's joins, None for a
+    # program without a join: a constant of the shapes, so it is noted
+    # where `fn` is traced and never leaves the device.
+    # `traced_expand_steps` is the latest trace's; `expand_steps_of`
+    # keeps it by argument shapes, as `hbm_bytes`
+    traced_expand_steps: Optional[int] = None
+    expand_steps: Dict[tuple, Optional[int]] = dataclasses.field(
+        default_factory=dict)
+
+    def expand_steps_of(self, batches) -> Optional[int]:
+        """The counter join_expand_steps of a dispatch of `fn` on
+        `batches` that has just returned (under the plan's call lock):
+        shapes met for the first time were traced by that call."""
+        return self.expand_steps.setdefault(shape_key(batches),
+                                            self.traced_expand_steps)
+
+
+def shape_key(batches) -> tuple:
+    """What jit keys a program of one function by, as far as scan
+    batches differ: the shapes and types of their leaves."""
+    return tuple((x.shape, str(x.dtype))
+                 for x in jax.tree_util.tree_leaves(batches))
 
 
 def _collect_scans(node: N.PlanNode, out: List[N.PlanNode], _seen=None):
@@ -208,6 +231,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
                           cap, node.join_type, node.right_output_channels)
             _note_overflow(r.overflow)
             search_steps.append(r.search_steps)
+            expand_steps.append(r.expand_steps)
             return r.batch
         if isinstance(node, N.SemiJoinNode):
             src = lower(node.source, inputs)
@@ -376,6 +400,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
 
     overflow_box: List = []
     search_steps: List = []  # one trip count per join lookup lowered
+    expand_steps: List[int] = []  # one per join expansion lowered
     _lower_memo: Dict[int, Batch] = {}
 
     def _note_overflow(flag, scalable: bool = False):
@@ -387,9 +412,12 @@ def compile_plan(root: N.PlanNode, mesh=None,
     def run(scan_batches: Sequence[Batch]):
         overflow_box.clear()
         search_steps.clear()
+        expand_steps.clear()
         _lower_memo.clear()
         inputs = {n.id: b for n, b in zip(scans, scan_batches)}
         out = lower(root, inputs)
+        plan.traced_expand_steps = sum(expand_steps) if expand_steps \
+            else None
         hard = jnp.zeros((), dtype=bool)   # join/group capacity
         slots = jnp.zeros((), dtype=bool)  # exchange slots (rescalable)
         for f, scalable in overflow_box:
@@ -408,10 +436,10 @@ def compile_plan(root: N.PlanNode, mesh=None,
         return out, (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
                      + (steps << FLAG_BITS))
 
+    plan = CompiledPlan(run, scans, root.output_types(), dist, root)
     if dist:
         in_specs = tuple(P(WORKERS_AXIS) for _ in scans)
-        fn = jax.shard_map(run, mesh=mesh, in_specs=(in_specs,),
-                           out_specs=(P(WORKERS_AXIS), P()), check_vma=False)
-    else:
-        fn = run
-    return CompiledPlan(fn, scans, root.output_types(), dist, root)
+        plan.fn = jax.shard_map(run, mesh=mesh, in_specs=(in_specs,),
+                                out_specs=(P(WORKERS_AXIS), P()),
+                                check_vma=False)
+    return plan

@@ -27,7 +27,7 @@ import numpy as np
 from .. import types as T
 from ..block import Batch, batch_from_numpy, to_numpy
 from ..plan import nodes as N
-from .planner import compile_plan, split_flags
+from .planner import compile_plan, shape_key, split_flags
 from .stats import (QueryStats, RuntimeStats, StatsCollector, joining, note,
                     note_max, span, stage)
 
@@ -869,13 +869,16 @@ _CAPACITY_FEEDBACK: Dict[str, int] = {}
 _MAX_CAPACITY_SCALE = 1 << 10
 
 
-def _read_status(word) -> int:
+def _read_status(word, expand_steps: Optional[int]) -> int:
     """The one host read of the word a program returns beside its batch:
     its overflow flags come back, the trips its joins' lookups took go
-    to the statement's counters."""
+    to the statement's counters, and with them `expand_steps`, the
+    trips of its joins' expansions (`CompiledPlan.expand_steps_of`)."""
     flags, steps = split_flags(int(np.asarray(word)))
     if steps:
         note("join_search_steps", steps)
+    if expand_steps is not None:  # 0 too: a join whose table is its
+        note("join_expand_steps", expand_steps)  # own directory
     return flags
 
 
@@ -888,8 +891,7 @@ def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
     compiled plan. Where the executable gives no analysis (one read
     from a compile cache may not), the allocator's peak: then the
     process's peak so far, not this program's."""
-    key = tuple((x.shape, str(x.dtype))
-                for x in jax.tree_util.tree_leaves(batches))
+    key = shape_key(batches)
     if key not in plan.hbm_bytes:
         found = 0
         try:
@@ -955,10 +957,12 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                 fn = jax.jit(plan.fn)
                 dispatch_fn = fn
                 out, overflow = fn(tuple(batches))
+                expand_steps = plan.expand_steps_of(batches)
             else:
                 dispatch_fn = jfn
                 with call_lock:  # serialize trace-time closure state
                     out, overflow = jfn(tuple(batches))
+                    expand_steps = plan.expand_steps_of(batches)
         with stage("device_wait", region):
             jax.block_until_ready(out)
             # host-observed device occupancy of this dispatch: the
@@ -966,7 +970,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # the only per-kernel timing one fused program exposes -- on
             # the monotonic now_us clock the timeline intervals share
             device_s += (_now_us() - t_disp0) / 1e6
-            flags = _read_status(overflow)
+            flags = _read_status(overflow, expand_steps)
         note_max("program_hbm_bytes",
                  _program_hbm_bytes(plan, dispatch_fn, call_lock, batches))
         if prog is not None:  # each landed dispatch advances
@@ -1142,7 +1146,8 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                 with stage("device_wait", region):
                     jax.block_until_ready(out)
                     dev_s = (_now_us() - t_don0) / 1e6
-                    oflags = _read_status(overflow)
+                    # no join is overflow-incapable: nothing expands
+                    oflags = _read_status(overflow, None)
                 if prog is not None:
                     prog.advance()
                 if oflags:  # unreachable: whitelist admits no overflow op

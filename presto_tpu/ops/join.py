@@ -20,8 +20,16 @@ through a static-capacity prefix-sum expansion:
   start[i], end[i] = _match_ranges(build, probe_i)
   cnt[i]   = end - start  (0 for null/missing keys)
   off      = exclusive_cumsum(cnt)
-  out row k maps back to probe row via searchsorted(off, k), and to
-  build row start[row] + (k - off[row])
+  out row k maps back to probe row row[k] = (#i : off[i] <= k) - 1
+  (`_slot_rows`), and to build row start[row] + (k - off[row])
+
+The slots are arange(out_capacity): dense, sorted, known at trace time.
+So a slot finds its row without a search of the whole table: offsets
+clipped to the capacity in int32, the first offset of every block of
+n / out_capacity rows counted into the slots (a histogram of about
+out_capacity updates and one cumsum) to give each slot its block, and
+log2(block) gathers of one 32-bit lane inside the block. What is gathered per slot afterwards
+(off, emit, cnt, start) is int32 too.
 
 Everything is a fixed-shape gather -- the dynamic result size only
 shows up in the output's active mask and an `overflow` flag when the
@@ -57,12 +65,13 @@ class JoinResult:
     num_rows: jnp.ndarray
     overflow: jnp.ndarray
     search_steps: jnp.ndarray  # binary-search trips the lookups took
+    expand_steps: int = 0  # gather trips a slot of `_slot_rows` took
 
 
 jax.tree_util.register_dataclass(JoinResult,
                                  data_fields=["batch", "num_rows", "overflow",
                                               "search_steps"],
-                                 meta_fields=[])
+                                 meta_fields=["expand_steps"])
 
 
 def _pad_chars(c: StringColumn, width: int) -> StringColumn:
@@ -278,6 +287,75 @@ def _lookup(sorted_words: Sequence[jnp.ndarray], usable: jnp.ndarray,
                          q_ranks.astype(jnp.uint64))
 
 
+def _slot_block(n: int, slots: int) -> int:
+    """Rows a block of `_slot_rows`' directory holds: the power of two
+    at or above n / slots. The directory's histogram then takes about
+    as many updates as there are slots and the search inside a block
+    log2(n / slots) trips: both follow the output, not the table (on
+    the chip an update costs about what a gathered index does; PERF.md,
+    PR 29, has the table). A table no longer than the output is its own
+    directory: blocks of one row, no search."""
+    return 1 << ((n - 1) // slots).bit_length()
+
+
+@jax.named_scope("_slot_rows")
+def _slot_rows(off: jnp.ndarray, slots: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
+    """The prefix-sum expansion's map from an output slot to the row
+    that emits it: for a nondecreasing offset table `off` (any integer
+    type) and the slots k = arange(slots), row[k] = (#i : off[i] <= k)
+    - 1 as int32, the integer searchsorted(off, k, side="right") - 1
+    gives (-1 where off[0] > k). Also returns j[k] = k - off[row[k]],
+    the slot's place among those its row emits (int32; row -1 read as
+    row 0), and the gather trips a slot takes to find its row, a
+    constant of the shapes.
+
+    1. off32 = clip(off, 0, slots) as int32: for a slot k < slots,
+       off > k iff off32 > k, whatever the width `off` needs.
+    2. The first offset of every block of B rows is counted into a bin
+       a slot and one behind them (n / B sorted updates) and the counts
+       summed along the slots: the
+       count of blocks that start at or before k, less one, is the last
+       block whose first offset is <= k, and it holds the answer. The
+       sum runs along rows of 1,024 bins and then over the rows' totals:
+       XLA:TPU compiles that in a second at any length, the flat cumsum
+       in 18-22 s at a million bins (PERF.md, PR 29).
+    3. log2 B trips inside the block, from its first row (known <= k):
+       a trip moves on by half the rows left where the row there is <=
+       k too, at one gather of a 32-bit lane.
+
+    B is `_slot_block`'s. A table of at most B rows (one row, or under
+    three slots) is one block entered before its first row."""
+    n = off.shape[0]
+    k = jnp.arange(slots, dtype=jnp.int32)
+    if n == 0 or slots == 0:
+        return jnp.full(slots, -1, dtype=jnp.int32), k, 0
+    off32 = jnp.clip(off, 0, slots).astype(jnp.int32)
+    block = _slot_block(n, slots)
+    if n <= block:
+        trips = n.bit_length()  # n + 1 answers, -1 among them
+        # -1 (the clip's floor is 0), and as varying as the carry under
+        # shard_map
+        row = jnp.broadcast_to(jnp.minimum(off32[0], 0) - 1, (slots,))
+    else:
+        trips = block.bit_length() - 1
+        hist = jnp.zeros((slots // 1024 + 1) * 1024, dtype=jnp.int32).at[
+            off32[::block]].add(1, indices_are_sorted=True)
+        starts = jnp.cumsum(hist.reshape(-1, 1024), axis=1, dtype=jnp.int32)
+        above = jnp.cumsum(starts[:, -1], dtype=jnp.int32) - starts[:, -1]
+        starts = (starts + above[:, None]).reshape(-1)[:slots]
+        # no block starts at or before k: -1, and no row after it is <= k
+        row = jnp.where(starts > 0, (starts - 1) * block, -1)
+
+    def advance(trip, row):
+        ahead = row + ((1 << trips) >> trip + 1)
+        there = off32[jnp.minimum(ahead, n - 1)]
+        return jnp.where((ahead < n) & (there <= k), ahead, row)
+
+    row = jax.lax.fori_loop(0, trips, advance, row)
+    return row, k - off32[jnp.maximum(row, 0)], trips
+
+
 @jax.named_scope("hash_join")
 def hash_join(probe: Batch, build: Batch,
               probe_key_channels: Sequence[int],
@@ -317,12 +395,14 @@ def hash_join(probe: Batch, build: Batch,
     # match ranges inside the usable (sorted-front) region
     start, end, steps = _lookup(sb_words, b_usable, p_words)
 
-    cnt = jnp.where(p_usable, end - start, 0).astype(jnp.int64)
+    # per probe row in int32 (they are gathered per slot); the running
+    # sum and the totals in int64
+    cnt = jnp.where(p_usable, end - start, 0)
     if join_type in ("left", "full"):
         emit = jnp.where(probe.active, jnp.maximum(cnt, 1), 0)
     else:
         emit = cnt
-    off = jnp.cumsum(emit) - emit  # exclusive
+    off = jnp.cumsum(emit, dtype=jnp.int64) - emit  # exclusive
     total = off[-1] + emit[-1]
 
     outer_build = join_type in ("right", "full")
@@ -333,18 +413,18 @@ def hash_join(probe: Batch, build: Batch,
         steps = steps + steps2
         b_matched = b_usable & (be > bs)
         unmatched = build.active & ~b_matched
-        u = unmatched.astype(jnp.int64)
-        off2 = jnp.cumsum(u) - u  # exclusive, original build row order
-        total2 = total + off2[-1] + u[-1]
+        u = unmatched.astype(jnp.int32)
+        # exclusive, original build row order, behind region 1's slots
+        off2 = total + jnp.cumsum(u, dtype=jnp.int64) - u
+        total2 = off2[-1] + u[-1]
     else:
         total2 = total
     overflow = total2 > out_capacity
 
-    k = jnp.arange(out_capacity, dtype=jnp.int64)
-    # map output slot -> probe row
-    prow = jnp.searchsorted(off, k, side="right") - 1
+    k = jnp.arange(out_capacity, dtype=jnp.int32)
+    # map output slot -> probe row, and its place j in that row's run
+    prow, j, expand_steps = _slot_rows(off, out_capacity)
     prow = jnp.clip(prow, 0, npr - 1)
-    j = k - off[prow]
     valid = (k < total) & (j < emit[prow])
     matched = j < cnt[prow]
     srow = jnp.clip(start[prow] + j, 0, nb - 1)
@@ -354,11 +434,10 @@ def hash_join(probe: Batch, build: Batch,
     all_valid = valid
     if outer_build:
         # region 2: slots [total, total2) emit unmatched build rows
-        k2 = k - total
-        brow2 = jnp.clip(jnp.searchsorted(off2, k2, side="right") - 1,
-                         0, nb - 1)
-        valid2 = (k >= total) & (k < total2) & \
-            (k2 - off2[brow2] < u[brow2])
+        brow2, j2, expand_steps2 = _slot_rows(off2, out_capacity)
+        expand_steps += expand_steps2
+        brow2 = jnp.clip(brow2, 0, nb - 1)
+        valid2 = (k >= total) & (k < total2) & (j2 < u[brow2])
         brow = jnp.where(valid2, brow2, brow)
         build_valid = build_valid | valid2
         all_valid = all_valid | valid2
@@ -371,7 +450,7 @@ def hash_join(probe: Batch, build: Batch,
         g = _gather(c, brow, build_valid)
         out_cols.append(g)
     out = Batch(tuple(out_cols), all_valid)
-    return JoinResult(out, total2, overflow, steps)
+    return JoinResult(out, total2, overflow, steps, expand_steps)
 
 
 from ..block import gather_block as _gather  # shared row gather

@@ -4,10 +4,10 @@ Reference surface: operator/unnest/ (UnnestOperator expanding ARRAY/MAP
 columns into rows, replicating the other channels; UnnestNode in the
 plan vocabulary, WITH ORDINALITY variant).
 
-TPU-first: the same static-capacity prefix-sum expansion the join build
-uses (ops/join.py): output slot k maps back to its source row by
-binary-searching the exclusive offsets of per-row cardinalities, and to
-the element by k - offset[row]. One gather per output column -- no
+TPU-first: the same static-capacity prefix-sum expansion the join
+uses (ops/join._slot_rows): output slot k maps back to its source row
+through the exclusive offsets of per-row cardinalities, and to the
+element by k - offset[row]. One gather per output column -- no
 per-row loops, overflow flagged when out_capacity is short.
 """
 
@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from .. import types as T
 from ..block import ArrayColumn, Batch, Block, Column, MapColumn, \
     gather_block as _gather
+from .join import _slot_rows
 
 __all__ = ["unnest"]
 
@@ -37,16 +38,16 @@ def unnest(batch: Batch, array_channel: int, out_capacity: int,
         "unnest requires an array or map column"
     n = batch.capacity
 
-    cnt = jnp.where(batch.active & ~arr.nulls, arr.lengths, 0).astype(jnp.int64)
-    off = jnp.cumsum(cnt) - cnt
+    cnt = jnp.where(batch.active & ~arr.nulls, arr.lengths, 0)
+    off = jnp.cumsum(cnt, dtype=jnp.int64) - cnt
     total = off[-1] + cnt[-1]
     overflow = total > out_capacity
 
-    k = jnp.arange(out_capacity, dtype=jnp.int64)
-    row = jnp.clip(jnp.searchsorted(off, k, side="right") - 1, 0, n - 1)
-    j = k - off[row]
+    k = jnp.arange(out_capacity, dtype=jnp.int32)
+    row, j, _ = _slot_rows(off, out_capacity)
+    row = jnp.clip(row, 0, n - 1)
     valid = (k < total) & (j < cnt[row])
-    jc = jnp.clip(j, 0, arr.max_cardinality - 1).astype(jnp.int32)
+    jc = jnp.clip(j, 0, arr.max_cardinality - 1)
 
     out_cols: List[Block] = []
     for ci, c in enumerate(batch.columns):
@@ -65,5 +66,5 @@ def unnest(batch: Batch, array_channel: int, out_capacity: int,
         out_cols.append(Column(elem_vals, elem_nulls,
                                arr.type.element_type))
     if with_ordinality:
-        out_cols.append(Column(j + 1, ~valid, T.BIGINT))
+        out_cols.append(Column((j + 1).astype(jnp.int64), ~valid, T.BIGINT))
     return Batch(tuple(out_cols), valid), overflow

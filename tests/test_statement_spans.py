@@ -323,6 +323,24 @@ def test_join_search_steps_ride_the_status_word(text, joins):
         stats["stages"]["dispatch"]["invocations"]
 
 
+@pytest.mark.parametrize("text,joins", [(Q3, 2), (Q6, 0)],
+                         ids=["q3", "q6"])
+def test_join_expand_steps_ride_the_compiled_plan(text, joins):
+    """The gather trips a slot of a join's expansion takes to find its
+    row (`ops/join._slot_rows`) are a constant of the program: noted
+    where it is traced, kept with the compiled plan, and in the
+    counters of every statement that dispatches it, a plan-cache hit
+    too: log2 of the probe's rows a slot, 0 where (as at this scale) the
+    default capacity holds more slots than the probe has rows; a
+    statement without a join has no such counter."""
+    with StatementServer(sf=0.01) as srv:
+        first = execute(srv.url, text).stats["queryStats"]["counters"]
+        again = execute(srv.url, text).stats["queryStats"]["counters"]
+    assert first.get("join_expand_steps") == (0 if joins else None)
+    assert again.get("join_expand_steps") == first.get("join_expand_steps")
+    assert again["plan_cache_hits"] >= 1
+
+
 def test_a_hard_overflow_still_reruns_under_the_steps():
     """Bits 0-1 of the status word stay the ladder's: a join capacity
     too small by 16x reruns twice (x4 each) and answers as the roomy
@@ -339,3 +357,7 @@ def test_a_hard_overflow_still_reruns_under_the_steps():
     once = roomy.query_stats.counters["join_search_steps"]
     assert once >= 1
     assert tight.query_stats.counters["join_search_steps"] == 3 * once
+    # 11,976 probe rows a slot: blocks of 1 at 65,536 slots; of 16, 4
+    # and 1 at 1,024, 4,096 and 16,384
+    assert roomy.query_stats.counters["join_expand_steps"] == 0
+    assert tight.query_stats.counters["join_expand_steps"] == 4 + 2 + 0
