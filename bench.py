@@ -194,21 +194,6 @@ def _latency_tail(run_once, runs=5):
             "runs": runs}
 
 
-def _top_kernel_shares(top=3):
-    """Top device-time kernels from the continuous profiler
-    (exec/profiler.py), with each one's share of ALL profiled device
-    time this process -- which kernels the benchmark actually paid."""
-    from presto_tpu.exec.profiler import profile_snapshot
-    rows = profile_snapshot()
-    total = sum(k["device_us"] for k in rows) or 1
-    return [{"fingerprint": k["fingerprint"][:12],
-             "device_us": k["device_us"],
-             "share": round(k["device_us"] / total, 4),
-             "calls": k["calls"], "retraces": k["retraces"],
-             "plan": k["label"][:100]}
-            for k in rows[:top]]
-
-
 def _query_telemetry(res):
     """QueryStats -> the compile/execute split the BENCH json records
     (exec/stats.py structured telemetry; None when stats are absent)."""
@@ -257,7 +242,6 @@ def _bench_sql_join(name, sql_text, sf, platform, **hints):
                    "telemetry_cold": _query_telemetry(res_cold),
                    "telemetry_warm": _query_telemetry(res),
                    "latency_warm": latency,
-                   "top_kernels": _top_kernel_shares(),
                    "platform": platform,
                    "meta": _bench_meta(platform)}}))
 
@@ -370,9 +354,7 @@ def main():
     telemetry_smoke = _query_telemetry(run_sql(
         TPCH_Q1, sf=0.01, session={"query_cost_analysis": True}))
     # per-query latency tail through the full front door at smoke
-    # scale, plus the top-3 kernel device-time shares of this process
-    # (incl. the sf-scale runs above): the perf trajectory finally
-    # captures tail behavior and per-kernel attribution
+    # scale: the perf trajectory captures tail behavior
     latency_smoke = _latency_tail(lambda: run_sql(TPCH_Q1, sf=0.01),
                                   runs=5)
     # donation A/B at smoke scale: per-query pool peak with the
@@ -380,11 +362,6 @@ def main():
     # `peak_memory_mb` sample -- beside the donation-off peak and the
     # bytes the K006-proven donating dispatches aliased in place
     donation_smoke = _donation_smoke()
-    # occupancy smoke at smoke scale: the q1 overlap fraction and
-    # device-idle wall from the interval ledger (exec/timeline.py) --
-    # the perfgate-gated `overlap_fraction` sample plus the bubble
-    # verdict naming the hop the device waited on
-    timeline_smoke = _timeline_smoke()
 
     rows_per_sec = n / dt_sql
     baseline_rows_per_sec = n / numpy_s
@@ -426,14 +403,6 @@ def main():
             # bytes ride the subsection for the A/B readout
             "peak_memory_mb": donation_smoke["peak_memory_mb"],
             "donation": donation_smoke,
-            # execution-timeline occupancy (exec/timeline.py): the
-            # gated overlap_fraction rides top-level (today's ~0 serial
-            # baseline the async-ingest PR must raise) beside the
-            # device-idle wall; the bubble verdict rides the subsection
-            "overlap_fraction": timeline_smoke["overlap_fraction"],
-            "device_idle_us": timeline_smoke["device_idle_us"],
-            "timeline": timeline_smoke,
-            "top_kernels": _top_kernel_shares(),
             "platform": platform,
             "iters": iters,
             # which small-G group-by form ACTUALLY COMPILED for the
@@ -472,27 +441,6 @@ def _donation_smoke():
     return {"peak_memory_mb": round(peaks["on"] / 1e6, 3),
             "peak_memory_mb_donation_off": round(peaks["off"] / 1e6, 3),
             "donated_bytes": donated}
-
-
-def _timeline_smoke():
-    """Occupancy readout of q1 at smoke scale from the execution
-    -timeline ledger (exec/timeline.py): overlap fraction (the gated
-    sample), device-idle wall, and the bubble verdict naming the hop
-    the device spent that idle wall waiting on."""
-    from presto_tpu.exec.timeline import bubble_verdict, occupancy
-    from presto_tpu.sql import sql as run_sql
-    res = run_sql(TPCH_Q1, sf=0.01, query_id="bench-timeline")
-    intervals = res.query_stats.timeline.intervals
-    occ = occupancy(intervals)
-    if occ is None:
-        return {"overlap_fraction": 0.0, "device_idle_us": 0,
-                "bubble_verdict": ""}
-    verdict = bubble_verdict(intervals, occ)
-    return {"overlap_fraction": occ["overlapFraction"],
-            "device_idle_us": occ["deviceIdleUs"],
-            "device_idle_fraction": occ["deviceIdleFraction"],
-            "bubble_hop": verdict["hop"] if verdict else "",
-            "bubble_verdict": verdict["message"] if verdict else ""}
 
 
 def _datapath_detail():

@@ -122,14 +122,6 @@ SCHEMA = {
                "column_count": T.BIGINT},
     "plan_cache": {"entries": T.BIGINT, "hits": T.BIGINT,
                    "misses": T.BIGINT},
-    # continuous per-kernel profiler (exec/profiler.py): one row per
-    # compiled kernel this process executed, hottest first
-    "kernels": {"fingerprint": _V, "plan": _V, "tables": _V,
-                "calls": T.BIGINT, "device_time_us": T.BIGINT,
-                "max_device_time_us": T.BIGINT,
-                "rows_in": T.BIGINT, "bytes_in": T.BIGINT,
-                "rows_out": T.BIGINT, "bytes_out": T.BIGINT,
-                "retraces": T.BIGINT, "footprint_bytes": T.BIGINT},
     # data-path waterfall (exec/datapath.py): one row per catalog hop,
     # data-path order -- lifetime bytes/wall, achieved B/s, the
     # measured ceiling it rooflines against, and the utilization ratio
@@ -145,13 +137,6 @@ SCHEMA = {
                     "unit": _V, "est": T.DOUBLE, "actual": T.DOUBLE,
                     "q_error": T.DOUBLE, "direction": _V,
                     "tasks": T.BIGINT},
-    # execution-timeline occupancy (exec/timeline.py): one row per
-    # (retained query, lane) -- lane busy wall/fraction beside the
-    # query's overlap fraction, device-idle wall and bubble hop
-    "occupancy": {"query_id": _V, "lane": _V, "busy_us": T.BIGINT,
-                  "busy_fraction": T.DOUBLE, "wall_us": T.BIGINT,
-                  "overlap_fraction": T.DOUBLE,
-                  "device_idle_us": T.BIGINT, "bubble_hop": _V},
     "session_properties": {"name": _V, "default_value": _V, "type": _V,
                            "description": _V},
     "functions": {"function_name": _V, "kind": _V},
@@ -328,22 +313,6 @@ def _rows_of(table: str) -> List[tuple]:
                  float(r["qError"]) if r["qError"] is not None else 0.0,
                  r["direction"], int(r["tasks"]))
                 for r in accuracy_snapshot()]
-    if table == "occupancy":
-        from ..exec.timeline import snapshot as timeline_snapshot
-        return [(r["queryId"], r["lane"], int(r["busyUs"]),
-                 float(r["busyFraction"]), int(r["wallUs"]),
-                 float(r["overlapFraction"]), int(r["deviceIdleUs"]),
-                 r["bubbleHop"])
-                for r in timeline_snapshot()]
-    if table == "kernels":
-        from ..exec.profiler import profile_snapshot
-        return [(p["fingerprint"], p["label"], p["tables"],
-                 int(p["calls"]), int(p["device_us"]),
-                 int(p["max_device_us"]),
-                 int(p["rows_in"]), int(p["bytes_in"]),
-                 int(p["rows_out"]), int(p["bytes_out"]),
-                 int(p["retraces"]), int(p["footprint_bytes"]))
-                for p in profile_snapshot()]
     raise KeyError(f"no system table {table!r}")
 
 

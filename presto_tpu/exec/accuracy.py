@@ -578,7 +578,7 @@ def cluster_accuracy_doc(worker_urls=(), timeout: float = 3.0) -> dict:
     record merge law. Pulls ride the shared best-effort helper
     (server/client.pull_worker_docs) so bearer/TLS/trace headers --
     and the skip-and-count-dead-workers contract -- stay identical to
-    the /v1/profile and /v1/datapath merges'."""
+    the /v1/datapath merge's."""
     from ..server.client import pull_worker_docs
     pulled, workers_seen = pull_worker_docs(
         worker_urls, timeout, lambda c: c.accuracy(), "accuracy")

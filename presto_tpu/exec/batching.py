@@ -21,8 +21,8 @@ Model:
     template traces exactly like the original plan.
 
   * **Batch key**: ``(plan_fingerprint(template), kernel-mode envs, sf,
-    join capacity)`` -- the exact identity ``exec/plan_cache.py`` and
-    ``exec/profiler.py`` already key on. Queries co-batch ONLY on key
+    join capacity)`` -- the exact identity ``exec/plan_cache.py``
+    already keys on. Queries co-batch ONLY on key
     equality: differing string literals, differing plan shapes, or a
     kernel-mode env flip produce different keys by construction.
 
@@ -486,7 +486,7 @@ class BatchingExecutor:
     def _batch_key(template_fp: str, sf: float,
                    join_capacity: int) -> tuple:
         from .plan_cache import _kernel_mode
-        # the exact identity the plan cache and profiler key on:
+        # the exact identity the plan cache keys on:
         # (structural fingerprint, kernel-mode envs) -- plus the scale
         # factor and join capacity that select the staged data/program
         return (template_fp, _kernel_mode(), float(sf),
@@ -864,7 +864,7 @@ class BatchingExecutor:
             return
         device_us = int((time.time() - t0) * 1e6)
         self._fan_out(out, plan, entries, device_us, steps, expand_steps)
-        self._account(key, entries, device_us)
+        self._account(entries)
 
     def _stage_inputs(self, key, plan, sf: float) -> list:
         """Stage the template's scan batches, replayed from the staged
@@ -962,8 +962,7 @@ class BatchingExecutor:
             m.result = res
             _note_query(_QUERY_BATCH, m.query_id, nbatch)
 
-    def _account(self, key, entries: List[_Pending],
-                 device_us: int) -> None:
+    def _account(self, entries: List[_Pending]) -> None:
         nbatch = len(entries)
         with _TOTALS_LOCK:
             if nbatch > 1:
@@ -976,23 +975,9 @@ class BatchingExecutor:
                 # a batch-of-1 riding a warm template program: counted
                 # apart so occupancy stats keep meaning "co-batched"
                 _TOTALS["solo_dispatches"] += 1
-        # the profiler attributes the batched dispatch to the template
-        # fingerprint -- the same identity its plan-cache entry lives
-        # under -- so /v1/profile shows the dispatch amortization; ONE
-        # registry fold for the whole batch, every member query id
-        # cross-linked for history/flight-dump attribution
-        from .profiler import note_query_kernel, record_call
-        first = entries[0]
-        record_call(key[0], label=f"batched[{nbatch}]",
-                    device_us=device_us,
-                    rows_out=sum(m.result.row_count for m in entries
-                                 if m.result),
-                    query_id=first.query_id,
-                    trace_id=_trace_str(first.trace_id, first.query_id))
-        note_query_kernel(key[0],
-                          [m.query_id for m in entries[1:]])
         if nbatch > 1:
             from ..server.metrics import observe_histogram
+            first = entries[0]
             observe_histogram("presto_tpu_batch_occupancy_queries",
                               float(nbatch),
                               trace_id=_trace_str(first.trace_id,

@@ -30,7 +30,7 @@ Model -- three layers, one merge law:
     size histogram (server/metrics.py SIZE_BUCKETS ladder).
   * process-lifetime registry: the ``GET /v1/datapath`` slice (the
     worker serves it; the statement tier merges slices cluster-wide
-    via server/client.pull_worker_docs, exactly like /v1/profile),
+    via server/client.pull_worker_docs),
     ``system.datapath``, and the bench.py per-hop artifact section.
 
 Ceilings probe: one-shot seeded microbenchmarks of host memcpy,
@@ -116,12 +116,10 @@ _DEFAULT_BAND = 0.5
 
 
 def now_us() -> int:
-    """The per-process monotonic microsecond clock -- the ONE clock
-    the hop walls and the timeline interval ledger (exec/timeline.py)
-    share, so a hop's wall_us sum and its intervals' duration sum
-    reconcile by construction (pinned within 1% on q1). Monotonic:
-    never steps backward under NTP slew, so intervals cannot go
-    negative on the recording process."""
+    """The per-process monotonic microsecond clock of the coarse
+    paths (the kernel hop's dispatch wall). Monotonic: never steps
+    backward under NTP slew, so a wall cannot go negative on the
+    recording process."""
     return int(time.monotonic() * 1e6)
 
 
@@ -244,8 +242,7 @@ class recording:
 # all touch these
 _LOCK = OrderedLock("datapath._LOCK")
 _PROCESS: Dict[str, HopStats] = {}
-# query id -> hop map (the flight-dump cross-link); bounded like the
-# profiler's query->fingerprint table
+# query id -> hop map (the flight-dump cross-link); bounded
 _QUERY_LEDGERS: "collections.OrderedDict[str, Dict[str, HopStats]]" = \
     collections.OrderedDict()
 _QUERY_LEDGERS_MAX = 256
@@ -261,19 +258,12 @@ _GUARDED_BY = {"_LOCK": ("_PROCESS", "_QUERY_LEDGERS", "_CEILINGS",
                          "_PROBING")}
 
 
-def record_hop(hop: str, nbytes: int, seconds: float,
-               end_us: Optional[int] = None,
-               split_id: int = -1) -> None:
+def record_hop(hop: str, nbytes: int, seconds: float) -> None:
     """Fold one hop observation into the ambient ledger (when one is
-    installed), the process-lifetime registry, the per-hop size
-    histogram, and the timeline interval ledger (exec/timeline.py --
-    the interval's duration IS this record's wall_us, so hop sums and
-    interval durations reconcile exactly). ``end_us`` is the window's
-    end on the :func:`now_us` clock; callers recording right after
-    the window (the coarse paths) may omit it. Never raises: this
-    sits on the staging/serde hot paths. Suppressed while the
-    ceilings probe runs (the probe calls the very seams it
-    measures)."""
+    installed), the process-lifetime registry and the per-hop size
+    histogram. Never raises: this sits on the staging/serde hot
+    paths. Suppressed while the ceilings probe runs (the probe calls
+    the very seams it measures)."""
     if getattr(_tls, "suppress", False):
         return
     try:
@@ -289,10 +279,6 @@ def record_hop(hop: str, nbytes: int, seconds: float,
             h.wall_us += wall_us
             h.invocations += 1
             h.max_wall_us = max(h.max_wall_us, wall_us)
-        t1 = now_us() if end_us is None else int(end_us)
-        from .timeline import record_interval
-        record_interval(hop, int(nbytes), t1 - wall_us, t1,
-                        split_id=split_id)
         from ..server.metrics import observe_histogram
         observe_histogram("presto_tpu_datapath_bytes", float(nbytes),
                           labels={"hop": hop})
@@ -307,29 +293,25 @@ def record_hop(hop: str, nbytes: int, seconds: float,
 
 class timed_hop:
     """``with timed_hop("connector_read") as t: ...; t.bytes = n`` --
-    records the hop on exit with the measured wall, on the monotonic
-    :func:`now_us` clock the interval ledger shares. The interval is
-    also a span of the statement's seam (exec/stats.py): a child of the
-    open stage in the collector's record, ``presto:<hop>`` in the
-    profiler's trace."""
+    the hop-sized opening of the statement's span seam (exec/stats.py):
+    the interval is a span, a child of the open stage in the
+    collector's record and ``presto:<hop>`` in the profiler's trace,
+    and the span's own two clock readings are the wall the hop is
+    recorded with on exit."""
 
-    def __init__(self, hop: str, nbytes: int = 0, split_id: int = -1):
+    def __init__(self, hop: str, nbytes: int = 0):
         self.hop = hop
         self.bytes = nbytes
-        self.split_id = split_id
 
     def __enter__(self):
         from .stats import span
         self._span = span(self.hop)
         self._span.__enter__()
-        self.t0_us = now_us()
         return self
 
     def __exit__(self, *exc):
-        end = now_us()
         self._span.__exit__(*exc)
-        record_hop(self.hop, self.bytes, (end - self.t0_us) / 1e6,
-                   end_us=end, split_id=self.split_id)
+        record_hop(self.hop, self.bytes, self._span.t1 - self._span.t0)
         return False
 
 
@@ -631,7 +613,7 @@ def cluster_datapath_doc(worker_urls=(), timeout: float = 3.0) -> dict:
     reachable worker's ``GET /v1/datapath``, folded by hop. Pulls ride
     the shared best-effort helper (server/client.pull_worker_docs) so
     bearer/TLS/trace headers -- and the skip-and-count-dead-workers
-    contract -- stay identical to the /v1/profile merge's."""
+    contract -- are the same for every merged surface."""
     from ..server.client import pull_worker_docs
     pulled, workers_seen = pull_worker_docs(
         worker_urls, timeout, lambda c: c.datapath(), "datapath")

@@ -119,14 +119,6 @@ BENCH_SPECS: Sequence[MetricSpec] = (
     # regression) shows up as a step UP in this metric.
     MetricSpec("peak_memory_mb", rel_threshold=0.10, abs_floor=4.0,
                mad_k=3.0),
-    # q1 host-staging/device-dispatch overlap fraction (exec/
-    # timeline.py occupancy engine; bench.py timeline smoke): today's
-    # strictly-serial pipeline measures ~0, which is the committed
-    # baseline the ROADMAP item-1 async ingest must visibly RAISE --
-    # so the metric regresses DOWN (higher_is_worse=False) and the
-    # abs_floor keeps scheduler jitter around zero from tripping it.
-    MetricSpec("overlap_fraction", higher_is_worse=False,
-               rel_threshold=0.5, abs_floor=0.05),
 )
 
 # MAD -> sigma consistency constant for normally distributed noise

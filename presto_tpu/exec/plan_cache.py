@@ -123,11 +123,6 @@ KERNEL_MODE_ENVS = (("PRESTO_TPU_SMALLG", "auto"),
                     # keeps audit-memo and executable lifecycles aligned
                     # and satisfies R001's registered-env contract
                     ("PRESTO_TPU_KERNEL_AUDIT", "0"),
-                    # continuous per-kernel profiling (exec/profiler.py):
-                    # like the audit knob, program-invariant but
-                    # registered so every ambient knob exec/ reads lives
-                    # in this one R001-checked list
-                    ("PRESTO_TPU_PROFILE", "1"),
                     # concurrent-query batching (exec/batching.py): the
                     # batched dispatch traces a vmapped program over the
                     # parameter axis, so the mode is part of every batch
@@ -138,12 +133,7 @@ KERNEL_MODE_ENVS = (("PRESTO_TPU_SMALLG", "auto"),
                     # program (donate_argnums over the dead leaves), so
                     # the mode is part of every cached key (and the env
                     # read rides the one R001-checked list)
-                    ("PRESTO_TPU_DONATION", "0"),
-                    # execution-timeline interval tracing (exec/
-                    # timeline.py): program-invariant observability, but
-                    # registered so every ambient knob exec/ reads lives
-                    # in this one R001-checked list
-                    ("PRESTO_TPU_TIMELINE", "1"))
+                    ("PRESTO_TPU_DONATION", "0"))
 
 
 def _kernel_mode() -> str:

@@ -22,11 +22,11 @@ a would-be region only for CAUSE:
     partition time, and the REAL K005 estimate -- fed back per region
     fingerprint whenever the staging-time auditor runs -- overrides
     the estimate on the next submission of the same region.
-  * **profiler demotion** -- a region whose fused per-dispatch device
+  * **demotion** -- a region whose fused per-dispatch device
     time regresses beyond the perfgate noise band vs the recorded
     materialized (per-operator) execution of the same span is demoted
-    back to materialized boundaries. Both sides of the comparison come
-    from the continuous profiler's device-time samples folded into
+    back to materialized boundaries. Both sides of the comparison are
+    the runner's host-timed dispatch walls folded into
     :class:`FusionMemory`; the band math is exec/perfgate.py's --
     the ONE regression comparator this repo allows.
   * **fusion off** -- ``fusion`` session property / ``PRESTO_TPU_FUSION=0``
@@ -52,9 +52,8 @@ Region identity: each region's root is a standalone plan tree (cut
 children replaced by RemoteSourceNode leaves), so its plan-cache
 fingerprint derives from the ORIGINAL plan's structure restricted to
 the region span -- a single-region plan keeps the existing whole-plan
-fingerprint unchanged, which is what keeps the profiler registry, the
-query-history archive and the kernaudit memo keyed exactly as before
-this refactor.
+fingerprint unchanged, which is what keeps the plan cache and the
+kernaudit memo keyed exactly as before this refactor.
 """
 
 from __future__ import annotations
@@ -185,8 +184,8 @@ class FusionMemory:
     """Process-wide feedback store for fusion-plan choice.
 
     Keyed by region fingerprint (exec/plan_cache.plan_fingerprint of
-    the region root -- the same identity the executable cache, the
-    profiler registry and the kernaudit memo use):
+    the region root -- the same identity the executable cache and
+    the kernaudit memo use):
 
       * ``note_footprint``: kernaudit K005's measured peak-intermediate
         estimate (max over audits); the partitioner prefers it over the
@@ -338,6 +337,30 @@ def fusion_memory() -> FusionMemory:
 # ---------------------------------------------------------------------------
 
 
+def _span_label(root) -> str:
+    """A region's `span` text: the node-type chain in DFS preorder with
+    scan tables inlined, capped."""
+    parts: List[str] = []
+
+    def walk(n):
+        if len(parts) > 24:
+            return
+        name = type(n).__name__.replace("Node", "")
+        table = getattr(n, "table", None)
+        conn = getattr(n, "connector", None)
+        if table and conn:
+            name += f"[{conn}.{table}]"
+        step = getattr(n, "step", None)
+        if step and name.startswith("Aggregation"):
+            name += f"({step})"
+        parts.append(name)
+        for s in getattr(n, "sources", ()):
+            walk(s)
+
+    walk(root)
+    return " > ".join(parts)[:120]
+
+
 def _audit_budget(session) -> int:
     from ..audit.staged import _budget
     return _budget(session)
@@ -441,8 +464,8 @@ def partition_regions(root: N.PlanNode, *, session=None, sf: float = 0.01,
         rebuilt: Dict[int, N.PlanNode] = {}
         region_root = rebuild(n)
 
-        # demotion check: a fused multi-op region whose fingerprint the
-        # profiler has proven regressive re-carves materialized
+        # demotion check: a fused multi-op region whose fingerprint
+        # FusionMemory has proven regressive re-carves materialized
         if fused and not single and not materialize_root and len(nodes) > 1:
             region_fp = fp_of(region_root)
             why = _MEMORY.demoted(region_fp)
@@ -459,14 +482,13 @@ def partition_regions(root: N.PlanNode, *, session=None, sf: float = 0.01,
         idx = len(regions)
         for m in nodes:
             node_region[id(m)] = idx
-        from .profiler import plan_label
         reason = ("mesh" if single else
                   (cause or "materialized")
                   if (per_op or materialize_root) else
                   "+".join(sorted(set(reasons))) or "fused")
         regions.append(PipelineRegion(
             index=idx, root=region_root, inputs=inputs,
-            span=plan_label(region_root, max_len=120), ops=len(nodes),
+            span=_span_label(region_root), ops=len(nodes),
             reason=reason, est_peak_bytes=est_sum[0]))
         carved[id(n)] = idx
         return idx

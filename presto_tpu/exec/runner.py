@@ -373,23 +373,15 @@ def run_query(root: N.PlanNode, sf: float = 0.01, mesh=None,
     from .datapath import DatapathLedger
     from .datapath import recording as _dp_recording
     from .progress import begin as _progress_begin
-    from .timeline import TimelineLedger, timeline_enabled
-    from .timeline import recording as _tl_recording
     prog = _progress_begin(query_id)
     dp = DatapathLedger()
     # the per-query estimate-vs-actual ledger (exec/accuracy.py) is
     # ambient too: measured boundaries (scan outputs, region outputs,
     # K005 footprint audits) attribute to THIS query's plan nodes
     acc = AccuracyLedger()
-    # ... and the interval-timeline ledger (exec/timeline.py): every
-    # hop the datapath records also lands as a (lane, hop, split,
-    # t0, t1) interval, the occupancy/bubble instrument. A disabled
-    # ledger (session `timeline` off) makes every record a no-op.
-    tl = TimelineLedger(query_id=query_id,
-                        enabled=timeline_enabled(session))
     try:
         with joining(query_id, trace_id) as collector, \
-                _dp_recording(dp), _acc_recording(acc), _tl_recording(tl):
+                _dp_recording(dp), _acc_recording(acc):
             res = _run_query_inner(
                 root, sf=sf, mesh=mesh, capacity_hints=capacity_hints,
                 default_join_capacity=default_join_capacity,
@@ -397,7 +389,7 @@ def run_query(root: N.PlanNode, sf: float = 0.01, mesh=None,
                 remote_sources=remote_sources, memory_pool=memory_pool,
                 query_id=query_id, session=session,
                 hbm_budget_bytes=hbm_budget_bytes, prepared=prepared,
-                trace_id=trace_id, prog=prog, dp=dp, acc=acc, tl=tl,
+                trace_id=trace_id, prog=prog, dp=dp, acc=acc,
                 collector=collector)
     except BaseException:
         prog.release(state="FAILED")
@@ -418,7 +410,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                      hbm_budget_bytes: Optional[int] = None,
                      prepared: bool = False,
                      trace_id=None, prog=None, dp=None,
-                     acc=None, tl=None,
+                     acc=None,
                      collector: Optional[StatsCollector] = None
                      ) -> QueryResult:
     # write/DDL roots execute their source on device, then write
@@ -486,8 +478,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     res = _batch_to_result(out_b, root)
                     res.stats = stats.snapshot()
                     _finalize_query_stats(collector, res, t_query0, 0,
-                                          root, trace_id, dp=dp,
-                                          acc=acc, tl=tl, sf=sf)
+                                          root, dp=dp, acc=acc, sf=sf)
                     return res
             with stage("execute"):
                 r = run_streaming_agg(root, sf, split_rows)
@@ -502,8 +493,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
             res = _batch_to_result(out_b, root)
             res.stats = stats.snapshot()
             _finalize_query_stats(collector, res, t_query0, 0, root,
-                                  trace_id, dp=dp, acc=acc, tl=tl,
-                                  sf=sf)
+                                  dp=dp, acc=acc, sf=sf)
             return res
     pad = (mesh.devices.size if mesh is not None else 1) * 8
     hints = capacity_hints or {}
@@ -522,7 +512,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     # on and nothing refused/demoted this is a single region -- the
     # fused whole-fragment program, compiled and cached exactly as
     # before. Materialized boundaries (fusion off, footprint refusal,
-    # profiler demotion) run the general region executor below.
+    # demotion) run the general region executor below.
     from .plan_cache import plan_fingerprint
     from .regions import fusion_memory, partition_regions
     from .. import failpoints
@@ -577,16 +567,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                 root, mesh, default_join_capacity, 1, False)
             fp = None
             scan_leaves = plan.scan_nodes
-    # continuous per-kernel profiling (exec/profiler.py): every executed
-    # program is attributed by its plan-cache fingerprint -- computed
-    # here even for the fragment tier's uncached compiles (scan ranges /
-    # remote sources change batches, not the program's identity). The
-    # region executor attributes per REGION fingerprint instead.
-    from .profiler import profiling_enabled
-    prof_on = profiling_enabled(session)
-    fp_prof = fp
-    if prof_on and fp_prof is None and not multi_region:
-        fp_prof = plan_fingerprint(root)
     adaptive_off = False
     if session is not None:
         try:
@@ -629,7 +609,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         if prog is not None:
             prog.set_planned(len(scan_leaves))
             prog.advance(stage="staging")
-        from .timeline import split_scope
         with stage("staging"):
             batches = []
             for si, s in enumerate(scan_leaves):
@@ -639,16 +618,11 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                         f"no remote source batch supplied for node {s.id}"
                     batches.append(remote_sources[s.id])
                 else:
-                    # split_scope: the hop seams inside this staging
-                    # call attribute their timeline intervals to the
-                    # si-th split without threading an index through
-                    # every connector signature
-                    with split_scope(si):
-                        batches.append(_scan_batch(
-                            s, sf, hints.get(s.id), pad,
-                            scan_ranges.get(s.id),
-                            dyn_filters=dyn_filters.get(s.id),
-                            stats=stats))
+                    batches.append(_scan_batch(
+                        s, sf, hints.get(s.id), pad,
+                        scan_ranges.get(s.id),
+                        dyn_filters=dyn_filters.get(s.id),
+                        stats=stats))
                 collector.operator(
                     _scan_key(si, s), _scan_label(s),
                     wall_us=int((time.time() - t_scan0) * 1e6))
@@ -728,11 +702,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                 fusion_memory().note_footprint(
                     fp or plan_fingerprint(root),
                     audit_report["peak_bytes_estimate"])
-            if prof_on:
-                # ... and rides the kernel's profile row: /v1/profile
-                # shows device time AND planned HBM appetite
-                from .profiler import note_footprint
-                note_footprint(fp_prof, audit_report["peak_bytes_estimate"])
     device_s = 0.0           # summed dispatch+sync wall (all reruns)
     compile_us: Optional[int] = None
     res = None
@@ -748,7 +717,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                 out, device_s, compile_us = _execute_regions(
                     rplan, scan_leaves, batches, default_join_capacity,
                     use_cache, stats, session, adaptive_off, refine,
-                    prog, collector, query_id, trace_id, prof_on,
+                    prog, collector, query_id,
                     memory_pool, plan_fp_root=plan_fingerprint(root),
                     sf=sf)
             else:
@@ -762,7 +731,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         # FLOPs / bytes-accessed from cost_analysis, memoized per plan.
         # Clamped to the execute wall that contains it (nested-jit
         # lowering events can overlap), anchored at execute start so
-        # trace timelines render the compile where it happened. The
+        # traces render the compile where it happened. The
         # region executor drains compile incrementally per region; any
         # remainder is folded in here.
         compile_us = (compile_us or 0) + collector.take_compile_us()
@@ -788,8 +757,8 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         if rplan.fused and mesh is None and not multi_region \
                 and rplan.regions[0].ops > 1:
             # fused-side sample for the demotion comparator: device
-            # occupancy of the fused span, compile excluded. When the
-            # profiler's samples show the fused form regressing beyond
+            # occupancy of the fused span, compile excluded. When these
+            # samples show the fused form regressing beyond
             # the perfgate band vs the materialized baseline, the span
             # demotes and the NEXT submission runs materialized.
             mem = fusion_memory()
@@ -807,8 +776,8 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         # kernel hop (exec/datapath.py): the compiled program's dispatch
         # wall over the bytes it read -- the data-path waterfall's
         # device-side rung, bounded by the device_put ceiling proxy.
-        # XLA compile is SUBTRACTED (same correction the profiler and
-        # the fusion comparator apply above): a cold dispatch's 1-2s
+        # XLA compile is SUBTRACTED (same correction the fusion
+        # comparator applies above): a cold dispatch's 1-2s
         # compile would otherwise read as <1% utilization and misname
         # 'kernel' as the bottleneck on every fresh query. Bytes scale
         # with the DISPATCH count (device_s sums every overflow
@@ -832,32 +801,10 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         if memory_pool is not None:
             memory_pool.free(query_id, reserved)
             peak_reserved = memory_pool.query_peak_bytes(query_id, pop=True)
-        if prof_on and not multi_region:
-            # record on success AND failure -- a failed query's device
-            # time must stay attributed (its flight dump embeds these
-            # rows). The captured XLA-compile wall is SUBTRACTED so
-            # device_us is device occupancy, not trace+compile: a cold
-            # dispatch would otherwise outrank genuinely hot kernels on
-            # every ranking surface. (The region executor attributes
-            # per region fingerprint inside its loop instead.)
-            cu = compile_us if compile_us is not None \
-                else collector.take_compile_us()
-            from ..server.tracing import TraceContext as _TC
-            from .profiler import plan_label, plan_tables, record_call
-            record_call(
-                fp_prof, label=plan_label(root),
-                tables=plan_tables(root),
-                device_us=max(int(device_s * 1e6) - cu, 0),
-                rows_in=staged_rows, bytes_in=staged_bytes,
-                rows_out=res.row_count if res is not None else 0,
-                bytes_out=_result_bytes(res) if res is not None else 0,
-                retraced=cu > 0, query_id=query_id,
-                trace_id=trace_id.trace_id
-                if isinstance(trace_id, _TC) else (trace_id or query_id))
     stats.add("output_rows", res.row_count)
     res.stats = stats.snapshot()
     _finalize_query_stats(collector, res, t_query0, peak_reserved, root,
-                          trace_id, dp=dp, acc=acc, tl=tl, sf=sf)
+                          dp=dp, acc=acc, sf=sf)
     return res
 
 
@@ -968,7 +915,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # host-observed device occupancy of this dispatch: the
             # block_until_ready delta around the existing sync point is
             # the only per-kernel timing one fused program exposes -- on
-            # the monotonic now_us clock the timeline intervals share
+            # the monotonic now_us clock
             device_s += (_now_us() - t_disp0) / 1e6
             flags = _read_status(overflow, expand_steps)
         note_max("program_hbm_bytes",
@@ -1017,7 +964,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
 
 def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                      use_cache, stats, session, adaptive_off, refine,
-                     prog, collector, query_id, trace_id, prof_on,
+                     prog, collector, query_id,
                      memory_pool, plan_fp_root: str, sf: float = 0.01):
     """Materialized region executor (exec/regions.py partition): run
     each pipeline region as its own compiled-and-cached program in
@@ -1026,8 +973,8 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
     HBM, never a host round trip. Per-region: the plan cache keys on
     the region fingerprint, the kernel auditor (when armed) audits the
     region's program and feeds its K005 peak into the fusion cost
-    model, and the continuous profiler attributes device time to the
-    region with its plan-node chain + region tag as provenance.
+    model; the `dispatch` / `device_wait` spans around each region's
+    call carry the region's tag (attribute `region`).
 
     Returns (final output Batch, total device seconds, total compile
     micros drained so far)."""
@@ -1035,7 +982,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
 
     from ..audit.staged import audit_staged_query, kernel_audit_enabled
     from ..server.flight_recorder import record_event
-    from ..server.tracing import TraceContext as _TC
     from ..utils.config import session_flag
     from .accuracy import est_rows_of as _acc_est
     from .accuracy import record_node as _acc_record
@@ -1044,8 +990,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                            prepare_donation)
     from .memory import batch_bytes
     from .plan_cache import plan_fingerprint
-    from .profiler import note_footprint, plan_label, plan_tables, \
-        record_call
     from .regions import fusion_memory
     staged_by_id = {id(n): b for n, b in zip(scan_leaves, batches)}
     outputs: Dict[int, Batch] = {}
@@ -1070,7 +1014,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
     # balances whatever is still accounted (the caller's bulk free
     # only covers staged scans).
     inter_bytes: Dict[int, int] = {}
-    nreg = len(rplan.regions)
     try:
         for reg in rplan.regions:
             rbatches = [staged_by_id[id(i.node)] if i.kind == "scan"
@@ -1088,8 +1031,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                 if report and report.get("peak_bytes_estimate"):
                     fusion_memory().note_footprint(
                         rfp, report["peak_bytes_estimate"])
-                    if prof_on:
-                        note_footprint(rfp, report["peak_bytes_estimate"])
                     # per-region K005 estimate: region estimates fold by
                     # max into ONE query-level footprint record (the pool
                     # measures one per-query peak, and intermediates drop
@@ -1214,15 +1155,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
             total_compile_us += cu
             dev_us = max(int(dev_s * 1e6) - cu, 0)
             stats.add(f"fusion_region_{reg.tag}_device_us", dev_us)
-            if prof_on:
-                record_call(
-                    rfp,
-                    label=(f"{plan_label(reg.root, max_len=120)} "
-                           f"[region {reg.tag}/{nreg}]"),
-                    tables=plan_tables(reg.root),
-                    device_us=dev_us, retraced=cu > 0, query_id=query_id,
-                    trace_id=trace_id.trace_id if isinstance(trace_id, _TC)
-                    else (trace_id or query_id))
     finally:
         # no residue may leak into the pool's per-query ledger: the
         # caller's finally frees exactly the staged-scan reservation
@@ -1306,18 +1238,14 @@ def _result_bytes(res: "QueryResult") -> int:
 def _finalize_query_stats(collector: StatsCollector, res: "QueryResult",
                           t0: float, peak_reserved_bytes: int,
                           root: Optional[N.PlanNode],
-                          trace_id=None, dp=None, acc=None, tl=None,
-                          sf: float = 0.01) -> None:
+                          dp=None, acc=None, sf: float = 0.01) -> None:
     """Close out the structured stats for one run_query invocation (its
     spans go to the tracer when the collector's owner is done with it:
     ``stats.joining``). `peak_reserved_bytes` is
     the pool high-water mark the caller already drained. `dp` is the
     invocation's datapath ledger: its hop map rides QueryStats.datapath
     (stitching worker slices through the task-status path) and the
-    bounded per-query registry flight dumps embed from. `tl` is the
-    interval-timeline ledger (exec/timeline.py): its slice rides
-    QueryStats.timeline the same way, and the per-query registry keeps
-    it cross-linked to the query's trace id (the Chrome export)."""
+    bounded per-query registry flight dumps embed from."""
     qs = collector.stats
     if dp is not None:
         from .datapath import merge_hop_maps, note_query
@@ -1325,16 +1253,6 @@ def _finalize_query_stats(collector: StatsCollector, res: "QueryResult",
         if hops:
             qs.datapath = merge_hop_maps(qs.datapath, hops)
             note_query(collector.query_id, hops)
-    if tl is not None:
-        from ..server.tracing import TraceContext as _TC
-        from .timeline import note_query as _tl_note
-        sl = tl.snapshot_slice()
-        if not sl.is_empty():
-            qs.timeline = qs.timeline.merge(sl)
-            _tl_note(collector.query_id, sl,
-                     trace_id=trace_id.trace_id
-                     if isinstance(trace_id, _TC)
-                     else (trace_id or collector.query_id))
     # drain any compile time not yet attributed (the streaming/spill
     # early-return paths compile inside their execute stage and never
     # reach the main path's drain); same clamp + anchor as there

@@ -49,7 +49,6 @@ from .accuracy import NodeAccuracy, merge_record_maps, \
     record_map_from_json, record_map_to_json
 from .datapath import HopStats, hop_map_from_json, hop_map_to_json, \
     merge_hop_maps
-from .timeline import TimelineSlice
 
 __all__ = ["RuntimeStats", "timed", "OperatorStats", "StageStats",
            "QueryStats", "StatsCollector", "current_collector",
@@ -219,13 +218,6 @@ class QueryStats:
     # query stitch to the coordinator's through the same path
     accuracy: Dict[str, NodeAccuracy] = \
         dataclasses.field(default_factory=dict)
-    # per-query interval-ledger slice (exec/timeline.py): bounded
-    # (lane, hop, split, t0, t1, bytes) records merged by the slice's
-    # own union-and-truncate law; shipped cross-process as skew-free
-    # ages, so a worker's slice stitches to the coordinator's without
-    # clock-skew-negative intervals
-    timeline: TimelineSlice = \
-        dataclasses.field(default_factory=TimelineSlice)
 
     # -- convenience accessors (the EXPLAIN ANALYZE / CLI summary view) --
 
@@ -260,8 +252,7 @@ class QueryStats:
             task_count=self.task_count + other.task_count,
             stages=stages, operators=operators, counters=counters,
             datapath=merge_hop_maps(self.datapath, other.datapath),
-            accuracy=merge_record_maps(self.accuracy, other.accuracy),
-            timeline=self.timeline.merge(other.timeline))
+            accuracy=merge_record_maps(self.accuracy, other.accuracy))
 
     def to_json(self) -> dict:
         return {"wallUs": self.wall_us,
@@ -274,8 +265,7 @@ class QueryStats:
                               for k, o in self.operators.items()},
                 "counters": dict(self.counters),
                 "datapath": hop_map_to_json(self.datapath),
-                "accuracy": record_map_to_json(self.accuracy),
-                "timeline": self.timeline.to_json()}
+                "accuracy": record_map_to_json(self.accuracy)}
 
     @classmethod
     def from_json(cls, doc: dict) -> "QueryStats":
@@ -293,11 +283,10 @@ class QueryStats:
                       for k, v in doc.get("counters", {}).items()},
             datapath=hop_map_from_json(doc.get("datapath", {})),
             # old-doc tolerance: records shipped before this field
-            # existed deserialize to the empty map (merge identity)
-            accuracy=record_map_from_json(doc.get("accuracy", {})),
-            # same tolerance: a missing timeline key is the empty
-            # slice (merge identity), never an error
-            timeline=TimelineSlice.from_json(doc.get("timeline", {})))
+            # existed deserialize to the empty map (merge identity);
+            # a key this version no longer reads (an older worker's
+            # `timeline`) is ignored
+            accuracy=record_map_from_json(doc.get("accuracy", {})))
 
     def summary(self) -> str:
         """One-paragraph human summary (the CLI --stats shape)."""
@@ -499,7 +488,7 @@ class _SpanTimer:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.time()
+        t1 = self.t1 = time.time()
         self._trace.__exit__(*exc)
         c = self.c
         if c is None:

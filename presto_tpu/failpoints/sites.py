@@ -128,14 +128,6 @@ SITES: Dict[str, Tuple[str, str]] = {
         "match the donation-off oracle, the fallback is counted "
         "presto_tpu_donation_fallbacks_total and recorded as a "
         "donation_fallback flight event"),
-    "timeline.record": (
-        "timeline",
-        "execution-timeline interval append (exec/timeline."
-        "record_interval, before the ledger fold): an error action "
-        "degrades the query's ledger STICKY to counted totals -- "
-        "intervals drop (counted in `dropped`), the query succeeds with "
-        "matching rows, the degradation is counted in the process "
-        "registry and recorded as a timeline_degraded flight event"),
 }
 
 

@@ -40,12 +40,10 @@ CONCURRENCY_TARGETS = (
     "presto_tpu/failpoints/*.py",
     "presto_tpu/exec/progress.py",
     "presto_tpu/exec/batching.py",
-    "presto_tpu/exec/profiler.py",
     "presto_tpu/exec/plan_cache.py",
     "presto_tpu/exec/regions.py",
     "presto_tpu/exec/datapath.py",
     "presto_tpu/exec/accuracy.py",
-    "presto_tpu/exec/timeline.py",
     "presto_tpu/utils/locks.py",
 )
 

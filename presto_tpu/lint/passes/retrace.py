@@ -42,9 +42,8 @@ __all__ = ["RetracePass", "kernel_mode_envs"]
 _KNOWN_KEYED_ENVS = ("PRESTO_TPU_SMALLG", "PRESTO_TPU_SMALLG_PALLAS",
                      "PRESTO_TPU_NARROW", "PRESTO_TPU_BF16",
                      "PRESTO_TPU_GROUPBY", "PRESTO_TPU_FUSION",
-                     "PRESTO_TPU_KERNEL_AUDIT", "PRESTO_TPU_PROFILE",
-                     "PRESTO_TPU_BATCHING", "PRESTO_TPU_DONATION",
-                     "PRESTO_TPU_TIMELINE")
+                     "PRESTO_TPU_KERNEL_AUDIT", "PRESTO_TPU_BATCHING",
+                     "PRESTO_TPU_DONATION")
 
 _ENV_ROOTS = ("os", "_os")
 _CLOCK_CALLS = {("time", "time"), ("time", "monotonic"),

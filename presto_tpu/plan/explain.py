@@ -295,10 +295,8 @@ def explain_analyze(root: N.PlanNode, sf: float = 0.01, **kwargs) -> str:
                      f"wall: {qs.wall_us}us")
     else:
         lines += ["", f"output rows: {res.row_count}"]
-    lines.extend(_kernel_lines(executed, session))
     lines.extend(_datapath_lines(qs))
     lines.extend(_accuracy_lines(qs))
-    lines.extend(_timeline_lines(qs))
     # the flat named counters keep their historical tail section
     if res.stats:
         lines += ["", "-- runtime counters --"]
@@ -306,42 +304,6 @@ def explain_analyze(root: N.PlanNode, sf: float = 0.01, **kwargs) -> str:
             lines.append(f"{name}: total={s['total']} count={s['count']} "
                          f"max={s['max']}")
     return "\n".join(lines)
-
-
-def _kernel_lines(executed: N.PlanNode, session,
-                  top: int = 3) -> List[str]:
-    """EXPLAIN ANALYZE's continuous-profiler tail: the top-k hottest
-    kernels in this process's registry (exec/profiler.py), with the
-    kernel this very plan executed marked -- so 'which kernel is
-    burning the device' reads straight off the analyze output."""
-    from ..exec.profiler import profile_snapshot, profiling_enabled
-    if not profiling_enabled(session):
-        return []
-    try:
-        from ..exec.plan_cache import plan_fingerprint
-        this_fp = plan_fingerprint(executed)
-        rows = profile_snapshot(top=top)
-        if not any(r["fingerprint"] == this_fp for r in rows):
-            # this query's kernel may be outside the process top-k;
-            # always show it (that is the question being asked)
-            rows += [r for r in profile_snapshot()
-                     if r["fingerprint"] == this_fp]
-    except Exception:  # noqa: BLE001 - profiler annotation is garnish;
-        # EXPLAIN ANALYZE output must never fail on it
-        return []
-    if not rows:
-        return []
-    lines = ["", f"-- kernels (top {top} device time, process-wide) --"]
-    for r in rows:
-        marker = "  <- this query" \
-            if r["fingerprint"] == this_fp else ""
-        mean_us = r["device_us"] // max(r["calls"], 1)
-        lines.append(
-            f"{r['fingerprint'][:12]} device={r['device_us']}us "
-            f"calls={r['calls']} mean={mean_us}us "
-            f"retraces={r['retraces']} rows_out={r['rows_out']} "
-            f"{r['label']}{marker}")
-    return lines
 
 
 def _datapath_lines(qs) -> List[str]:
@@ -412,37 +374,6 @@ def _accuracy_lines(qs) -> List[str]:
             lines.append(f"verdict: {verdict['message']} ({qual})")
         return lines
     except Exception:  # noqa: BLE001 - the ledger is garnish here;
-        # EXPLAIN ANALYZE output must never fail on it
-        return []
-
-
-def _timeline_lines(qs) -> List[str]:
-    """EXPLAIN ANALYZE's execution-timeline tail (exec/timeline.py):
-    an ASCII Gantt per lane over THIS query's recorded intervals,
-    closed by the occupancy summary and the bubble verdict naming the
-    hop the device spent its idle wall waiting on."""
-    try:
-        from ..exec.timeline import ascii_gantt, bubble_verdict, occupancy
-        if qs is None or qs.timeline.is_empty():
-            return []
-        intervals = qs.timeline.intervals
-        occ = occupancy(intervals)
-        if occ is None:
-            return []
-        lines = ["", "-- timeline --"]
-        lines.extend(ascii_gantt(intervals))
-        lines.append(
-            f"wall={occ['wallUs']}us "
-            f"overlap={occ['overlapFraction']:.0%} "
-            f"device_idle={occ['deviceIdleUs']}us "
-            f"({occ['deviceIdleFraction']:.0%})"
-            + (f" dropped={qs.timeline.dropped}"
-               if qs.timeline.dropped else ""))
-        verdict = bubble_verdict(intervals, occ)
-        if verdict is not None:
-            lines.append(f"verdict: {verdict['message']}")
-        return lines
-    except Exception:  # noqa: BLE001 - the Gantt is garnish here;
         # EXPLAIN ANALYZE output must never fail on it
         return []
 

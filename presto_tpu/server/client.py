@@ -132,39 +132,25 @@ class WorkerClient:
         data, _ = self._request("GET", "/v1/info")
         return json.loads(data)
 
-    def profile(self) -> dict:
-        """The worker's per-kernel profile slice (GET /v1/profile) --
-        authenticated/TLS'd like every other internal hop, so the
-        coordinator's cluster merge works on secured clusters too."""
-        data, _ = self._request("GET", "/v1/profile")
-        return json.loads(data)
-
     def history(self) -> dict:
-        """The worker's completed-query history slice (GET /v1/history),
-        pulled over the same authenticated transport as profile() so
+        """The worker's completed-query history slice (GET /v1/history)
+        -- authenticated/TLS'd like every other internal hop, so
         the statement tier's cluster merge works on secured clusters."""
         data, _ = self._request("GET", "/v1/history")
         return json.loads(data)
 
     def datapath(self) -> dict:
         """The worker's per-hop data-path slice (GET /v1/datapath),
-        pulled over the same authenticated transport as profile() so
+        pulled over the same authenticated transport as history() so
         the statement tier's cluster merge works on secured clusters."""
         data, _ = self._request("GET", "/v1/datapath")
         return json.loads(data)
 
     def accuracy(self) -> dict:
         """The worker's estimate-accuracy slice (GET /v1/accuracy),
-        pulled over the same authenticated transport as profile() so
+        pulled over the same authenticated transport as history() so
         the statement tier's cluster merge works on secured clusters."""
         data, _ = self._request("GET", "/v1/accuracy")
-        return json.loads(data)
-
-    def timeline(self) -> dict:
-        """The worker's execution-timeline slice (GET /v1/timeline),
-        pulled over the same authenticated transport as profile() so
-        the statement tier's cluster merge works on secured clusters."""
-        data, _ = self._request("GET", "/v1/timeline")
         return json.loads(data)
 
     def status(self) -> dict:
@@ -339,7 +325,7 @@ def pull_worker_docs(worker_urls, timeout: float, fetch,
                      component: str, site: str = "cluster_pull",
                      parallel: bool = False, placeholder=None):
     """The one best-effort cluster pull the merged surfaces
-    (/v1/profile, /v1/history, /v1/cluster) share: fetch one document
+    (/v1/datapath, /v1/history, /v1/cluster) share: fetch one document
     per reachable worker through an authenticated WorkerClient,
     skip-and-count the unreachable ones (never an error).
     ``fetch(client) -> dict``; returns (docs, workers_pulled) with

@@ -152,24 +152,14 @@ class FlightRecorder:
         try:
             os.makedirs(self.dump_dir, exist_ok=True)
             events = self.events(query_id=key)
-            kernels = self._profile_of(key)
             datapath = self._datapath_of(key)
             accuracy = self._accuracy_of(key)
-            timeline = self._timeline_of(key)
             with open(path, "w") as f:
                 f.write(json.dumps(
                     {"dump": {"key": key, "reason": reason,
                               "tsUs": int(time.time() * 1_000_000),
                               "events": len(events),
                               **(extra or {})}}) + "\n")
-                if kernels:
-                    # the continuous profiler's view of THIS query's
-                    # kernels (cross-linked by plan fingerprint): a
-                    # slow-query dump answers "which kernel" offline,
-                    # without a live /v1/profile to ask
-                    f.write(json.dumps(
-                        {"profile": {"queryId": key,
-                                     "kernels": kernels}}) + "\n")
                 if datapath:
                     # the data-path waterfall of THIS query (per-hop
                     # bytes/wall): a slow-query dump answers "which
@@ -185,14 +175,6 @@ class FlightRecorder:
                     f.write(json.dumps(
                         {"accuracy": {"queryId": key,
                                       "nodes": accuracy}}) + "\n")
-                if timeline:
-                    # the execution timeline of THIS query (lane/hop
-                    # intervals + occupancy verdict): a slow-query dump
-                    # answers "what was the device waiting on" offline,
-                    # without a live /v1/timeline to ask
-                    f.write(json.dumps(
-                        {"timeline": {"queryId": key,
-                                      **timeline}}) + "\n")
                 for evt in events:
                     f.write(json.dumps(evt, default=str) + "\n")
         except Exception as e:  # noqa: BLE001 - a full disk must not
@@ -241,8 +223,8 @@ class FlightRecorder:
 
     @staticmethod
     def _datapath_of(key: str) -> dict:
-        """This query's per-hop ledger (best-effort, like the profile
-        embed)."""
+        """This query's per-hop ledger (best-effort: a dump without
+        it beats no dump)."""
         try:
             from ..exec.datapath import datapath_for_query
             return datapath_for_query(key)
@@ -255,7 +237,7 @@ class FlightRecorder:
     @staticmethod
     def _accuracy_of(key: str) -> dict:
         """This query's per-node estimate-vs-actual records
-        (best-effort, like the profile embed)."""
+        (best-effort, like the datapath embed)."""
         try:
             from ..exec.accuracy import accuracy_for_query
             return accuracy_for_query(key)
@@ -264,33 +246,6 @@ class FlightRecorder:
             from .metrics import record_suppressed
             record_suppressed("flight_recorder", "accuracy_snapshot", e)
             return {}
-
-    @staticmethod
-    def _timeline_of(key: str) -> dict:
-        """This query's lane/hop interval ledger + occupancy verdict
-        (best-effort, like the profile embed)."""
-        try:
-            from ..exec.timeline import timeline_for_query
-            return timeline_for_query(key)
-        except Exception as e:  # noqa: BLE001 - the dump must land
-            # even when the ledger is broken; count the gap
-            from .metrics import record_suppressed
-            record_suppressed("flight_recorder", "timeline_snapshot", e)
-            return {}
-
-    @staticmethod
-    def _profile_of(key: str) -> List[dict]:
-        """Top device-time kernel rows the profiler attributed to this
-        query/task id (best-effort: a dump with no profile beats no
-        dump)."""
-        try:
-            from ..exec.profiler import profile_for_query
-            return profile_for_query(key, top=8)
-        except Exception as e:  # noqa: BLE001 - the dump must land even
-            # when the profiler is broken; count the gap
-            from .metrics import record_suppressed
-            record_suppressed("flight_recorder", "profile_snapshot", e)
-            return []
 
 
 _recorder: Optional[FlightRecorder] = None

@@ -1,17 +1,17 @@
 """Query history archive + in-engine perf regression sentinel.
 
 The operational gap this closes: the engine can explain ONE query in
-exhaustive detail (QueryStats, traces, flight dumps, kernel profiles)
+exhaustive detail (QueryStats, traces, flight dumps)
 but retains nothing once the statement TTL reaps it -- "is the cluster
 slower than it was yesterday" has no in-engine answer. This module is
 the cross-query, cross-run performance memory: one structured record
-per completed statement (plan-cache fingerprint, the session's
+per completed statement (baseline fingerprint, the session's
 kernel-mode env knobs, the QueryStats rollup, trace id, failpoint
-hits, top-kernel device shares), kept in a bounded in-memory archive,
+hits), kept in a bounded in-memory archive,
 persisted as a JSONL ring under ``PRESTO_TPU_HISTORY_DIR`` (retention
 caps on both file count and records per file), served at
-``GET /v1/history`` (the statement tier merges worker slices exactly
-like ``/v1/profile``, deduplicated by processId), and queryable as
+``GET /v1/history`` (the statement tier merges worker slices,
+deduplicated by processId), and queryable as
 ``SELECT * FROM system.query_history``.
 
 The SENTINEL rides every append: each FINISHED query's metric vector
@@ -54,9 +54,8 @@ __all__ = ["QueryHistoryArchive", "get_history_archive",
 
 HISTORY_DIR_ENV = "PRESTO_TPU_HISTORY_DIR"
 
-# one id per process (the cluster merge's dedup key, like the
-# profiler's): two server shells over one process fold their shared
-# archive exactly once
+# one id per process (the cluster merge's dedup key): two server shells
+# over one process fold their shared archive exactly once
 _PROCESS_ID = None
 
 
@@ -107,19 +106,17 @@ def _kernel_mode_envs() -> Dict[str, str]:
             for name, default in KERNEL_MODE_ENVS}
 
 
-def _fingerprint_of(kernels: List[str], text: str,
-                    kernel_mode: Dict[str, str],
+def _fingerprint_of(text: str, kernel_mode: Dict[str, str],
                     session: Optional[dict] = None) -> str:
-    """The baseline key: the executed plan-cache fingerprints when the
-    profiler attributed any (the plan identity, immune to whitespace /
-    literal formatting), else the collapsed statement text -- both
-    salted with the kernel-mode envs (a PRESTO_TPU_NARROW=0 A/B run
-    baselines separately instead of alarming against the narrow form)
-    AND the session's scale factor: the text fallback would otherwise
-    merge sf=0.01 and sf=1.0 runs of the same SQL into one baseline
-    and page on the ~100x wall of a legitimate workload change."""
-    basis = ",".join(kernels) if kernels else \
-        " ".join(text.lower().split())
+    """The baseline key: the collapsed statement text (a plan-cache
+    fingerprint changes with every literal too, so the text keys at
+    the same grain), salted with the kernel-mode envs (a
+    PRESTO_TPU_NARROW=0 A/B run baselines separately instead of
+    alarming against the narrow form) AND the session's scale factor:
+    sf=0.01 and sf=1.0 runs of the same SQL would otherwise merge into
+    one baseline and page on the ~100x wall of a legitimate workload
+    change."""
+    basis = " ".join(text.lower().split())
     mode = "|".join(f"{k}={v}" for k, v in sorted(kernel_mode.items()))
     sf = str((session or {}).get("sf", ""))
     return hashlib.sha256(
@@ -179,9 +176,8 @@ class QueryHistoryArchive:
                   ) -> dict:
         """Build one archive record from a terminal statement. Pure
         assembly over already-collected telemetry (QueryStats, the
-        profiler's query->fingerprint attribution, the flight ring's
-        failpoint events) -- never raises on partial inputs: a record
-        with zeros beats no record."""
+        flight ring's failpoint events) -- never raises on partial
+        inputs: a record with zeros beats no record."""
         qs = query_stats
         staging = qs.stages.get("staging") if qs is not None else None
         stats = {
@@ -239,22 +235,6 @@ class QueryHistoryArchive:
             from .metrics import record_suppressed
             record_suppressed("history", "accuracy_snapshot", e)
         stats["max_q_error"] = round(max_q, 4)
-        kernels: List[str] = []
-        top: List[dict] = []
-        try:
-            from ..exec.profiler import (profile_for_query,
-                                         query_fingerprints)
-            kernels = query_fingerprints(query_id)
-            rows = profile_for_query(query_id, top=3)
-            total = sum(int(r.get("device_us", 0)) for r in rows) or 1
-            top = [{"fingerprint": r["fingerprint"],
-                    "device_us": int(r.get("device_us", 0)),
-                    "share": round(int(r.get("device_us", 0)) / total, 4)}
-                   for r in rows]
-        except Exception as e:  # noqa: BLE001 - a record without kernel
-            # attribution still archives; count the gap
-            from .metrics import record_suppressed
-            record_suppressed("history", "profiler_snapshot", e)
         failpoint_hits = 0
         try:
             from .flight_recorder import get_flight_recorder
@@ -271,14 +251,12 @@ class QueryHistoryArchive:
             "user": str(user),
             "query": str(text)[:200],
             "tsUs": int(time.time() * 1_000_000),
-            "fingerprint": _fingerprint_of(kernels, text, kernel_mode,
+            "fingerprint": _fingerprint_of(text, kernel_mode,
                                            session=session),
-            "kernels": kernels,
             "kernelModeEnvs": kernel_mode,
             "traceId": str(trace_id),
             "stats": stats,
             "failpointHits": failpoint_hits,
-            "topKernels": top,
             "accuracy": accuracy_rows,
             "misestimatedNode": misestimated,
             "session": {k: str(v) for k, v in (session or {}).items()

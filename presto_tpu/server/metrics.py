@@ -736,41 +736,6 @@ def donation_families() -> List[MetricFamily]:
     ]
 
 
-def timeline_families() -> List[MetricFamily]:
-    """Execution-timeline totals (exec/timeline.py), exported by BOTH
-    tiers with a stable zero shape: lifetime interval/drop/query
-    counters plus the last completed query's occupancy headline
-    (overlap fraction and device-idle wall) -- the gauges the async
-    -pipeline ROADMAP item is sentineled against."""
-    from ..exec.timeline import last_occupancy, timeline_totals
-    t = timeline_totals()
-    last = last_occupancy()
-    return [
-        MetricFamily("presto_tpu_timeline_intervals_total", "counter",
-                     "execution-timeline intervals retained across "
-                     "queries (exec/timeline.py; see DESIGN.md "
-                     "'Execution timeline & occupancy')").add(
-                         t["intervals"]),
-        MetricFamily("presto_tpu_timeline_dropped_total", "counter",
-                     "intervals dropped by the per-query cap or "
-                     "totals-only degradation (never a query "
-                     "failure)").add(t["dropped"]),
-        MetricFamily("presto_tpu_timeline_queries_total", "counter",
-                     "queries that contributed a timeline slice").add(
-                         t["queries"]),
-        MetricFamily("presto_tpu_overlap_fraction", "gauge",
-                     "last query's host-staging/device-dispatch "
-                     "overlap fraction (0 = strictly serial pipeline; "
-                     "the async-ingest baseline)").add(
-                         float(last.get("overlapFraction", 0.0))),
-        MetricFamily("presto_tpu_device_idle_us", "gauge",
-                     "last query's device-idle wall within the "
-                     "timeline extent (the bubble the occupancy "
-                     "verdict attributes per hop)").add(
-                         int(last.get("deviceIdleUs", 0))),
-    ]
-
-
 def live_introspection_families(workers_alive: Optional[int] = None
                                 ) -> List[MetricFamily]:
     """Live-cluster introspection gauges + the stuck-progress counter,

@@ -185,8 +185,9 @@ class StatementServer:
                  tls: Optional[tuple] = None,
                  profile_workers=None):
         """`profile_workers`: worker base URLs (list, or zero-arg
-        callable returning one) whose GET /v1/profile slices the
-        cluster-merged GET /v1/profile on THIS server folds in --
+        callable returning one) whose GET /v1/datapath, /v1/accuracy
+        and /v1/history slices the cluster-merged documents of the
+        same routes on THIS server fold in --
         wire the coordinator's worker view here on the distributed
         tier; None serves this process's slice alone."""
         self.sf = sf
@@ -1005,9 +1006,6 @@ class StatementServer:
             # estimate-accuracy lifetime summary (worst q-error + its
             # node): the ptop header's accuracy line
             "accuracy": self._accuracy_summary(),
-            # execution-timeline occupancy headline (overlap fraction,
-            # device-idle wall): the ptop occupancy line
-            "timeline": self._timeline_summary(),
         }
 
     def _accuracy_summary(self) -> dict:
@@ -1032,18 +1030,6 @@ class StatementServer:
             # take down the fleet overview
             from .metrics import record_suppressed
             record_suppressed("statement", "datapath_summary", e)
-            return {}
-
-    def _timeline_summary(self) -> dict:
-        """The cheap per-frame occupancy embed (never fails the fleet
-        overview)."""
-        try:
-            from ..exec.timeline import timeline_summary
-            return timeline_summary()
-        except Exception as e:  # noqa: BLE001 - introspection must not
-            # take down the fleet overview
-            from .metrics import record_suppressed
-            record_suppressed("statement", "timeline_summary", e)
             return {}
 
     def _batching_doc(self) -> dict:
@@ -1135,27 +1121,17 @@ class StatementServer:
         fams.extend(kernel_audit_families())
         fams.extend(donation_families())
         fams.extend(failpoint_families())
-        from .metrics import timeline_families
-        fams.extend(timeline_families())
         from .metrics import lock_families
         fams.extend(lock_families())
         fams.extend(query_history_families())
         fams.extend(histogram_families())
         return fams
 
-    def profile_doc(self) -> dict:
-        """Cluster-merged per-kernel profile for GET /v1/profile: this
-        process's slice plus every configured worker's, folded by
-        fingerprint (exec/profiler.py; process-id dedup keeps an
-        in-process worker from double-counting)."""
-        from ..exec.profiler import cluster_profile_doc
-        return cluster_profile_doc(self._worker_urls())
-
     def history_doc(self) -> dict:
         """Cluster-merged completed-query history for GET /v1/history
         (server/history.py): this process's archive slice plus every
         configured worker's, newest-first, deduplicated by processId
-        like the profile merge."""
+        (an in-process worker is not counted twice)."""
         from .history import cluster_history_doc
         return cluster_history_doc(self._worker_urls())
 
@@ -1163,8 +1139,7 @@ class StatementServer:
         """Cluster-merged per-hop data-path ledger for GET
         /v1/datapath: this process's slice plus every configured
         worker's, folded by hop (exec/datapath.py; processId dedup
-        keeps an in-process worker from double-counting, exactly like
-        the profile merge)."""
+        keeps an in-process worker from double-counting)."""
         from ..exec.datapath import cluster_datapath_doc
         return cluster_datapath_doc(self._worker_urls())
 
@@ -1173,23 +1148,13 @@ class StatementServer:
         /v1/accuracy: this process's slice plus every configured
         worker's, per-query records stitched by the NodeAccuracy merge
         law (exec/accuracy.py; processId dedup keeps an in-process
-        worker from double-counting, exactly like the profile merge)."""
+        worker from double-counting)."""
         from ..exec.accuracy import cluster_accuracy_doc
         return cluster_accuracy_doc(self._worker_urls())
 
-    def timeline_doc(self) -> dict:
-        """Cluster-merged execution-timeline ledger for GET
-        /v1/timeline: this process's slice plus every configured
-        worker's, per-query interval slices stitched on a shared
-        reference clock (exec/timeline.py; processId dedup keeps an
-        in-process worker from double-counting, exactly like the
-        profile merge)."""
-        from ..exec.timeline import cluster_timeline_doc
-        return cluster_timeline_doc(self._worker_urls())
-
     def _worker_urls(self) -> list:
         """The worker base URLs the cluster-merged surfaces
-        (/v1/profile, /v1/history) pull slices from."""
+        (/v1/datapath, /v1/accuracy, /v1/history) pull slices from."""
         pw = self._profile_workers
         return list(pw() if callable(pw) else (pw or ()))
 
@@ -1332,11 +1297,6 @@ def _make_handler(server: StatementServer):
                 # document scripts/ptop.py renders)
                 self._send(server.cluster_doc())
                 return
-            if parts == ["v1", "profile"]:
-                # cluster-merged per-kernel device-time table (the
-                # continuous profiler's coordinator surface)
-                self._send(server.profile_doc())
-                return
             if parts == ["v1", "datapath"]:
                 # cluster-merged per-hop byte/throughput ledger with
                 # roofline bottleneck verdicts (exec/datapath.py)
@@ -1346,11 +1306,6 @@ def _make_handler(server: StatementServer):
                 # cluster-merged per-plan-node estimate-vs-actual
                 # ledger with misestimate verdicts (exec/accuracy.py)
                 self._send(server.accuracy_doc())
-                return
-            if parts == ["v1", "timeline"]:
-                # cluster-merged execution-timeline ledger with
-                # occupancy/bubble verdicts (exec/timeline.py)
-                self._send(server.timeline_doc())
                 return
             if parts == ["v1", "history"]:
                 # cluster-merged completed-query archive (the perf

@@ -1014,8 +1014,6 @@ class _Handler(BaseHTTPRequestHandler):
         fams.extend(kernel_audit_families())
         fams.extend(donation_families())
         fams.extend(failpoint_families())
-        from .metrics import timeline_families
-        fams.extend(timeline_families())
         from .metrics import lock_families
         fams.extend(lock_families())
         from .metrics import (fleet_families,
@@ -1059,11 +1057,6 @@ class _Handler(BaseHTTPRequestHandler):
             self.end_headers()
             self.wfile.write(body)
             return
-        if parts == ["v1", "profile"]:
-            # this worker's per-kernel profile slice (the coordinator
-            # pulls + merges these cluster-wide; exec/profiler.py)
-            from ..exec.profiler import profile_doc
-            return self._send_json(profile_doc())
         if parts == ["v1", "datapath"]:
             # this worker's per-hop data-path slice (the statement
             # tier pulls + merges these cluster-wide, same path shape;
@@ -1076,15 +1069,9 @@ class _Handler(BaseHTTPRequestHandler):
             # exec/accuracy.py)
             from ..exec.accuracy import accuracy_doc
             return self._send_json(accuracy_doc())
-        if parts == ["v1", "timeline"]:
-            # this worker's execution-timeline slice (the statement
-            # tier pulls + merges these cluster-wide with processId
-            # dedup; exec/timeline.py)
-            from ..exec.timeline import timeline_doc
-            return self._send_json(timeline_doc())
         if parts == ["v1", "history"]:
             # this process's completed-query archive slice (the
-            # statement tier merges these cluster-wide like /v1/profile;
+            # statement tier merges these cluster-wide like /v1/datapath;
             # server/history.py)
             from .history import get_history_archive
             return self._send_json(get_history_archive().history_doc())

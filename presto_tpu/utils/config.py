@@ -195,10 +195,10 @@ SESSION_PROPERTIES = (
          "fragment's operator chain as ONE XLA program per pipeline "
          "region, with fusion-plan choice (what to fuse vs materialize) "
          "driven by K005 footprint estimates against "
-         "kernel_audit_budget_bytes and the continuous profiler's "
-         "per-fingerprint device time (regressing fused regions demote "
-         "back to materialized boundaries). false = one program per "
-         "operator, the A/B + bisection mode (env PRESTO_TPU_FUSION, "
+         "kernel_audit_budget_bytes and the fused-against-materialized "
+         "device-time samples of exec/regions.FusionMemory (regressing "
+         "fused regions demote back to materialized boundaries). "
+         "false = one program per operator, the A/B + bisection mode (env PRESTO_TPU_FUSION, "
          "registered in KERNEL_MODE_ENVS)")
     .add("buffer_donation", "bool", False,
          "donate dead region-boundary buffers to XLA on proven-safe "
@@ -302,20 +302,6 @@ SESSION_PROPERTIES = (
          "preempts scans at admission (higher priority + weight), "
          "per-class concurrency and queue-depth limits apply "
          "(empty = the dispatcher's default group)")
-    .add("continuous_profiling", "bool", True,
-         "accumulate per-kernel device-time profiles keyed by plan "
-         "fingerprint (exec/profiler.py): calls, block_until_ready "
-         "device wall, rows/bytes in-out, retraces; served at "
-         "GET /v1/profile and SELECT * FROM system.kernels (env "
-         "default PRESTO_TPU_PROFILE; on by default -- the overhead "
-         "is one clock pair and a dict update per query)")
-    .add("timeline", "bool", True,
-         "record per-query execution-timeline intervals (exec/"
-         "timeline.py): (lane, hop, split, t0, t1, bytes) spans at the "
-         "datapath seams, powering occupancy/bubble verdicts, "
-         "GET /v1/timeline, system.occupancy and the Chrome trace "
-         "export (env default PRESTO_TPU_TIMELINE; on by default -- "
-         "bounded to 4096 intervals per query, totals-only beyond)")
 )
 
 

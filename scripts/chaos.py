@@ -104,7 +104,6 @@ OP_ROUNDS = [
     ("task", "stuck"),
     ("fusion", "demote"),
     ("fusion", "donation"),
-    ("timeline", "timeline_degrade"),
     ("fleet", "elastic"),
     ("fleet", "speculate"),
 ]
@@ -604,51 +603,6 @@ class ChaosRun:
                           "donation_fallback flight event")
                 return "NO_FLIGHT_EVENT"
             return "match+fallback"
-        if op == "timeline_degrade":
-            # forced interval-ledger failure (this PR): with timeline
-            # recording on (the default), the timeline.record failpoint
-            # kills the first interval append -- the ledger must
-            # degrade STICKY to counted totals (intervals drop, hop
-            # totals keep folding), the query must still match its
-            # fault-free oracle, the degradation must be counted in the
-            # process registry, and a timeline_degraded flight event
-            # must land on the query's timeline
-            from presto_tpu.exec.timeline import timeline_totals
-            from presto_tpu.queries.tpch_sql import tpch_query
-            from presto_tpu.sql import sql as engine_sql
-            step["site"], step["spec"] = "timeline.record", "error:once"
-            q = tpch_query(6)
-            oracle = engine_sql(q.text, sf=self.sf,
-                                session={"timeline": False},
-                                max_groups=q.max_groups)
-            before = timeline_totals()["degraded"]
-            cluster.arm(step["site"], step["spec"])
-            try:
-                res = engine_sql(q.text, sf=self.sf,
-                                 max_groups=q.max_groups)
-            except BaseException as e:  # noqa: BLE001 - verdict
-                self.fail(f"timeline round: query FAILED under forced "
-                          f"ledger degradation: {type(e).__name__}: {e}")
-                return f"clean_failure:{type(e).__name__}"
-            if res.canonical_rows() != oracle.canonical_rows():
-                self.fail("timeline round: forced degradation returned "
-                          "WRONG rows")
-                return "WRONG_RESULT"
-            if timeline_totals()["degraded"] - before < 1:
-                self.fail("timeline round: the failpoint fired but no "
-                          "degradation was counted")
-                return "UNACCOUNTED_DEGRADATION"
-            qs = res.query_stats
-            if qs.timeline.intervals or not qs.timeline.totals:
-                self.fail("timeline round: degraded ledger must keep "
-                          "counted totals and drop intervals")
-                return "NOT_DEGRADED_TO_TOTALS"
-            if not get_flight_recorder().events(
-                    kind="timeline_degraded"):
-                self.fail("timeline round: degradation without a "
-                          "timeline_degraded flight event")
-                return "NO_FLIGHT_EVENT"
-            return "match+degraded"
         if op == "elastic":
             # the elastic-fleet acceptance round: an 8-worker
             # discovery-backed cluster changes shape MID-QUERY -- kill

@@ -91,16 +91,6 @@ def render(doc: dict) -> str:
             f"misest {acc.get('misestimates', 0)}  "
             f"worst q {acc.get('worstQError', 0.0):.2f}x"
             + (f" ({worst})" if worst else ""))
-    # execution-timeline occupancy roll-up (exec/timeline.py): the last
-    # query's host/device overlap fraction and device-idle wall --
-    # zero overlap reads "the pipeline ran strictly serial"
-    tl = doc.get("timeline") or {}
-    if tl:
-        lines.append(
-            f"occupancy overlap {tl.get('overlapFraction', 0.0):.0%}  "
-            f"device idle {tl.get('deviceIdleUs', 0) / 1000.0:.1f}ms  "
-            f"intervals {tl.get('intervals', 0)} "
-            f"({tl.get('dropped', 0)} dropped)")
     lines.append("-" * 78)
     running = doc.get("runningQueries", [])
     if not running:

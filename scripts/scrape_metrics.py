@@ -133,17 +133,6 @@ DONATION_FAMILIES = (
     "presto_tpu_donation_fallbacks_total",
 )
 
-# execution-timeline occupancy (exec/timeline.py): its own
-# always-present section, zeros included -- interval/drop/query counter
-# deltas plus the overlap-fraction and device-idle gauges. "Overlap
-# stayed at zero this window" is an answer a pipeline-occupancy
-# investigation needs stated, not implied.
-TIMELINE_FAMILY_PREFIX = "presto_tpu_timeline"
-TIMELINE_FAMILIES = (
-    "presto_tpu_overlap_fraction",
-    "presto_tpu_device_idle_us",
-)
-
 
 _LE_RE = re.compile(r'le="([^"]+)"')
 
@@ -191,7 +180,7 @@ def diff(before: dict, after: dict) -> dict:
     out = {"counters": {}, "gauges": {}, "tracing": {}, "faults": {},
            "history": {}, "cluster": {}, "fleet": {}, "locks": {},
            "datapath": {}, "accuracy": {}, "donation": {},
-           "timeline": {}, "histograms": {}, "violations": {}}
+           "histograms": {}, "violations": {}}
     hist_bases = set()
     for fam, samples in after.items():
         if fam.endswith("_bucket"):
@@ -211,8 +200,6 @@ def diff(before: dict, after: dict) -> dict:
         is_fleet = fam in FLEET_FAMILIES
         is_locks = fam in LOCK_FAMILIES
         is_donation = fam in DONATION_FAMILIES
-        is_timeline = fam.startswith(TIMELINE_FAMILY_PREFIX) \
-            or fam in TIMELINE_FAMILIES
         for key, val in samples.items():
             label = fam + key
             if is_counter:
@@ -249,9 +236,6 @@ def diff(before: dict, after: dict) -> dict:
                     # donated dispatches / bytes / fallback deltas,
                     # zeros included
                     out["donation"][label] = round(delta, 6)
-                elif is_timeline:
-                    # interval/drop/query deltas, zeros included
-                    out["timeline"][label] = round(delta, 6)
                 elif fam in TRACING_FAMILIES:
                     out["tracing"][label] = round(delta, 6)
                 elif delta:
@@ -265,11 +249,6 @@ def diff(before: dict, after: dict) -> dict:
                 # deltas: "0 new misestimates, worst ever 47x" reads
                 # off one block
                 out["accuracy"][label] = round(val, 6)
-            elif is_timeline:
-                # the overlap/idle gauges ride beside the interval
-                # deltas: "overlap 0, device idle 31ms" reads off one
-                # block
-                out["timeline"][label] = round(val, 6)
             elif is_history:
                 # the archive-size gauge rides the history section:
                 # "N records retained, 0 regressions" reads off one block

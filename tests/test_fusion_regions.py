@@ -1,6 +1,6 @@
 """Pipeline-region fusion compiler (exec/regions.py + the runner's
 region executor): partition law, bit-exact fused-vs-materialized oracle
-match over the TPC-H corpus, footprint refusal, profiler demotion,
+match over the TPC-H corpus, footprint refusal, demotion,
 plan-cache behavior, and IR-audit cleanliness of the fused corpus.
 """
 
@@ -47,7 +47,7 @@ def _canon(res):
 
 def test_fused_default_is_one_region_keeping_the_plan_fingerprint():
     """Fusion on + nothing refused = ONE region whose root IS the plan
-    (same object, same fingerprint) -- the profiler/history/kernaudit
+    (same object, same fingerprint) -- the plan-cache/kernaudit
     keying contract of the refactor."""
     root = _prepared()
     rp = partition_regions(root, sf=SF)
@@ -249,7 +249,7 @@ def test_live_kernel_audit_feeds_fusion_footprint():
     assert fusion_memory().footprint(fp) > 0
 
 
-# -- profiler-driven demotion -------------------------------------------
+# -- sample-driven demotion ---------------------------------------------
 
 
 def test_demotion_comparator_uses_perfgate_bands():
@@ -359,16 +359,6 @@ def test_join_free_region_reruns_do_not_fragment_cache():
 
 
 # -- provenance surfaces ------------------------------------------------
-
-
-def test_profiler_rows_carry_region_provenance():
-    from presto_tpu.exec.profiler import profile_snapshot
-    root = _prepared()
-    run_query(root, sf=SF, prepared=True, session={"fusion": False},
-              query_id="fusion_prov_q")
-    rows = [r for r in profile_snapshot() if "[region R" in r["label"]]
-    assert rows, "no region-tagged profile rows"
-    assert any(">" in r["label"] for r in rows)  # plan-node chain
 
 
 def test_explain_renders_region_annotations():

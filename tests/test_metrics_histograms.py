@@ -1,19 +1,14 @@
-"""Continuous kernel profiler + histogram metrics: the round-10
-observability layer.
+"""Histogram metrics (server/metrics.py).
 
 Covers: the Histogram merge law (associative / commutative / identity,
 the same contract QueryStats.merge carries), exposition-format
 compliance (cumulative ``le`` ladder, ``+Inf`` == ``_count``,
 exemplars, parse_prometheus round-trip), concurrent ``observe()``
-under threads, profiler registry bounded-size eviction, the
-cluster-wide ``/v1/profile`` merge E2E with two workers,
-``system.kernels`` via SQL, exemplar -> trace linkage, the
-flight-dump profiler embed, and scrape-side histogram quantile /
-counter-monotonicity analysis."""
+under threads, exemplar -> trace linkage, and scrape-side histogram
+quantile / counter-monotonicity analysis."""
 
 import json
 import threading
-import time
 import urllib.request
 
 import pytest
@@ -227,217 +222,6 @@ def test_exemplar_links_to_trace():
 
 
 # ---------------------------------------------------------------------------
-# profiler registry
-# ---------------------------------------------------------------------------
-
-
-def test_profiler_records_and_matches_query_stats():
-    from presto_tpu.exec.plan_cache import clear_plan_cache
-    from presto_tpu.exec.profiler import clear_profiler, profile_snapshot
-    from presto_tpu.queries.tpch_sql import tpch_query
-    from presto_tpu.sql import sql
-    clear_profiler()
-    # the retraces>=1 assertion below needs a COLD first execution;
-    # earlier suite files (fusion regions) may have warmed q1's
-    # compiled plan, which would skip the compile this test measures
-    clear_plan_cache()
-    q1 = tpch_query(1)
-    res = sql(q1.text, sf=0.01, max_groups=q1.max_groups)
-    assert res.row_count > 0
-    snap = profile_snapshot()
-    assert snap, "q1 execution did not land in the profiler"
-    top = snap[0]
-    qs = res.query_stats
-    exec_us = qs.stages["execute"].wall_us
-    comp = qs.stages.get("compile")
-    comp_us = comp.wall_us if comp else 0
-    # the acceptance bound: the hottest kernel's device time matches
-    # the QueryStats stage timings within measurement noise -- the
-    # execute stage wraps exactly the dispatch + block_until_ready this
-    # measures, minus the carved-out compile stage (cold dispatches
-    # must not book trace+XLA-compile as device occupancy)
-    expected = max(exec_us - comp_us, 0)
-    assert 0 <= top["device_us"] <= exec_us * 1.1 + 20_000
-    assert abs(top["device_us"] - expected) <= \
-        max(0.3 * max(expected, 1), 50_000)
-    assert top["calls"] >= 1
-    assert top["retraces"] >= 1          # first execution pays compile
-    assert top["rows_out"] == res.row_count
-    assert top["rows_in"] > 0 and top["bytes_in"] > 0
-    assert "lineitem" in top["tables"]
-    assert "TableScan[tpch.lineitem]" in top["label"]
-    # second run: cache hit -> calls grow, retraces do not
-    sql(q1.text, sf=0.01, max_groups=q1.max_groups)
-    again = [p for p in profile_snapshot()
-             if p["fingerprint"] == top["fingerprint"]][0]
-    assert again["calls"] == top["calls"] + 1
-    assert again["retraces"] == top["retraces"]
-
-
-def test_profiler_bounded_eviction():
-    from presto_tpu.exec import profiler
-    profiler.clear_profiler()
-    prev = profiler.set_capacity(4)
-    try:
-        for i in range(10):
-            profiler.record_call(f"fp{i:02d}", label=f"k{i}",
-                                 device_us=100 + i)
-        snap = profiler.profile_snapshot()
-        assert len(snap) == 4
-        fps = {p["fingerprint"] for p in snap}
-        assert fps == {"fp06", "fp07", "fp08", "fp09"}  # LRU out
-    finally:
-        profiler.set_capacity(prev)
-        profiler.clear_profiler()
-
-
-def test_merge_kernel_rows_dedups_process_slices():
-    from presto_tpu.exec.profiler import merge_kernel_rows
-    row = {"fingerprint": "abc", "calls": 2, "device_us": 100,
-           "max_device_us": 80, "rows_in": 10, "bytes_in": 100,
-           "rows_out": 1, "bytes_out": 8, "retraces": 1,
-           "footprint_bytes": 0, "label": "X", "tables": "t"}
-    other = dict(row, device_us=50, calls=1, max_device_us=50)
-    docs = [{"processId": "p1", "kernels": [row]},
-            {"processId": "p1", "kernels": [row]},   # same process twice
-            {"processId": "p2", "kernels": [other]}]
-    merged = merge_kernel_rows(docs)
-    assert len(merged) == 1
-    assert merged[0]["calls"] == 3            # p1 once + p2
-    assert merged[0]["device_us"] == 150
-    assert merged[0]["max_device_us"] == 80   # max law
-
-
-def test_cluster_profile_merge_two_workers_e2e():
-    from presto_tpu.exec.profiler import clear_profiler, profile_snapshot
-    from presto_tpu.plan.distribute import add_exchanges
-    from presto_tpu.server import Coordinator, TpuWorkerServer
-    from presto_tpu.server.statement import StatementServer
-    from presto_tpu.sql import plan_sql
-    clear_profiler()
-    ws = [TpuWorkerServer(sf=0.01).start() for _ in range(2)]
-    urls = [f"http://127.0.0.1:{w.port}" for w in ws]
-    try:
-        coord = Coordinator(urls)
-        dist = add_exchanges(plan_sql(
-            "SELECT regionkey, count(*) AS c FROM nation "
-            "GROUP BY regionkey", max_groups=64))
-        cols, _ = coord.execute(dist, sf=0.01)
-        # each worker serves its slice at GET /v1/profile
-        slices = []
-        for url in urls:
-            with urllib.request.urlopen(f"{url}/v1/profile") as r:
-                slices.append(json.loads(r.read().decode()))
-        assert all(doc["kernels"] for doc in slices)
-        assert all(doc["processId"] for doc in slices)
-        # the statement tier serves the cluster-merged table
-        with StatementServer(sf=0.01,
-                             profile_workers=lambda: urls) as srv:
-            with urllib.request.urlopen(f"{srv.url}/v1/profile") as r:
-                doc = json.loads(r.read().decode())
-        assert doc["cluster"] is True
-        assert doc["workersPulled"] == 2
-        assert doc["kernels"]
-        # in-process workers share one registry: processId dedup must
-        # fold the three identical slices into exactly the local view
-        local = {p["fingerprint"]: p for p in profile_snapshot()}
-        merged = {p["fingerprint"]: p for p in doc["kernels"]}
-        assert set(merged) == set(local)
-        for fp, p in merged.items():
-            assert p["calls"] == local[fp]["calls"]
-            assert p["device_us"] == local[fp]["device_us"]
-    finally:
-        for w in ws:
-            w.stop()
-
-
-def test_system_kernels_sql():
-    from presto_tpu.exec.profiler import clear_profiler
-    from presto_tpu.sql import sql
-    clear_profiler()
-    sql("SELECT count(*) AS n FROM region", sf=0.01)
-    res = sql("SELECT fingerprint, plan, calls, device_time_us, "
-              "retraces FROM system.kernels")
-    rows = res.rows()
-    assert rows, "system.kernels is empty after an executed query"
-    fp, plan, calls, device_us, retraces = rows[0]
-    assert len(fp) == 64 and int(calls) >= 1
-    assert "TableScan[tpch.region]" in plan
-    assert int(device_us) > 0
-
-
-def test_explain_analyze_kernel_section():
-    from presto_tpu.plan import explain_analyze
-    from presto_tpu.sql import plan_sql
-    text = explain_analyze(
-        plan_sql("SELECT nationkey FROM nation WHERE regionkey = 1"),
-        sf=0.01)
-    assert "-- kernels" in text
-    assert "<- this query" in text
-
-
-def test_failed_query_keeps_attribution():
-    """A query that fails mid-execute still lands in the registry (the
-    recording sits in run_query's finally), so its flight dump can
-    embed the kernels that burned device time before the failure."""
-    from presto_tpu.exec.profiler import (clear_profiler,
-                                          profile_for_query,
-                                          profile_snapshot)
-    from presto_tpu.sql import sql
-    clear_profiler()
-    with pytest.raises(RuntimeError, match="overflow"):
-        sql("SELECT custkey, count(*) AS c FROM orders GROUP BY custkey",
-            sf=0.01, max_groups=4,
-            session={"adaptive_capacity": False,
-                     "stats_capacity_refinement": False})
-    snap = profile_snapshot()
-    assert snap and snap[0]["calls"] == 1
-    assert snap[0]["rows_out"] == 0           # it never produced
-    assert profile_for_query("query")         # query-id cross-link
-
-
-def test_footprint_estimate_rides_profile_rows():
-    from presto_tpu.exec.profiler import clear_profiler, profile_snapshot
-    from presto_tpu.sql import sql
-    clear_profiler()
-    sql("SELECT sum(quantity) AS s FROM lineitem", sf=0.001,
-        session={"kernel_audit": True})
-    rows = [p for p in profile_snapshot() if "lineitem" in p["tables"]]
-    assert rows and rows[0]["footprint_bytes"] > 0
-
-
-def test_flight_dump_embeds_profile(tmp_path):
-    from presto_tpu.client import execute
-    from presto_tpu.server.flight_recorder import (FlightRecorder,
-                                                   set_flight_recorder)
-    from presto_tpu.server.statement import StatementServer
-    rec = FlightRecorder(dump_dir=str(tmp_path))
-    set_flight_recorder(rec)
-    try:
-        with StatementServer(sf=0.01) as srv:
-            r = execute(srv.url, "SELECT count(*) AS n FROM lineitem",
-                        session={"sf": "0.01",
-                                 "slow_query_threshold_ms": "1"})
-            qid = r.query_id
-            deadline = time.time() + 5
-            path = None
-            while path is None and time.time() < deadline:
-                path = rec.dump_path(qid)
-                time.sleep(0.05)
-        assert path is not None
-        lines = [json.loads(l) for l in open(path)]
-        assert lines[0]["dump"]["reason"] == "slow"
-        profs = [l for l in lines if "profile" in l]
-        assert profs, "dump carries no profiler snapshot"
-        kernels = profs[0]["profile"]["kernels"]
-        assert kernels and kernels[0]["fingerprint"]
-        assert kernels[0]["device_us"] >= 0
-        assert kernels[0]["calls"] >= 1
-    finally:
-        set_flight_recorder(None)
-
-
-# ---------------------------------------------------------------------------
 # scrape-side analysis (scripts/scrape_metrics.py)
 # ---------------------------------------------------------------------------
 
@@ -484,24 +268,3 @@ def test_quantile_from_buckets_shared_helper():
     assert quantile_from_buckets(bounds, [0, 10, 0, 10], 0.25) <= 0.01
     assert quantile_from_buckets(bounds, [0, 10, 0, 10], 0.99) == 0.1
     assert quantile_from_buckets(bounds, [0, 0, 0, 0], 0.5) == 0.0
-
-
-def test_profile_view_renders():
-    import importlib
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    pv = importlib.import_module("profile_view")
-    doc = {"processId": "p", "cluster": True, "workersPulled": 2,
-           "kernels": [
-               {"fingerprint": "a" * 64, "label": "Output > Scan",
-                "tables": "tpch.nation", "calls": 3,
-                "device_us": 900_000, "max_device_us": 500_000,
-                "rows_in": 75, "bytes_in": 4096, "rows_out": 5,
-                "bytes_out": 64, "retraces": 1,
-                "footprint_bytes": 1 << 20}]}
-    text = pv.render(doc, top=5)
-    assert "aaaaaaaaaaaa" in text
-    assert "100.0%" in text
-    assert "cluster scope, 2 workers pulled" in text

@@ -4,8 +4,8 @@ The contracts under test: the median+MAD comparator is deterministic
 and warms up before it alarms; every terminal statement lands one
 record in the archive (fingerprint, QueryStats rollup, trace id) and
 on the JSONL ring with rotation + retention; GET /v1/history serves it
-on both tiers (cluster-merged on the statement tier, processId-deduped
-like /v1/profile) and SELECT * FROM system.query_history serves it as
+on both tiers (cluster-merged on the statement tier,
+processId-deduped) and SELECT * FROM system.query_history serves it as
 SQL; the end-to-end sentinel catches an injected exchange delay on a
 warmed baseline (regression counter + flight event + auto dump) and
 stays SILENT on the clean replay; and the offline gate
@@ -151,12 +151,37 @@ def test_record_of_real_query_rollup(recorder):
     assert st["staged_bytes"] == qs.stages["staging"].bytes > 0
     assert st["output_rows"] == 1
     assert st["peak_memory_bytes"] == qs.peak_memory_bytes
-    # the profiler attributed this query id's kernels (default-on)
-    assert rec["kernels"], "expected plan-cache fingerprint attribution"
-    assert rec["topKernels"] and \
-        rec["topKernels"][0]["fingerprint"] == rec["kernels"][0]
     # kernel-mode envs ride the record (the A/B provenance)
     assert "PRESTO_TPU_NARROW" in rec["kernelModeEnvs"]
+
+
+def _record_of_run(query_id, text, sf):
+    from presto_tpu.sql import sql as run_sql
+    res = run_sql(text, sf=sf, query_id=query_id)
+    qs = res.query_stats
+    return QueryHistoryArchive.record_of(
+        query_id, "FINISHED", "alice", text, qs.wall_us / 1000.0,
+        "trace-" + query_id, query_stats=qs, session={"sf": sf})
+
+
+def test_baseline_key_is_the_statement_text_salted_with_sf():
+    """Two runs of one text share a baseline key however the text is
+    spaced or cased; the same text at another scale does not."""
+    text = "SELECT count(*) FROM lineitem WHERE quantity > 11"
+    a = _record_of_run("qh-key-1", text, 0.01)
+    b = _record_of_run("qh-key-2", text.lower().replace(" ", "  "), 0.01)
+    c = _record_of_run("qh-key-3", text, 0.02)
+    assert a["fingerprint"] == b["fingerprint"]
+    assert a["fingerprint"] != c["fingerprint"]
+
+
+def test_record_carries_no_kernel_rows():
+    """The record's account of time is its QueryStats rollup: no
+    per-kernel rows, no fingerprints of a registry beside it."""
+    rec = _record_of_run("qh-nokern-1",
+                         "SELECT count(*) FROM lineitem", 0.01)
+    assert "kernels" not in rec and "topKernels" not in rec
+    assert rec["stats"]["execute_us"] > 0
 
 
 def test_ring_rotation_retention_and_reload(tmp_path, recorder):
@@ -382,11 +407,15 @@ def test_e2e_sentinel_catches_exchange_delay_then_stays_silent(
     q = ("SELECT custkey, count(*) AS c FROM orders "
          "GROUP BY custkey")
     sizes = archive.size()
-    for i in range(3):  # min_samples=3 warmup (fixture baseline)
+    # five warm-ups where min_samples is 3: the median then holds
+    # against the cold first run and one more slow one (six workers
+    # share this machine: with three samples, two slow ones widened the
+    # band past the injected stall)
+    for i in range(5):
         execute(srv.url, q)
         _wait_for(lambda: archive.size() >= sizes + i + 1)
     key = archive.records()[0]["fingerprint"]
-    assert len(archive.baseline.samples_of(key)["wall_us"]) == 3
+    assert len(archive.baseline.samples_of(key)["wall_us"]) == 5
     before = dict(perf_regression_totals())
 
     # one 2500ms stall per exchange pull: far outside any warm band

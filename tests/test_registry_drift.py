@@ -160,18 +160,116 @@ def test_buffer_donation_property_is_registered_and_keyed():
     assert ("PRESTO_TPU_DONATION", "0") in KERNEL_MODE_ENVS
 
 
-def test_timeline_property_is_registered_and_keyed():
-    """The timeline knob rides both registries: session property (on
-    by default -- the occupancy baseline must exist before the async
-    -pipeline PR) and kernel-mode env."""
-    prop = SESSION_PROPERTIES.properties["timeline"]
-    assert prop.default is True
-    assert ("PRESTO_TPU_TIMELINE", "1") in KERNEL_MODE_ENVS
-
-
 @pytest.mark.parametrize("name", sorted(_UNKEYED_ENVS))
 def test_unkeyed_allowlist_entries_are_still_read(name):
     """Allowlist hygiene: each unkeyed env is still read somewhere;
     a vestigial entry must be dropped, not carried."""
     _, envs = _scan_all()
     assert name in envs, f"{name} allowlisted but no longer read"
+
+
+# -- one ledger of host time --------------------------------------------
+#
+# The span seam (exec/stats.py) is the only record of where a
+# statement's host time went. These keep the surfaces of the two
+# ledgers that stood beside it (exec/timeline.py, exec/profiler.py)
+# from growing back: routes, system tables, knobs, stats keys, metric
+# families.
+
+_GONE_ROUTES = ("/v1/timeline", "/v1/profile")
+_GONE_TABLES = ("occupancy", "kernels")
+_GONE_PROPERTIES = ("timeline", "continuous_profiling")
+_GONE_ENVS = ("PRESTO_TPU_TIMELINE", "PRESTO_TPU_PROFILE")
+_GONE_FAMILIES = ("presto_tpu_timeline_", "presto_tpu_overlap_fraction",
+                  "presto_tpu_device_idle_us")
+
+
+@pytest.fixture(scope="module")
+def both_tiers():
+    from presto_tpu.server import TpuWorkerServer
+    from presto_tpu.server.statement import StatementServer
+    worker = TpuWorkerServer(sf=0.01).start()
+    try:
+        with StatementServer(sf=0.01) as srv:
+            yield {"statement": srv.url, "worker": worker.url}
+    finally:
+        worker.stop()
+
+
+@pytest.mark.parametrize("route", _GONE_ROUTES)
+@pytest.mark.parametrize("tier", ["statement", "worker"])
+def test_one_ledger_route_is_gone(both_tiers, tier, route):
+    import urllib.error
+    import urllib.request
+    with pytest.raises(urllib.error.HTTPError) as e:
+        urllib.request.urlopen(both_tiers[tier] + route)
+    assert e.value.code == 404
+
+
+@pytest.mark.parametrize("table", _GONE_TABLES)
+def test_one_ledger_system_table_does_not_resolve(table):
+    from presto_tpu.connectors import system
+    from presto_tpu.sql import sql
+    assert table not in system.SCHEMA
+    with pytest.raises(KeyError):
+        sql(f"SELECT * FROM system.{table}", sf=0.01)
+
+
+@pytest.mark.parametrize("name", _GONE_PROPERTIES)
+def test_one_ledger_session_property_is_unknown(name):
+    from presto_tpu.utils.config import Session
+    assert name not in SESSION_PROPERTIES.properties
+    with pytest.raises(KeyError, match="unknown config property"):
+        Session({name: False})
+
+
+@pytest.mark.parametrize("env", _GONE_ENVS)
+def test_one_ledger_env_keys_no_plan(env, monkeypatch):
+    """Neither env is a kernel mode: setting it moves no cache key, and
+    the next identical statement is a plan-cache hit."""
+    from presto_tpu.exec.plan_cache import _kernel_mode
+    from presto_tpu.sql import sql
+    assert env not in dict(KERNEL_MODE_ENVS)
+    text = "SELECT count(*) FROM nation WHERE regionkey < 3"
+    monkeypatch.delenv(env, raising=False)
+    mode = _kernel_mode()
+    sql(text, sf=0.01)
+    monkeypatch.setenv(env, "0")
+    assert _kernel_mode() == mode
+    counters = sql(text, sf=0.01).query_stats.counters
+    assert counters.get("plan_cache_hits", 0) >= 1
+    assert counters.get("plan_cache_misses", 0) == 0
+
+
+def _parent_era_document():
+    """`stats.queryStats` as a worker of the parent commit ships it:
+    today's document plus a populated `timeline` slice."""
+    from presto_tpu.sql import sql
+    doc = sql("SELECT count(*) FROM region", sf=0.01).query_stats.to_json()
+    return doc, {**doc, "timeline": {
+        "intervals": [["host", "connector_read", 0, 10, 90, 640]],
+        "totals": {"connector_read": [1, 80, 640]}, "dropped": 0}}
+
+
+@pytest.mark.parametrize("direction", ["to_json", "from_json"])
+def test_one_ledger_query_stats_has_no_timeline(direction):
+    from presto_tpu.exec.stats import QueryStats
+    doc, older = _parent_era_document()
+    if direction == "to_json":
+        assert "timeline" not in doc
+        assert {"stages", "datapath", "counters", "operators",
+                "accuracy"} <= set(doc)
+    else:
+        # an older worker's key is ignored, the rest round-trips
+        assert QueryStats.from_json(older).to_json() == doc
+
+
+@pytest.mark.parametrize("tier", ["statement", "worker"])
+def test_one_ledger_metrics_export_no_second_clock(both_tiers, tier):
+    import urllib.request
+    with urllib.request.urlopen(both_tiers[tier] + "/v1/metrics") as r:
+        text = r.read().decode()
+    assert "presto_tpu_stage_seconds" in text
+    families = {line.split()[2] for line in text.splitlines()
+                if line.startswith("# TYPE")}
+    assert not [f for f in families if f.startswith(_GONE_FAMILIES)]
