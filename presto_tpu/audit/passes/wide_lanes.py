@@ -50,7 +50,8 @@ WIDE_OK_SITES: Dict[str, Set[str]] = {
         "_seg_extreme_at", "group_by", "merge_partials",
     },
     "keys.py": {"_fixed_words", "key_words", "_string_words"},
-    "join.py": {"_pack_ranks", "hash_join", "semi_join_mask"},
+    "join.py": {"_pack_ranks", "hash_join", "_probe_slots",
+                "semi_join_mask"},
     "window.py": {"window", "_seg_search", "_range_extreme"},
     # decimal comparison/arithmetic widens narrowed lanes to the exact
     # scaled-int64 (or int128 limb) domain before comparing -- the

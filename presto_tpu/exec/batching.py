@@ -846,7 +846,7 @@ class BatchingExecutor:
                 jax.block_until_ready(out)
             finally:
                 prog.release(state="FINISHED")
-            flags, steps = split_flags(np.asarray(overflow))
+            flags, steps, compacted = split_flags(np.asarray(overflow))
             if int(flags.max()) != 0:
                 # a member overflowed a static bucket: the serial
                 # ladder owns adaptive reruns; collapse the whole batch
@@ -863,7 +863,8 @@ class BatchingExecutor:
             self._serial_fallback(entries, sf, "error")
             return
         device_us = int((time.time() - t0) * 1e6)
-        self._fan_out(out, plan, entries, device_us, steps, expand_steps)
+        self._fan_out(out, plan, entries, device_us, steps, expand_steps,
+                      compacted)
         self._account(entries)
 
     def _stage_inputs(self, key, plan, sf: float) -> list:
@@ -928,7 +929,8 @@ class BatchingExecutor:
         return tuple(out)
 
     def _fan_out(self, out, plan, entries: List[_Pending],
-                 device_us: int, search_steps, expand_steps) -> None:
+                 device_us: int, search_steps, expand_steps,
+                 compacted) -> None:
         """Slice the batched output back into per-member QueryResults
         (member i owns batch row i -- ordering is positional by
         construction). ONE host conversion covers the whole batch;
@@ -956,6 +958,7 @@ class BatchingExecutor:
                 qs.counters["join_search_steps"] = int(search_steps[i])
             if expand_steps is not None:
                 qs.counters["join_expand_steps"] = expand_steps
+                qs.counters["join_probe_compacted"] = int(compacted[i])
             res.query_stats = qs
             res.stats = {"batch": {"size": float(nbatch),
                                    "device_us": float(device_us)}}

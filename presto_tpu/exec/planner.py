@@ -44,15 +44,20 @@ from ..plan import nodes as N
 __all__ = ["compile_plan", "CompiledPlan", "split_flags"]
 
 # the status word a program returns beside its batch: overflow flags in
-# the low bits, the joins' binary-search trips from this bit up
+# the low bits, the joins' binary-search trips in STEP_BITS from
+# FLAG_BITS up (held to what the field takes: 127 lookups of 32 trips),
+# the joins whose probe was compacted above them
 FLAG_BITS = 8
+STEP_BITS = 12
 
 
 def split_flags(word):
     """The status word, taken apart on the host: (overflow flags: bit0
-    hard, bit1 exchange slots; join_search_steps). `word` is an int, or
-    the array of them a vmapped program returns."""
-    return word & ((1 << FLAG_BITS) - 1), word >> FLAG_BITS
+    hard, bit1 exchange slots; join_search_steps; join_probe_compacted).
+    `word` is an int, or the array of them a vmapped program returns."""
+    return (word & ((1 << FLAG_BITS) - 1),
+            (word >> FLAG_BITS) & ((1 << STEP_BITS) - 1),
+            word >> (FLAG_BITS + STEP_BITS))
 
 
 @dataclasses.dataclass
@@ -232,6 +237,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
             _note_overflow(r.overflow)
             search_steps.append(r.search_steps)
             expand_steps.append(r.expand_steps)
+            compacted.append(r.compacted)
             return r.batch
         if isinstance(node, N.SemiJoinNode):
             src = lower(node.source, inputs)
@@ -401,6 +407,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
     overflow_box: List = []
     search_steps: List = []  # one trip count per join lookup lowered
     expand_steps: List[int] = []  # one per join expansion lowered
+    compacted: List = []  # one 0/1 per join: its probe was compacted
     _lower_memo: Dict[int, Batch] = {}
 
     def _note_overflow(flag, scalable: bool = False):
@@ -413,6 +420,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
         overflow_box.clear()
         search_steps.clear()
         expand_steps.clear()
+        compacted.clear()
         _lower_memo.clear()
         inputs = {n.id: b for n, b in zip(scans, scan_batches)}
         out = lower(root, inputs)
@@ -426,15 +434,21 @@ def compile_plan(root: N.PlanNode, mesh=None,
             else:
                 hard = hard | f
         steps = sum(search_steps, jnp.zeros((), dtype=jnp.int32))
+        took = sum(compacted, jnp.zeros((), dtype=jnp.int32))
         if dist:
             hard = jax.lax.psum(hard.astype(jnp.int32), axis) > 0
             slots = jax.lax.psum(slots.astype(jnp.int32), axis) > 0
             steps = jax.lax.pmax(steps, axis)  # the deepest shard's
+            took = jax.lax.pmax(took, axis)  # each shard chooses for itself
         # one word, one host read: bit0 = hard (non-scalable), bit1 =
-        # exchange slots, bits 8 and up = the joins' binary-search trips
-        # (the counter join_search_steps; `split_flags` takes it apart)
+        # exchange slots, then the joins' binary-search trips and the
+        # joins that compacted their probe (the counters
+        # join_search_steps, join_probe_compacted; `split_flags` takes
+        # it apart)
+        steps = jnp.minimum(steps, (1 << STEP_BITS) - 1)
         return out, (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
-                     + (steps << FLAG_BITS))
+                     + (steps << FLAG_BITS)
+                     + (took << (FLAG_BITS + STEP_BITS)))
 
     plan = CompiledPlan(run, scans, root.output_types(), dist, root)
     if dist:

@@ -820,12 +820,15 @@ def _read_status(word, expand_steps: Optional[int]) -> int:
     """The one host read of the word a program returns beside its batch:
     its overflow flags come back, the trips its joins' lookups took go
     to the statement's counters, and with them `expand_steps`, the
-    trips of its joins' expansions (`CompiledPlan.expand_steps_of`)."""
-    flags, steps = split_flags(int(np.asarray(word)))
+    trips of its joins' expansions (`CompiledPlan.expand_steps_of`:
+    None for a program without a join), and how many of its joins
+    compacted their probe."""
+    flags, steps, compacted = split_flags(int(np.asarray(word)))
     if steps:
         note("join_search_steps", steps)
     if expand_steps is not None:  # 0 too: a join whose table is its
         note("join_expand_steps", expand_steps)  # own directory
+        note("join_probe_compacted", compacted)
     return flags
 
 

@@ -56,8 +56,11 @@ WIDE_OK_FUNCS: Dict[str, Set[str]] = {
     "keys.py": {"_fixed_words", "key_words", "_string_words"},
     # join row-id packing: build-side positions and packed rank words
     # are int64 by contract (row ids can exceed 2^31 at SF1k; the
-    # packed (rank, pos) word needs the full 64 bits)
-    "join.py": {"_pack_ranks", "hash_join", "semi_join_mask"},
+    # packed (rank, pos) word needs the full 64 bits); the running
+    # sum of what the probe rows emit and its total are int64
+    # (`_probe_slots`, hash_join's probe side)
+    "join.py": {"_pack_ranks", "hash_join", "_probe_slots",
+                "semi_join_mask"},
     "sort.py": set(),
     # window positions/ranks/frame bounds are int64 row ids and exact
     # 64-bit accumulators (rank arithmetic, padded-cumsum frame totals)

@@ -31,6 +31,23 @@ out_capacity updates and one cumsum) to give each slot its block, and
 log2(block) gathers of one 32-bit lane inside the block. What is gathered per slot afterwards
 (off, emit, cnt, start) is int32 too.
 
+What a probe row costs: nothing but a place in one prefix sum, where
+the rows that can emit a slot (usable key; under LEFT / FULL, active)
+are few. A filter upstream leaves most of a fact table inactive (TPC-H
+Q14: 1.2% of lineitem pass l_shipdate), and a row that cannot emit
+needs no lookup. Where the probe is at least four times as long as
+the output (`_compact_capacity`, shapes alone) the program holds two
+forms of the probe side (`_probe_side`) in a `cond` on the count of
+emitting rows, which the device sees and the host never reads: where
+they fit the output capacity, `_slot_rows` over the mask's prefix sum
+names the k-th emitting row (`_compact_probe`), its key columns are
+gathered, and lookup, running sum and expansion run over out_capacity
+rows (`_probe_slots`, the same function at another length; the
+expansion's table is then no longer than its output: blocks of one
+row); where they do not, every probe row is looked up as above. Slots
+fill in probe row order either way, so the two answer alike slot for
+slot. A probe that does not fit is no overflow: nothing reruns.
+
 Everything is a fixed-shape gather -- the dynamic result size only
 shows up in the output's active mask and an `overflow` flag when the
 out_capacity bucket is too small (exec layer re-runs bigger, the
@@ -65,12 +82,13 @@ class JoinResult:
     num_rows: jnp.ndarray
     overflow: jnp.ndarray
     search_steps: jnp.ndarray  # binary-search trips the lookups took
+    compacted: jnp.ndarray  # 1 where the probe took the compacted form
     expand_steps: int = 0  # gather trips a slot of `_slot_rows` took
 
 
 jax.tree_util.register_dataclass(JoinResult,
                                  data_fields=["batch", "num_rows", "overflow",
-                                              "search_steps"],
+                                              "search_steps", "compacted"],
                                  meta_fields=["expand_steps"])
 
 
@@ -298,6 +316,28 @@ def _slot_block(n: int, slots: int) -> int:
     return 1 << ((n - 1) // slots).bit_length()
 
 
+def _slot_trips(n: int, slots: int) -> int:
+    """Gather trips a slot of `_slot_rows` takes over a table of `n`
+    rows: log2 of the block, or a search of the whole table where it is
+    one block; a constant of the shapes."""
+    if n == 0 or slots == 0:
+        return 0
+    block = _slot_block(n, slots)
+    return n.bit_length() if n <= block else block.bit_length() - 1
+
+
+def _running_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of int32 `x`, along rows of 1,024 and then
+    over the rows' totals: XLA:TPU compiles that in a second at any
+    length, the flat cumsum in 18-22 s at a million elements (PERF.md,
+    PR 29)."""
+    n = x.shape[0]
+    rows = jnp.pad(x, (0, -n % 1024)).reshape(-1, 1024)
+    sums = jnp.cumsum(rows, axis=1, dtype=jnp.int32)
+    above = jnp.cumsum(sums[:, -1], dtype=jnp.int32) - sums[:, -1]
+    return (sums + above[:, None]).reshape(-1)[:n]
+
+
 @jax.named_scope("_slot_rows")
 def _slot_rows(off: jnp.ndarray, slots: int
                ) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
@@ -316,10 +356,8 @@ def _slot_rows(off: jnp.ndarray, slots: int
        a slot and one behind them (n / B sorted updates) and the counts
        summed along the slots: the
        count of blocks that start at or before k, less one, is the last
-       block whose first offset is <= k, and it holds the answer. The
-       sum runs along rows of 1,024 bins and then over the rows' totals:
-       XLA:TPU compiles that in a second at any length, the flat cumsum
-       in 18-22 s at a million bins (PERF.md, PR 29).
+       block whose first offset is <= k, and it holds the answer
+       (`_running_sum`).
     3. log2 B trips inside the block, from its first row (known <= k):
        a trip moves on by half the rows left where the row there is <=
        k too, at one gather of a 32-bit lane.
@@ -332,18 +370,15 @@ def _slot_rows(off: jnp.ndarray, slots: int
         return jnp.full(slots, -1, dtype=jnp.int32), k, 0
     off32 = jnp.clip(off, 0, slots).astype(jnp.int32)
     block = _slot_block(n, slots)
-    if n <= block:
-        trips = n.bit_length()  # n + 1 answers, -1 among them
+    trips = _slot_trips(n, slots)
+    if n <= block:  # n + 1 answers, -1 among them
         # -1 (the clip's floor is 0), and as varying as the carry under
         # shard_map
         row = jnp.broadcast_to(jnp.minimum(off32[0], 0) - 1, (slots,))
     else:
-        trips = block.bit_length() - 1
         hist = jnp.zeros((slots // 1024 + 1) * 1024, dtype=jnp.int32).at[
             off32[::block]].add(1, indices_are_sorted=True)
-        starts = jnp.cumsum(hist.reshape(-1, 1024), axis=1, dtype=jnp.int32)
-        above = jnp.cumsum(starts[:, -1], dtype=jnp.int32) - starts[:, -1]
-        starts = (starts + above[:, None]).reshape(-1)[:slots]
+        starts = _running_sum(hist)[:slots]
         # no block starts at or before k: -1, and no row after it is <= k
         row = jnp.where(starts > 0, (starts - 1) * block, -1)
 
@@ -354,6 +389,110 @@ def _slot_rows(off: jnp.ndarray, slots: int
 
     row = jax.lax.fori_loop(0, trips, advance, row)
     return row, k - off32[jnp.maximum(row, 0)], trips
+
+
+def _compact_capacity(npr: int, out_capacity: int) -> int:
+    """Rows the compacted probe holds: the join's output capacity, where
+    the probe is at least four times as long; else 0, and the join
+    compiles no second form. A probe row that can emit emits at least
+    one slot, so emitting rows that pass the capacity would overflow it
+    too: the capacity the ladder learned for the output fits the
+    probe."""
+    return out_capacity if 0 < 4 * out_capacity <= npr else 0
+
+
+@jax.named_scope("_compact_probe")
+def _compact_probe(emits: jnp.ndarray, capacity: int) -> jnp.ndarray:
+    """The rows of the probe that can emit a slot, in row order: the
+    row of the k-th True of `emits` for k < capacity (int32; the last
+    row where there is no k-th). The mask's exclusive prefix sum is an
+    offset table of rows that emit 0 or 1 slots, so `_slot_rows` over
+    it is the map."""
+    e = emits.astype(jnp.int32)
+    crow, _, _ = _slot_rows(_running_sum(e) - e, capacity)
+    return jnp.clip(crow, 0, emits.shape[0] - 1)
+
+
+def _probe_slots(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
+                 p_words: Sequence[jnp.ndarray], p_usable: jnp.ndarray,
+                 p_active: jnp.ndarray, outer_probe: bool, out_capacity: int):
+    """The probe side of the join over the rows given (the whole probe,
+    or its compacted rows): look every row up, sum what each emits, and
+    map the output slots back. Returns, per slot, the row that emits it
+    (int32), whether the slot is live, whether it carries a build row,
+    and that row's place in the sorted build side; then the slots the
+    rows emit in all (int64) and the lookups' search trips."""
+    n = p_usable.shape[0]
+    nb = b_usable.shape[0]
+    # match ranges inside the usable (sorted-front) region
+    start, end, steps = _lookup(sb_words, b_usable, p_words)
+
+    # per probe row in int32 (they are gathered per slot), and so their
+    # running sum: exact where the total fits 31 bits, and a total that
+    # does not overflows every capacity (what the slots then hold is
+    # discarded with the dispatch). The total itself is exact, in int64.
+    # (A 64-bit scan is no option inside a `cond`: XLA:TPU fails to
+    # place some, by their length, "allocating on stack"; PERF.md, PR 31.)
+    cnt = jnp.where(p_usable, end - start, 0)
+    if outer_probe:
+        emit = jnp.where(p_active, jnp.maximum(cnt, 1), 0)
+    else:
+        emit = cnt
+    off = _running_sum(emit) - emit  # exclusive
+    total = jnp.sum(emit, dtype=jnp.int64)
+
+    k = jnp.arange(out_capacity, dtype=jnp.int32)
+    # map output slot -> probe row, and its place j in that row's run
+    prow, j, _ = _slot_rows(off, out_capacity)
+    prow = jnp.clip(prow, 0, n - 1)
+    valid = (k < total) & (j < emit[prow])
+    matched = j < cnt[prow]
+    srow = jnp.clip(start[prow] + j, 0, nb - 1)
+    return prow, valid, matched, srow, total, steps
+
+
+def _probe_side(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
+                p_keys: Sequence[Block], p_active: jnp.ndarray,
+                outer_probe: bool, out_capacity: int, compact_capacity: int):
+    """`_probe_slots` over the probe rows that can emit a slot, where
+    there are at most `compact_capacity` of them (`_compact_probe`; the
+    key columns gathered at those rows), and over every probe row where
+    there are more, or the capacity is 0. Both forms are in the one
+    program and the device chooses by the count it sees: no host read,
+    no rerun. Slots are filled in probe row order in both, so they
+    answer alike, slot for slot; the last value returned says which
+    ran. The forms take the key columns, not their words: what a
+    `cond` takes in is held in memory whole, and a key's words are
+    twice its column."""
+    npr = p_active.shape[0]
+
+    def slots(keys, active):
+        words, usable = _combined_key(keys, active)
+        return _probe_slots(sb_words, b_usable, words, usable, active,
+                            outer_probe, out_capacity)
+
+    def full():
+        return slots(p_keys, p_active)
+
+    if not compact_capacity:
+        return (*full(), jnp.zeros((), dtype=bool))
+    # an inner join's row emits what it matches; an outer probe's active
+    # row emits at least its one NULL-extended slot, NULL key or not (its
+    # lookup is masked in either form)
+    emits = p_active if outer_probe else _combined_key(p_keys, p_active)[1]
+    n_emit = jnp.sum(emits, dtype=jnp.int32)
+
+    def compacted():
+        crow = _compact_probe(emits, compact_capacity)
+        live = jnp.arange(compact_capacity, dtype=jnp.int32) < n_emit
+        prow, valid, matched, srow, total, steps = slots(
+            [_gather(c, crow) for c in p_keys], live)
+        # a slot past the total names the last probe row, as in `full`
+        return (jnp.where(valid, crow[prow], npr - 1), valid, matched, srow,
+                total, steps)
+
+    took = n_emit <= compact_capacity
+    return (*jax.lax.cond(took, compacted, full), took)
 
 
 @jax.named_scope("hash_join")
@@ -375,7 +514,13 @@ def hash_join(probe: Batch, build: Batch,
     matched region through the same prefix-sum expansion, with NULL
     probe columns. Under a mesh this requires PARTITIONED distribution
     (each build row must live on exactly one worker; plan.distribute
-    forces it)."""
+    forces it).
+
+    The probe side is `_probe_side`: four 32-bit gathers a probe row
+    where every row is looked up, none for a row that cannot emit where
+    those that can fit `out_capacity` and the probe is four times as
+    long (the result's `compacted` says which ran: the counter
+    join_probe_compacted); `expand_steps` is the same in both forms."""
     assert join_type in ("inner", "left", "right", "full"), join_type
     if build_output_channels is None:
         build_output_channels = range(build.num_columns)
@@ -392,18 +537,11 @@ def hash_join(probe: Batch, build: Batch,
     # sort build by key words (unusable rows masked to MAX, sorted last)
     sb_words, b_perm = _sort_build(b_words, b_usable,
                                    jnp.arange(nb, dtype=jnp.int32))
-    # match ranges inside the usable (sorted-front) region
-    start, end, steps = _lookup(sb_words, b_usable, p_words)
-
-    # per probe row in int32 (they are gathered per slot); the running
-    # sum and the totals in int64
-    cnt = jnp.where(p_usable, end - start, 0)
-    if join_type in ("left", "full"):
-        emit = jnp.where(probe.active, jnp.maximum(cnt, 1), 0)
-    else:
-        emit = cnt
-    off = jnp.cumsum(emit, dtype=jnp.int64) - emit  # exclusive
-    total = off[-1] + emit[-1]
+    prow, valid, matched, srow, total, steps, took = _probe_side(
+        sb_words, b_usable, p_keys, probe.active,
+        join_type in ("left", "full"), out_capacity,
+        _compact_capacity(npr, out_capacity))
+    expand_steps = _slot_trips(npr, out_capacity)
 
     outer_build = join_type in ("right", "full")
     if outer_build:
@@ -422,12 +560,6 @@ def hash_join(probe: Batch, build: Batch,
     overflow = total2 > out_capacity
 
     k = jnp.arange(out_capacity, dtype=jnp.int32)
-    # map output slot -> probe row, and its place j in that row's run
-    prow, j, expand_steps = _slot_rows(off, out_capacity)
-    prow = jnp.clip(prow, 0, npr - 1)
-    valid = (k < total) & (j < emit[prow])
-    matched = j < cnt[prow]
-    srow = jnp.clip(start[prow] + j, 0, nb - 1)
     brow = b_perm[srow]  # back to original build row order
 
     build_valid = valid & matched
@@ -450,7 +582,8 @@ def hash_join(probe: Batch, build: Batch,
         g = _gather(c, brow, build_valid)
         out_cols.append(g)
     out = Batch(tuple(out_cols), all_valid)
-    return JoinResult(out, total2, overflow, steps, expand_steps)
+    return JoinResult(out, total2, overflow, steps, took.astype(jnp.int32),
+                      expand_steps)
 
 
 from ..block import gather_block as _gather  # shared row gather

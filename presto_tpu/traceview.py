@@ -233,8 +233,9 @@ TOP = 24                  # rows of a printed ranking
 # (tests/test_traceview_xplane.py holds this list to the source)
 OPS_SCOPES = frozenset([
     "lex_sort", "hash_join", "_sort_build", "_pack_ranks",
-    "_match_ranges", "_slot_rows", "semi_join_mask", "_group_ids_hash",
-    "_group_ids_sort", "_group_by_sorted", "top_n", "limb_partial_sums"])
+    "_match_ranges", "_slot_rows", "_compact_probe", "semi_join_mask",
+    "_group_ids_hash", "_group_ids_sort", "_group_by_sorted", "top_n",
+    "limb_partial_sums"])
 _NODE = re.compile(r"[A-Za-z]+Node\.\d+$")
 
 

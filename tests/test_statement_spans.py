@@ -480,6 +480,96 @@ def test_kernel_hop_is_device_wait(cells, name):
     assert kernel["wall_us"] <= execute_us - compile_us + 1
 
 
+# a probe of 60,000 rows is four times as long as 4,096 slots; at the
+# default 65,536 no join of this scale has a second form
+TIGHT = {"join_capacity": "4096"}
+
+
+@pytest.mark.parametrize("name,session,compacted", [
+    # 1.2% of lineitem pass l_shipdate: some 750 rows fit 4,096
+    ("q14-mem", TIGHT, 1),
+    # at this scale the dynamic filter prunes Q3's lineitem to 29,104
+    # rows before it is staged, and what passes l_shipdate of those fits
+    ("q3-mem", TIGHT, 1),
+    ("q14-mem", None, 0), ("q3-mem", None, 0),
+    ("q6-mem", TIGHT, None), ("q6-gen", None, None)],
+    ids=["q14-tight", "q3-tight-pruned", "q14", "q3", "q6-tight", "q6-gen"])
+def test_join_probe_compacted_rides_the_status_word(cells, name, session,
+                                                    compacted):
+    """How many of a statement's joins looked up only the probe rows
+    that can emit (`ops/join._probe_side`: the device's choice, by its
+    own count) leaves the device above the search trips in the word the
+    program already returns: in the counters of every statement whose
+    program holds a join, 0 too and on a plan-cache hit too; a
+    statement without a join has no such counter."""
+    with StatementServer(sf=0.01) as srv:
+        first, again = (
+            execute(srv.url, _cell_text(name), session=session)
+            .stats["queryStats"] for _ in range(2))
+    for stats in (first, again):
+        assert stats["counters"].get("join_probe_compacted") == compacted
+        assert stats["stages"]["device_wait"]["invocations"] == \
+            stats["stages"]["dispatch"]["invocations"]  # one host read each
+    assert again["counters"]["plan_cache_hits"] >= 1
+    if session is None and compacted is not None:
+        assert cells[name]["stats"]["counters"]["join_probe_compacted"] == 0
+
+
+def test_a_probe_that_does_not_fit_is_looked_up_whole(cells):
+    """Q3 as a cell sends it, its lineitem unpruned (the dynamic filter
+    is off over 1M build rows; here by the session): 54% of 60,000 rows
+    pass l_shipdate, 32,000 do not fit 4,096, and its second join's
+    probe is as long as its output. The device takes the whole-probe
+    form, nothing overflows, nothing reruns: 0."""
+    res = sql(_cell_text("q3-mem"), sf=0.01, join_capacity=4096,
+              session={"dynamic_filtering": False})
+    assert res.query_stats.counters["join_probe_compacted"] == 0
+    assert res.query_stats.counters["capacity_reruns"] == 0
+    assert res.query_stats.stages["dispatch"].invocations == 1
+    assert res.rows() == sql(_cell_text("q3-mem"), sf=0.01).rows()
+
+
+def test_a_ladder_rerun_sums_the_compacted_probes():
+    """A probe that fits the compacted capacity is no overflow, and an
+    output that overflows is no reason to leave the form: 779 of 60,000
+    lineitem rows pass the filter and fan out to four slots each, so
+    the dispatch at 1,024 slots compacts and overflows, the rerun at
+    4,096 compacts and fits, and the counter adds over both; the
+    answer is the roomy run's, which has no second form."""
+    text = ("SELECT count(*), sum(l2.quantity) FROM lineitem l "
+            "JOIN lineitem l2 ON l.orderkey = l2.orderkey "
+            "WHERE l.shipdate >= date '1995-09-01' "
+            "AND l.shipdate < date '1995-10-01'")
+    roomy = sql(text, sf=0.01)
+    tight = sql(text, sf=0.01, join_capacity=1024,
+                session={"dynamic_filtering": False})
+    assert tight.rows() == roomy.rows()
+    assert roomy.query_stats.stages["dispatch"].invocations == 1
+    assert roomy.query_stats.counters["join_probe_compacted"] == 0
+    assert tight.query_stats.stages["dispatch"].invocations == 2
+    assert tight.stats["capacity_reruns"]["count"] == 1
+    assert tight.query_stats.counters["join_probe_compacted"] == 1 + 1
+    # blocks of 64 probe rows at 1,024 slots, of 16 at 4,096: the trips
+    # of the compaction in the one form, of the expansion in the other
+    assert tight.query_stats.counters["join_expand_steps"] == 6 + 4
+
+
+def test_the_status_word_splits_three_ways():
+    """Flags in bits 0-7, the search trips in the twelve above them
+    (held to the field), the compacted probes above those; an array of
+    words (a vmapped program's) splits lane by lane."""
+    import numpy as np
+    from presto_tpu.exec.planner import FLAG_BITS, STEP_BITS, split_flags
+    word = 1 + (37 << FLAG_BITS) + (2 << FLAG_BITS + STEP_BITS)
+    assert split_flags(word) == (1, 37, 2)
+    assert split_flags(2) == (2, 0, 0)
+    flags, steps, compacted = split_flags(
+        np.asarray([word, 3 << FLAG_BITS, 1 << FLAG_BITS + STEP_BITS],
+                   dtype=np.int32))
+    assert (flags.tolist(), steps.tolist(), compacted.tolist()) == \
+        ([1, 0, 0], [37, 3, 0], [2, 0, 1])
+
+
 # -- a failed statement -------------------------------------------------
 
 

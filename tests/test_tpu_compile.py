@@ -92,6 +92,36 @@ def test_lex_sort_compiles_as_one_single_key_sort(one_chip):
     assert "while" in text  # the scan over key words
 
 
+@pytest.mark.parametrize("npr,slots,nb", [
+    (60_000_000, 4_194_304, 15_000_000),  # Q3's lineitem x orders
+    (60_000_000, 1_048_576, 2_000_000),   # Q14's lineitem x part
+], ids=["sf10-q3-join7", "sf10-q14-join4"])
+def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
+                                                            slots, nb):
+    """`hash_join`'s probe side holds its two forms in a `cond`, and
+    XLA:TPU fails to place some 64-bit scans inside a `cond`'s branch
+    ("vmem while allocating on stack", by the scan's length: a flat
+    int64 cumsum over 4,194,304 rows failed here, over 1,048,576 it did
+    not), which no CPU run shows: the forms keep their running sums in
+    int32, and this holds them to it at the benchmark's SF10 shapes."""
+    from presto_tpu import types as T
+    from presto_tpu.block import Column
+    from presto_tpu.ops import join
+    capacity = join._compact_capacity(npr, slots)
+    assert capacity == slots
+
+    def fn(sorted_keys, b_usable, p_keys, p_active):
+        key = Column(p_keys, jnp.zeros(p_keys.shape, dtype=bool), T.INTEGER)
+        return join._probe_side([sorted_keys], b_usable, [key], p_active,
+                                False, slots, capacity)
+
+    text = jax.jit(fn).lower(
+        _shape((nb,), jnp.uint64, one_chip), _shape((nb,), jnp.bool_, one_chip),
+        _shape((npr,), jnp.int32, one_chip),
+        _shape((npr,), jnp.bool_, one_chip)).compile().as_text()
+    assert "conditional" in text
+
+
 # -- whole statement programs, as the SQL front door builds them ----------
 
 def _program(text, sharding):
