@@ -12,25 +12,28 @@ staged-file atomic replace.
 
 Engine difference, documented: pyarrow exposes no per-stripe column
 statistics, so ORC scans do not prune stripes by predicate the way the
-parquet connector (and the reference's selective reader) does; range
+parquet connector (and the reference's selective reader) does, and
+`column_range` proves nothing (a scan stages its logical widths); range
 splits and column pruning still apply. The conversion layer
-(engine_to_arrow / _column_to_engine) is shared with parquet."""
+(engine_to_arrow / arrow_to_engine / assemble) is shared with parquet:
+one decode a scan, no Python object per value."""
 
 from __future__ import annotations
 
+import os
 import threading
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import types as T
-from ..block import batch_from_numpy
-from .parquet import (_column_to_engine, _engine_type, _record_decode,
-                      engine_to_arrow)
+from .parquet import (_engine_type, arrow_schema, columns_batch,
+                      decode_scan, engine_to_arrow)
 
 __all__ = ["SCHEMA", "register_table", "unregister_table", "reset",
-           "table_row_count", "generate_columns", "generate_nulls",
-           "generate_batch", "column_type", "write_table",
+           "table_row_count", "read_columns", "generate_columns",
+           "generate_nulls", "generate_batch", "column_type",
+           "column_range", "write_table",
            "set_warehouse", "data_version"]
 
 _lock = threading.RLock()
@@ -69,8 +72,6 @@ SCHEMA = SCHEMA()
 
 
 def register_table(name: str, path: str) -> Dict[str, T.Type]:
-    import os
-
     import pyarrow.orc as orc
     f = orc.ORCFile(path)
     schema = {fld.name: _engine_type(fld) for fld in f.schema}
@@ -105,97 +106,137 @@ def data_version(table: str) -> float:
         return _tables[table]["mtime"]
 
 
-def _read(table: str, columns: Sequence[str], start: int, count: int):
-    """Read [start, start+count) of the requested columns, decoding only
-    the stripes the range touches (stripe = the ORC row-group analog)."""
-    import time as _time
-    t_read0 = _time.time()
+def stored_bytes(table: str) -> int:
+    with _lock:
+        return os.path.getsize(_tables[table]["path"])
+
+
+def column_range(table: str, column: str, sf: float = 0.0):
+    """None: no statistics, so width inference refuses to narrow."""
+    return None
+
+
+def read_columns(table: str, columns: Sequence[str], start: int = 0,
+                 count: Optional[int] = None, predicate=None
+                 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """Rows [start, start+count) of `columns` as (values, null masks),
+    decoding only the stripes the range touches (stripe = the ORC
+    row-group analog), each once; `predicate` prunes nothing here.
+    Hops and counters as the parquet module's."""
+    from ..exec.datapath import timed_hop
     with _lock:
         f = _tables[table]["f"]
         schema = _tables[table]["schema"]
     import pyarrow as pa
-    out_tables = []
+    count = f.nrows - start if count is None else count
+    columns = list(columns)
+    pieces = []
     seen = 0
-    for s in range(f.nstripes):
-        if seen >= start + count:
-            break  # range satisfied: do not decode trailing stripes
-        # stripe row counts come from reading the stripe lazily; pyarrow
-        # exposes no stripe metadata, so rows are counted as we go
-        t = f.read_stripe(s, columns=list(columns))
-        g_lo, g_hi = seen, seen + t.num_rows
-        seen += t.num_rows
-        if g_hi <= start:
-            continue
-        lo = max(start - g_lo, 0)
-        hi = min(start + count - g_lo, t.num_rows)
-        out_tables.append(pa.table(t).slice(lo, hi - lo))
-    if not out_tables:
-        return ({c: (np.array([]), np.array([], dtype=bool))
-                 for c in columns}, schema)
-    whole = pa.concat_tables(out_tables)
-    out = {}
-    for c in columns:
-        out[c] = _column_to_engine(whole.column(c).combine_chunks(),
-                                   schema[c])
-    _record_decode(out, _time.time() - t_read0)
-    return out, schema
+    with timed_hop("connector_read") as t_read:
+        for s in range(f.nstripes):
+            if seen >= start + count:
+                break  # range satisfied: do not read trailing stripes
+            # pyarrow exposes no stripe metadata, so rows are counted
+            # as the stripes are read
+            t = pa.table(f.read_stripe(s, columns=columns))
+            g_lo, g_hi = seen, seen + t.num_rows
+            seen += t.num_rows
+            if g_hi <= start:
+                continue
+            lo = max(start - g_lo, 0)
+            pieces.append(t.slice(lo, min(start + count - g_lo,
+                                          t.num_rows) - lo))
+        t_read.bytes = sum(t.nbytes for t in pieces)
+    return decode_scan(pieces, schema, columns, len(pieces), t_read.bytes)
 
 
 def generate_columns(table: str, sf: float, columns: Sequence[str],
                      start: int = 0, count: Optional[int] = None
                      ) -> Dict[str, np.ndarray]:
-    count = table_row_count(table) - start if count is None else count
-    data, _ = _read(table, columns, start, count)
-    return {c: v for c, (v, _n) in data.items()}
+    return read_columns(table, columns, start, count)[0]
 
 
 def generate_nulls(table: str, columns: Sequence[str], start: int = 0,
                    count: Optional[int] = None) -> Dict[str, np.ndarray]:
-    count = table_row_count(table) - start if count is None else count
-    data, _ = _read(table, columns, start, count)
-    return {c: n for c, (_v, n) in data.items()}
+    return read_columns(table, columns, start, count)[1]
 
 
 def generate_batch(table: str, sf: float, columns: Sequence[str],
                    start: int = 0, count: Optional[int] = None,
-                   capacity: Optional[int] = None):
-    count = table_row_count(table) - start if count is None else count
-    data, schema = _read(table, columns, start, count)
-    vals = [data[c][0] for c in columns]
-    nulls = [data[c][1] for c in columns]
-    types = [schema[c] for c in columns]
-    n = len(vals[0]) if vals else 0
-    cap = capacity or max(n, 1)
-    return batch_from_numpy(types, vals, capacity=cap, nulls=nulls)
+                   capacity: Optional[int] = None, predicate=None):
+    values, nulls = read_columns(table, columns, start, count)
+    return columns_batch(values, nulls, SCHEMA[table], columns, capacity)
 
 
 # ---------------------------------------------------------------------------
-# writer sink: the staged commit state machine is the SHARED LakeSink
+# the writer: this format's primitives under the SHARED LakeSink
 # (lake_sink.py, ConnectorPageSink analog)
 # ---------------------------------------------------------------------------
+
+
+def _orc_schema(schema):
+    """The schema as ORC can hold it: pyarrow's ORC writer knows no
+    decimal64, so a short decimal goes as decimal128."""
+    import pyarrow as pa
+    return pa.schema([
+        pa.field(f.name, pa.decimal128(f.type.precision, f.type.scale))
+        if pa.types.is_decimal(f.type) else f for f in schema])
+
+
+class _StripeWriter:
+    """pyarrow's ORCWriter behind the sink's writer surface
+    (`schema`, `write_table`, `close`)."""
+
+    def __init__(self, path: str, schema, stripe_size: Optional[int] = None):
+        import pyarrow.orc as orc
+        self.schema = _orc_schema(schema)
+        kw = {"stripe_size": stripe_size} if stripe_size else {}
+        self._w = orc.ORCWriter(path, **kw)
+        self._wrote = False
+
+    def write_table(self, tbl) -> None:
+        self._w.write(tbl.cast(self.schema))
+        self._wrote = True
+
+    def close(self) -> None:
+        if self._w is None:
+            return
+        if not self._wrote:  # an ORC file needs its schema written
+            self._w.write(self.schema.empty_table())
+        self._w.close()
+        self._w = None
+
+
+open_writer = _StripeWriter
+
+
+def read_tables(path: str):
+    """The file's stripes as arrow tables, one at a time."""
+    import pyarrow as pa
+    import pyarrow.orc as orc
+    f = orc.ORCFile(path)
+    for s in range(f.nstripes):
+        yield pa.table(f.read_stripe(s))
 
 
 def write_table(path: str, columns: Dict[str, np.ndarray],
                 types: Dict[str, T.Type],
                 nulls: Optional[Dict[str, np.ndarray]] = None,
                 stripe_size: Optional[int] = None) -> None:
-    import pyarrow.orc as orc
-    tbl = engine_to_arrow(columns, types, nulls)
-    kw = {"stripe_size": stripe_size} if stripe_size else {}
-    orc.write_table(tbl, path, **kw)
-
-
-def _read_all(table: str, columns):
-    return _read(table, columns, 0, table_row_count(table))[0]
+    w = _StripeWriter(path, arrow_schema({c: types[c] for c in columns}),
+                      stripe_size)
+    try:
+        w.write_table(engine_to_arrow(columns, types, nulls))
+    finally:
+        w.close()
 
 
 from .lake_sink import LakeSink  # noqa: E402
 
-_sink = LakeSink("orc", ".orc", _tables, _lock, write_table,
-                 register_table, table_row_count, _read_all)
+_sink = LakeSink("orc", ".orc", _tables, _lock, open_writer, read_tables,
+                 register_table)
 set_warehouse = _sink.set_warehouse
 write_lock = _sink.write_lock
-create_table = _sink.create_table
 drop_table = _sink.drop_table
 begin_insert = _sink.begin_insert
 append = _sink.append

@@ -8,41 +8,55 @@ straight into the SAME columnar batches every other connector produces,
 so the whole engine -- stats, dynamic filtering, adaptive capacities,
 mesh sharding -- runs unchanged over files.
 
-Pushdown hooks:
-  * column pruning is intrinsic: only requested columns are read;
-  * row-group pruning: scans with a `predicate` (column, lo, hi) skip
-    row groups whose min/max statistics cannot match (the
-    OrcSelectiveRecordReader stripe-skip analog). The dynamic-filter
-    path feeds this from build-side key domains.
+A scan is one call, `read_columns`: the row groups its row range
+touches are its splits, those whose footer statistics exclude the
+pushed-down range are skipped, and the rest are read (file bytes ->
+arrow, hop ``connector_read``) and decoded (arrow -> the engine's lanes
+and null masks, hop ``decode``) once, on a small thread pool, each
+group into its own slice of the output. Nothing on the way makes a
+Python object per value: a short decimal is its int64 lane, a date its
+int32, a string column a `block.HostStrings` built from arrow's offsets
+and bytes. `column_range` answers from the footers' min/max, so
+plan/widths.py narrows a file scan's lanes as it narrows a memory
+table's.
 
-Tables register explicitly (`register_table(name, path)`); engine types
-derive from the parquet schema (decimals -> scaled int64/int128 lanes,
-date32 -> day numbers, strings -> varchar)."""
+The writer (`engine_to_arrow`, `open_writer`; the staged commit is the
+shared `lake_sink.LakeSink`) is the same conversions the other way, a
+row group at a time. Short decimals are stored as Parquet INT64 (INT32
+at precision 9 or less) with the DECIMAL logical type; the engine's
+types ride the file's key-value metadata, so a table read back has the
+varchar widths it was written with.
+
+Tables register explicitly (`register_table(name, path)`) or by a
+write; engine types of a foreign file derive from its schema (decimals
+-> scaled int64/int128 lanes, date32 -> day numbers, strings ->
+varchar)."""
 
 from __future__ import annotations
 
+import json
+import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import types as T
-from ..block import batch_from_numpy
+from ..block import HostStrings, batch_from_numpy
 
 __all__ = ["SCHEMA", "register_table", "unregister_table", "reset",
-           "table_row_count", "generate_columns", "generate_nulls",
-           "generate_batch", "column_type", "write_table",
-           "row_groups_matching"]
+           "table_row_count", "read_columns", "generate_columns",
+           "generate_nulls", "generate_batch", "column_type",
+           "column_range", "write_table", "row_groups_matching"]
 
-
-def _pa():
-    import pyarrow
-    import pyarrow.parquet  # noqa: F401
-    return pyarrow
-
+# the writer's defaults (benchmarks/configs/tpch_sf10_parquet.json
+# states them under `assumed`)
+ROW_GROUP_ROWS = 1 << 20
+CODEC = "snappy"
+_TYPES_KEY = b"presto_tpu.types"
 
 _lock = threading.RLock()
-_tables: Dict[str, dict] = {}  # name -> {path, pf, schema{col: Type}}
+_tables: Dict[str, dict] = {}  # name -> {path, pf, schema{col: Type}, ...}
 
 
 def _engine_type(field) -> T.Type:
@@ -71,6 +85,20 @@ def _engine_type(field) -> T.Type:
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         return T.varchar(1 << 19)  # width discovered per batch at stage
     raise NotImplementedError(f"parquet type {t} for {field.name}")
+
+
+def engine_schema(arrow_schema) -> Dict[str, T.Type]:
+    """Engine types of a file's columns: those its writer left in the
+    key-value metadata where it was this engine's, else what the arrow
+    types say."""
+    kept = {}
+    try:
+        kept = {c: T.parse_type(s) for c, s in json.loads(
+            (arrow_schema.metadata or {}).get(_TYPES_KEY, b"{}")).items()}
+    except ValueError:
+        pass  # another writer's metadata under the key: the arrow types
+    return {f.name: kept.get(f.name) or _engine_type(f)
+            for f in arrow_schema}
 
 
 class SCHEMA(dict):  # noqa: N801 - registry surface
@@ -105,18 +133,16 @@ SCHEMA = SCHEMA()
 
 
 def register_table(name: str, path: str) -> Dict[str, T.Type]:
-    import os
-
     import pyarrow.parquet as pq
     pf = pq.ParquetFile(path)
-    schema = {f.name: _engine_type(f) for f in pf.schema_arrow}
+    schema = engine_schema(pf.schema_arrow)
     with _lock:
         # mtime snapshot taken WITH the handle: result caching keys on
         # the data this handle actually reads (an overwritten file
         # serves stale rows until re-registration, and re-registration
-        # refreshes both handle and version together)
+        # refreshes handle, version and footer ranges together)
         _tables[name] = {"path": path, "pf": pf, "schema": schema,
-                         "mtime": os.path.getmtime(path)}
+                         "mtime": os.path.getmtime(path), "ranges": {}}
     return schema
 
 
@@ -140,224 +166,6 @@ def table_row_count(table: str, sf: float = 0.0) -> int:
         return _tables[table]["pf"].metadata.num_rows
 
 
-def row_groups_matching(table: str,
-                        predicate: Optional[Tuple[str, object, object]]
-                        ) -> List[int]:
-    """Row groups whose min/max statistics can satisfy
-    `(column, lo, hi)` (None bound = unbounded) -- the row-group-level
-    predicate pushdown hook."""
-    with _lock:
-        md = _tables[table]["pf"].metadata
-        schema = _tables[table]["pf"].schema_arrow
-    if predicate is None:
-        return list(range(md.num_row_groups))
-    col, lo, hi = predicate
-    ci = schema.get_field_index(col)
-
-    def _engine_repr(v):
-        """Parquet stat value -> this engine's lane representation
-        (dates = epoch days, timestamps = micros, decimals = scaled)."""
-        import datetime
-        import decimal
-        if isinstance(v, datetime.datetime):
-            return int(v.replace(tzinfo=datetime.timezone.utc)
-                       .timestamp() * 1_000_000)
-        if isinstance(v, datetime.date):
-            return (v - datetime.date(1970, 1, 1)).days
-        if isinstance(v, decimal.Decimal):
-            exp = -v.as_tuple().exponent
-            return int(v.scaleb(exp))
-        return v
-
-    out = []
-    for g in range(md.num_row_groups):
-        st = md.row_group(g).column(ci).statistics
-        if st is None or not st.has_min_max:
-            out.append(g)
-            continue
-        smax = _engine_repr(st.max) if st.max is not None else None
-        smin = _engine_repr(st.min) if st.min is not None else None
-        if lo is not None and smax is not None and smax < lo:
-            continue
-        if hi is not None and smin is not None and smin > hi:
-            continue
-        out.append(g)
-    return out
-
-
-def _column_to_engine(arr, ty: T.Type) -> Tuple[np.ndarray, np.ndarray]:
-    """pyarrow array -> (engine values, null mask)."""
-    import pyarrow as pa
-    import pyarrow.compute as pc
-    nulls = np.asarray(arr.is_null().to_numpy(zero_copy_only=False))
-    if ty.is_decimal:
-        if ty.is_short_decimal and pa.types.is_decimal128(arr.type) and \
-                arr.type.scale == ty.scale:
-            # vectorized: a decimal128's unscaled value is a 16-byte
-            # two's-complement int; for p <= 18 it fits int64, so the
-            # little-endian LOW word IS the value -- no Python loop on
-            # the hot scan path
-            data = np.frombuffer(arr.buffers()[1], dtype=np.int64)
-            lo = data[0::2]
-            vals = lo[arr.offset:arr.offset + len(arr)].copy()
-            return np.where(nulls, 0, vals), nulls
-        # long decimals (int128) decode exactly through Python ints
-        vals = np.array([0 if v is None else int(v.scaleb(ty.scale))
-                         for v in arr.to_pylist()], dtype=object)
-        if ty.is_short_decimal:
-            vals = vals.astype(np.int64)
-        return vals, nulls
-    if ty.base == "date":
-        days = pc.cast(arr, pa.int32()).to_numpy(zero_copy_only=False)
-        return np.where(nulls, 0, days).astype(np.int32), nulls
-    if ty.base == "timestamp":
-        us = pc.cast(pc.cast(arr, pa.timestamp("us")),
-                     pa.int64()).to_numpy(zero_copy_only=False)
-        return np.where(nulls, 0, us).astype(np.int64), nulls
-    if ty.is_string:
-        vals = np.array(["" if v is None else v for v in arr.to_pylist()],
-                        dtype=object)
-        return vals, nulls
-    np_vals = arr.to_numpy(zero_copy_only=False)
-    fill = ty.to_dtype().type(0)
-    return np.where(nulls, fill, np_vals).astype(ty.to_dtype()), nulls
-
-
-def _record_decode(cols: Dict[str, Tuple[np.ndarray, np.ndarray]],
-                   seconds: float) -> None:
-    """File decode feeds the data-path waterfall's ``decode`` hop
-    (exec/datapath.py) with the decoded engine-array bytes. Shielded:
-    connectors must stay importable in stripped tooling, and
-    attribution must never fail a scan. Shared with the ORC reader."""
-    try:
-        from ..exec.datapath import record_hop
-        record_hop("decode",
-                   sum(v.nbytes + n.nbytes for v, n in cols.values()),
-                   seconds)
-    except Exception:  # noqa: BLE001 - attribution is garnish here
-        pass
-
-
-def _read(table: str, columns: Sequence[str], start: int, count: int,
-          predicate=None):
-    """Read [start, start+count) of the requested columns, decoding only
-    the row groups the range (and the optional predicate) touches."""
-    import time as _time
-    t_read0 = _time.time()
-    with _lock:
-        pf = _tables[table]["pf"]
-        schema = _tables[table]["schema"]
-    groups = row_groups_matching(table, predicate)
-    md = pf.metadata
-    read_stats["groups_total"] += md.num_row_groups
-    read_stats["groups_read"] += len(groups)
-    out_tables = []
-    seen = 0
-    for g in range(md.num_row_groups):
-        g_rows = md.row_group(g).num_rows
-        g_lo, g_hi = seen, seen + g_rows
-        seen += g_rows
-        if g_hi <= start or g_lo >= start + count or g not in groups:
-            continue
-        t = pf.read_row_group(g, columns=list(columns))
-        lo = max(start - g_lo, 0)
-        hi = min(start + count - g_lo, g_rows)
-        out_tables.append(t.slice(lo, hi - lo))
-    import pyarrow as pa
-    if not out_tables:
-        empty = {c: ([], []) for c in columns}
-        return {c: (np.array(v), np.array(n, dtype=bool))
-                for c, (v, n) in empty.items()}, schema
-    whole = pa.concat_tables(out_tables)
-    out = {}
-    for c in columns:
-        out[c] = _column_to_engine(whole.column(c).combine_chunks(),
-                                   schema[c])
-    _record_decode(out, _time.time() - t_read0)
-    return out, schema
-
-
-def generate_columns(table: str, sf: float, columns: Sequence[str],
-                     start: int = 0, count: Optional[int] = None
-                     ) -> Dict[str, np.ndarray]:
-    count = table_row_count(table) - start if count is None else count
-    data, _ = _read(table, columns, start, count)
-    return {c: v for c, (v, _n) in data.items()}
-
-
-def generate_nulls(table: str, columns: Sequence[str], start: int = 0,
-                   count: Optional[int] = None) -> Dict[str, np.ndarray]:
-    count = table_row_count(table) - start if count is None else count
-    data, _ = _read(table, columns, start, count)
-    return {c: n for c, (_v, n) in data.items()}
-
-
-def generate_batch(table: str, sf: float, columns: Sequence[str],
-                   start: int = 0, count: Optional[int] = None,
-                   capacity: Optional[int] = None, predicate=None):
-    count = table_row_count(table) - start if count is None else count
-    data, schema = _read(table, columns, start, count, predicate)
-    vals = [data[c][0] for c in columns]
-    nulls = [data[c][1] for c in columns]
-    types = [schema[c] for c in columns]
-    n = len(vals[0]) if vals else 0
-    cap = capacity or max(n, 1)
-    return batch_from_numpy(types, vals, capacity=cap, nulls=nulls)
-
-
-def engine_to_arrow(columns: Dict[str, np.ndarray],
-                    types: Dict[str, T.Type],
-                    nulls: Optional[Dict[str, np.ndarray]] = None):
-    """Engine-representation columns -> a pyarrow Table (shared by the
-    parquet and ORC sinks)."""
-    import decimal
-
-    import pyarrow as pa
-    arrays, fields = [], []
-    for name, vals in columns.items():
-        ty = types[name]
-        nl = None if nulls is None or name not in nulls else \
-            np.asarray(nulls[name], dtype=bool)
-
-        def masked(py_vals):
-            if nl is None:
-                return py_vals
-            return [None if nl[i] else v for i, v in enumerate(py_vals)]
-        if ty.is_decimal:
-            pa_t = pa.decimal128(ty.precision, ty.scale)
-            py = [decimal.Decimal(int(v)).scaleb(-ty.scale)
-                  for v in np.asarray(vals, dtype=object)]
-            arrays.append(pa.array(masked(py), type=pa_t))
-        elif ty.base == "date":
-            pa_t = pa.date32()
-            arrays.append(pa.array(masked([int(v) for v in vals]),
-                                   type=pa_t))
-        elif ty.base == "timestamp":
-            pa_t = pa.timestamp("us")
-            arrays.append(pa.array(masked([int(v) for v in vals]),
-                                   type=pa_t))
-        elif ty.is_string:
-            pa_t = pa.string()
-            arrays.append(pa.array(masked([str(v) for v in vals]),
-                                   type=pa_t))
-        else:
-            pa_t = pa.from_numpy_dtype(ty.to_dtype())
-            arrays.append(pa.array(masked(list(vals)), type=pa_t))
-        fields.append(pa.field(name, arrays[-1].type))
-    return pa.Table.from_arrays(arrays, schema=pa.schema(fields))
-
-
-def write_table(path: str, columns: Dict[str, np.ndarray],
-                types: Dict[str, T.Type],
-                nulls: Optional[Dict[str, np.ndarray]] = None,
-                row_group_size: Optional[int] = None) -> None:
-    """Write engine-representation columns to a parquet file (the
-    TableWriter parquet sink and the fixture writer)."""
-    import pyarrow.parquet as pq
-    tbl = engine_to_arrow(columns, types, nulls)
-    pq.write_table(tbl, path, row_group_size=row_group_size)
-
-
 def data_version(table: str) -> float:
     """Fragment-result-cache seam: the registration-time mtime snapshot
     (what the pinned reader handle actually serves)."""
@@ -365,26 +173,448 @@ def data_version(table: str) -> float:
         return _tables[table]["mtime"]
 
 
+def stored_bytes(table: str) -> int:
+    """The table's file size: what a write reports as `write_bytes`."""
+    with _lock:
+        return os.path.getsize(_tables[table]["path"])
+
+
 # ---------------------------------------------------------------------------
-# Read statistics (pruning evidence) + the writer sink: the staged
-# commit state machine is the SHARED LakeSink (lake_sink.py,
-# ConnectorPageSink analog), bound to this module's primitives
+# footer statistics: row-group pruning and column ranges
 # ---------------------------------------------------------------------------
 
-read_stats = {"groups_total": 0, "groups_read": 0}
+
+def _engine_repr(v):
+    """Parquet stat value -> this engine's lane representation
+    (dates = epoch days, timestamps = micros, decimals = scaled)."""
+    import datetime
+    import decimal
+    if isinstance(v, datetime.datetime):
+        return int(v.replace(tzinfo=datetime.timezone.utc)
+                   .timestamp() * 1_000_000)
+    if isinstance(v, datetime.date):
+        return (v - datetime.date(1970, 1, 1)).days
+    if isinstance(v, decimal.Decimal):
+        return int(v.scaleb(-v.as_tuple().exponent))
+    return v
 
 
-def _read_all(table: str, columns):
-    return _read(table, columns, 0, table_row_count(table))[0]
+def _group_stats(md, column_index: int):
+    """(min, max) of each row group in engine representation; None for
+    a group whose footer gives none."""
+    out = []
+    for g in range(md.num_row_groups):
+        st = md.row_group(g).column(column_index).statistics
+        if st is None or not st.has_min_max:
+            out.append(None)
+        else:
+            out.append((_engine_repr(st.min), _engine_repr(st.max)))
+    return out
+
+
+def row_groups_matching(table: str,
+                        predicate: Optional[Tuple[str, object, object]]
+                        ) -> List[int]:
+    """Row groups whose min/max statistics can satisfy
+    `(column, lo, hi)` (None bound = unbounded) -- the row-group-level
+    predicate pushdown hook."""
+    with _lock:
+        pf = _tables[table]["pf"]
+    md = pf.metadata
+    if predicate is None:
+        return list(range(md.num_row_groups))
+    col, lo, hi = predicate
+    out = []
+    for g, mm in enumerate(_group_stats(
+            md, pf.schema_arrow.get_field_index(col))):
+        if mm is not None and ((lo is not None and mm[1] < lo) or
+                               (hi is not None and mm[0] > hi)):
+            continue
+        out.append(g)
+    return out
+
+
+def column_range(table: str, column: str, sf: float = 0.0):
+    """(lo, hi) over the column's non-null values from the footers'
+    min/max (narrow-width execution stats), read once per registered
+    file. None for a column that is not an integer lane, an empty or
+    all-null one, or a file with a row group whose statistics are
+    absent: width inference then refuses to narrow. A file overwritten
+    behind the handle is covered by the staging-time guard
+    (plan/widths.checked_physical_dtypes)."""
+    with _lock:
+        ent = _tables.get(table)
+        if ent is None:
+            raise KeyError(f"no parquet table {table!r}")
+        if column in ent["ranges"]:
+            return ent["ranges"][column]
+        pf, ty = ent["pf"], ent["schema"][column]
+    found = None
+    if ty.is_fixed_width and ty.to_dtype().kind in "iu" and \
+            not (ty.is_decimal and not ty.is_short_decimal):
+        md = pf.metadata
+        ci = pf.schema_arrow.get_field_index(column)
+        stats = _group_stats(md, ci)
+        live = [mm for g, mm in enumerate(stats) if mm is not None]
+        known = all(
+            mm is not None or _all_null(md.row_group(g).column(ci))
+            for g, mm in enumerate(stats))
+        if live and known:
+            found = (min(mm[0] for mm in live), max(mm[1] for mm in live))
+    with _lock:
+        ent["ranges"][column] = found
+    return found
+
+
+def _all_null(chunk) -> bool:
+    st = chunk.statistics
+    return st is not None and st.has_null_count and \
+        st.null_count == chunk.num_values
+
+
+# ---------------------------------------------------------------------------
+# arrow <-> engine lanes (shared with the ORC module)
+# ---------------------------------------------------------------------------
+
+
+def _ragged(width: int, lengths: np.ndarray) -> np.ndarray:
+    """(n, width) mask of the bytes each row of a `HostStrings` holds:
+    row-major, it lists them in the order arrow's data buffer does."""
+    return np.arange(width, dtype=np.int32)[None, :] < lengths[:, None]
+
+
+def arrow_to_engine(arr, ty: T.Type
+                    ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """One arrow array -> (engine values, null mask or None where no
+    row is null). A null row's value is 0 (an empty string)."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+    n, off = len(arr), arr.offset
+    nulls = np.asarray(arr.is_null().to_numpy(zero_copy_only=False)) \
+        if arr.null_count else None
+    at = arr.type
+    if ty.is_string:
+        if nulls is not None:
+            arr = pc.fill_null(arr, "")  # a null row holds no bytes
+            off = arr.offset
+        wide = pa.types.is_large_string(arr.type)
+        offs = np.frombuffer(arr.buffers()[1],
+                             dtype=np.int64 if wide else np.int32
+                             )[off:off + n + 1]
+        lengths = np.diff(offs).astype(np.int32)
+        chars = np.zeros((n, max(int(lengths.max()) if n else 0, 1)),
+                         dtype=np.uint8)
+        if n and offs[-1] > offs[0]:
+            chars[_ragged(chars.shape[1], lengths)] = np.frombuffer(
+                arr.buffers()[2], dtype=np.uint8)[offs[0]:offs[-1]]
+        return HostStrings(chars, lengths), nulls
+    if ty.is_decimal and ty.is_short_decimal and at.scale == ty.scale:
+        # a decimal's unscaled value is a little-endian two's-complement
+        # integer of 4, 8, 16 or 32 bytes; for p <= 18 it fits int64, so
+        # the LOW word is the value
+        if at.byte_width == 4:
+            vals = np.frombuffer(arr.buffers()[1], dtype=np.int32
+                                 )[off:off + n].astype(np.int64)
+        else:
+            words = at.byte_width // 8
+            vals = np.frombuffer(arr.buffers()[1], dtype=np.int64
+                                 )[off * words:(off + n) * words:words]
+    elif ty.is_decimal:
+        # long decimals (int128 lanes) decode exactly through Python ints
+        vals = np.array([0 if v is None else int(v.scaleb(ty.scale))
+                         for v in arr.to_pylist()], dtype=object)
+        return (vals.astype(np.int64) if ty.is_short_decimal else vals), \
+            nulls
+    elif ty.base == "boolean":
+        vals = arr.to_numpy(zero_copy_only=False)
+        if nulls is not None:
+            vals = np.where(nulls, False, vals).astype(bool)
+        return vals, nulls
+    else:
+        if ty.base == "date":
+            arr = arr.cast(pa.date32()).cast(pa.int32())
+        elif ty.base == "timestamp":
+            arr = arr.cast(pa.timestamp("us")).cast(pa.int64())
+        vals = np.frombuffer(arr.buffers()[1],
+                             dtype=arr.type.to_pandas_dtype()
+                             )[arr.offset:arr.offset + n]
+    if vals.dtype != ty.to_dtype() or nulls is not None:
+        vals = vals.astype(ty.to_dtype())  # the caller's own copy
+        if nulls is not None:
+            vals[nulls] = 0
+    return vals, nulls
+
+
+def arrow_type(ty: T.Type):
+    import pyarrow as pa
+    if ty.is_decimal:
+        return pa.decimal64(ty.precision, ty.scale) if ty.is_short_decimal \
+            else pa.decimal128(ty.precision, ty.scale)
+    if ty.base == "date":
+        return pa.date32()
+    if ty.base == "timestamp":
+        return pa.timestamp("us")
+    if ty.is_string:
+        return pa.string()
+    return pa.from_numpy_dtype(ty.to_dtype())
+
+
+def arrow_schema(types: Dict[str, T.Type]):
+    import pyarrow as pa
+    return pa.schema(
+        [pa.field(c, arrow_type(ty)) for c, ty in types.items()],
+        metadata={_TYPES_KEY: json.dumps(
+            {c: str(ty) for c, ty in types.items()}).encode()})
+
+
+def _column_to_arrow(vals, ty: T.Type, nulls: Optional[np.ndarray]):
+    import pyarrow as pa
+    n = len(vals)
+    if nulls is not None:
+        nulls = np.asarray(nulls, dtype=bool)
+        if not nulls.any():
+            nulls = None
+    if ty.is_decimal and not ty.is_short_decimal:
+        import decimal
+        return pa.array(
+            [None if nulls is not None and nulls[i]
+             else decimal.Decimal(int(v)).scaleb(-ty.scale)
+             for i, v in enumerate(vals)], type=arrow_type(ty))
+    if ty.is_string or ty.is_decimal:
+        valid, n_null = None, 0
+        if nulls is not None:
+            valid = pa.py_buffer(np.packbits(~nulls, bitorder="little"))
+            n_null = int(nulls.sum())
+        if ty.is_decimal:
+            lanes = np.ascontiguousarray(vals, dtype=np.int64)
+            return pa.Array.from_buffers(arrow_type(ty), n,
+                                         [valid, pa.py_buffer(lanes)],
+                                         n_null)
+        hs = HostStrings.from_objects(vals)  # itself, where it is one
+        lengths = hs.lengths if nulls is None else \
+            np.where(nulls, 0, hs.lengths).astype(np.int32)
+        offsets = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(lengths, out=offsets[1:])
+        if offsets[-1] >= 1 << 31:
+            raise ValueError(f"{offsets[-1]} bytes of strings in one "
+                             "arrow array: write fewer rows at a time")
+        return pa.Array.from_buffers(
+            pa.string(), n,
+            [valid, pa.py_buffer(offsets.astype(np.int32)),
+             pa.py_buffer(hs.chars[_ragged(hs.chars.shape[1], lengths)])],
+            n_null)
+    lanes = np.asarray(vals, dtype=ty.to_dtype())
+    arr = pa.array(lanes, mask=nulls)
+    return arr if arr.type == arrow_type(ty) else arr.cast(arrow_type(ty))
+
+
+def engine_to_arrow(columns: Dict[str, np.ndarray],
+                    types: Dict[str, T.Type],
+                    nulls: Optional[Dict[str, np.ndarray]] = None):
+    """Engine-representation columns -> a pyarrow Table, lanes and
+    buffers handed over as they are (shared by the parquet and ORC
+    sinks). Only a long decimal, which the engine itself holds as
+    Python ints, is converted value by value."""
+    import pyarrow as pa
+    return pa.Table.from_arrays(
+        [_column_to_arrow(vals, types[c], (nulls or {}).get(c))
+         for c, vals in columns.items()],
+        schema=arrow_schema({c: types[c] for c in columns}))
+
+
+# ---------------------------------------------------------------------------
+# the scan
+# ---------------------------------------------------------------------------
+
+_pool_lock = threading.Lock()
+_pool = None
+
+
+def _decode_pool():
+    """The few threads a scan's row groups decode on (pyarrow and
+    numpy's copies release the GIL)."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+            _pool = ThreadPoolExecutor(
+                max_workers=min(8, os.cpu_count() or 1),
+                thread_name_prefix="lake-decode")
+        return _pool
+
+
+def _empty_lane(ty: T.Type, n: int):
+    if ty.is_string:
+        return None  # its groups are concatenated: widths differ
+    if ty.is_decimal and not ty.is_short_decimal:
+        return np.zeros(n, dtype=object)
+    return np.empty(n, dtype=ty.to_dtype())
+
+
+def assemble(pieces, schema: Dict[str, T.Type], columns: Sequence[str],
+             pool) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """Arrow tables (one a row group or stripe, in row order) -> the
+    engine's columns and null masks: each piece decoded once, on
+    `pool`, into its own slice of lanes allocated whole."""
+    starts = np.concatenate([[0], np.cumsum([t.num_rows for t in pieces])]
+                            ).astype(np.int64)
+    total = int(starts[-1])
+    values = {c: _empty_lane(schema[c], total) for c in columns}
+    nulls = {c: np.zeros(total, dtype=bool) for c in columns}
+    strings = {c: [None] * len(pieces) for c in columns
+               if values[c] is None}
+
+    def decode(k: int) -> None:
+        lo, hi = int(starts[k]), int(starts[k + 1])
+        for c in columns:
+            col = pieces[k].column(c)
+            arr = col.chunk(0) if col.num_chunks == 1 \
+                else col.combine_chunks()
+            vals, nl = arrow_to_engine(arr, schema[c])
+            if nl is not None:
+                nulls[c][lo:hi] = nl
+            if c in strings:
+                strings[c][k] = vals
+            else:
+                values[c][lo:hi] = vals
+
+    list(pool.map(decode, range(len(pieces))))
+    for c, parts in strings.items():
+        values[c] = HostStrings.concat(parts)
+    return values, nulls
+
+
+def decode_scan(pieces, schema: Dict[str, T.Type], columns: Sequence[str],
+                touched: int, file_bytes: int
+                ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """The second half of a lake scan, shared with the ORC module: the
+    ``decode`` hop over the pieces read, and the statement's counters
+    (`touched` row groups or stripes met, `len(pieces)` read)."""
+    from ..exec.datapath import timed_hop
+    from ..exec.stats import note
+    with timed_hop("decode") as t_decode:
+        values, nulls = assemble(pieces, schema, columns, _decode_pool())
+        t_decode.bytes = sum(v.nbytes for v in values.values()) + \
+            sum(n.nbytes for n in nulls.values())
+    note("lake_row_groups_total", touched)
+    note("lake_row_groups_read", len(pieces))
+    note("lake_file_bytes", file_bytes)
+    note("lake_decoded_bytes", t_decode.bytes)
+    return values, nulls
+
+
+def columns_batch(values, nulls, schema: Dict[str, T.Type],
+                  columns: Sequence[str], capacity: Optional[int]):
+    """What `read_columns` gave as a device batch."""
+    vals = [values[c] for c in columns]
+    n = len(vals[0]) if vals else 0
+    return batch_from_numpy([schema[c] for c in columns], vals,
+                            capacity=capacity or max(n, 1),
+                            nulls=[nulls[c] for c in columns])
+
+
+def read_columns(table: str, columns: Sequence[str], start: int = 0,
+                 count: Optional[int] = None, predicate=None
+                 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """Rows [start, start+count) of `columns` as (values, null masks):
+    the one decode of a scan. Row groups the range does not touch, and
+    those `predicate` (column, lo, hi) excludes by their statistics,
+    are not opened: with a predicate the answer may hold fewer rows
+    than `count`. Records the hops ``connector_read`` and ``decode``
+    and the statement's ``lake_*`` counters."""
+    import pyarrow.parquet as pq
+    from ..exec.datapath import timed_hop
+    with _lock:
+        ent = _tables[table]
+    path, md, schema = ent["path"], ent["pf"].metadata, ent["schema"]
+    count = md.num_rows - start if count is None else count
+    columns = list(columns)
+    keep = set(row_groups_matching(table, predicate))
+    picked, touched, at = [], 0, 0  # picked: (group, first row, rows)
+    for g in range(md.num_row_groups):
+        g_rows = md.row_group(g).num_rows
+        lo, hi = max(start - at, 0), min(start + count - at, g_rows)
+        at += g_rows
+        if lo < hi:
+            touched += 1
+            if g in keep:
+                picked.append((g, lo, hi - lo))
+    index = [ent["pf"].schema_arrow.get_field_index(c) for c in columns]
+    file_bytes = sum(md.row_group(g).column(ci).total_compressed_size
+                     for g, _lo, _n in picked for ci in index)
+
+    def read(pick):
+        g, lo, n = pick
+        # a reader of its own: a ParquetFile is one file position
+        t = pq.ParquetFile(path, metadata=md).read_row_group(
+            g, columns=columns, use_threads=False)
+        return t if n == t.num_rows else t.slice(lo, n)
+
+    with timed_hop("connector_read", file_bytes):
+        pieces = list(_decode_pool().map(read, picked))
+    return decode_scan(pieces, schema, columns, touched, file_bytes)
+
+
+def generate_columns(table: str, sf: float, columns: Sequence[str],
+                     start: int = 0, count: Optional[int] = None
+                     ) -> Dict[str, np.ndarray]:
+    return read_columns(table, columns, start, count)[0]
+
+
+def generate_nulls(table: str, columns: Sequence[str], start: int = 0,
+                   count: Optional[int] = None) -> Dict[str, np.ndarray]:
+    return read_columns(table, columns, start, count)[1]
+
+
+def generate_batch(table: str, sf: float, columns: Sequence[str],
+                   start: int = 0, count: Optional[int] = None,
+                   capacity: Optional[int] = None, predicate=None):
+    values, nulls = read_columns(table, columns, start, count, predicate)
+    return columns_batch(values, nulls, SCHEMA[table], columns, capacity)
+
+
+# ---------------------------------------------------------------------------
+# the writer: this format's primitives under the SHARED LakeSink
+# (lake_sink.py, ConnectorPageSink analog)
+# ---------------------------------------------------------------------------
+
+
+def open_writer(path: str, schema):
+    """A writer of row groups at `path` (`write_table(arrow table)`,
+    `close()`), with this module's defaults."""
+    import pyarrow.parquet as pq
+    return pq.ParquetWriter(path, schema, compression=CODEC,
+                            store_decimal_as_integer=True)
+
+
+def read_tables(path: str):
+    """The file's row groups as arrow tables, one at a time."""
+    import pyarrow.parquet as pq
+    pf = pq.ParquetFile(path)
+    for g in range(pf.metadata.num_row_groups):
+        yield pf.read_row_group(g)
+
+
+def write_table(path: str, columns: Dict[str, np.ndarray],
+                types: Dict[str, T.Type],
+                nulls: Optional[Dict[str, np.ndarray]] = None,
+                row_group_size: Optional[int] = None) -> None:
+    """Write engine-representation columns to a parquet file (the
+    fixture writer; a table's writes go through the sink)."""
+    w = open_writer(path, arrow_schema({c: types[c] for c in columns}))
+    try:
+        w.write_table(engine_to_arrow(columns, types, nulls),
+                      row_group_size=row_group_size or ROW_GROUP_ROWS)
+    finally:
+        w.close()
 
 
 from .lake_sink import LakeSink  # noqa: E402
 
-_sink = LakeSink("parquet", ".parquet", _tables, _lock, write_table,
-                 register_table, table_row_count, _read_all)
+_sink = LakeSink("parquet", ".parquet", _tables, _lock, open_writer,
+                 read_tables, register_table)
 set_warehouse = _sink.set_warehouse
 write_lock = _sink.write_lock
-create_table = _sink.create_table
 drop_table = _sink.drop_table
 begin_insert = _sink.begin_insert
 append = _sink.append

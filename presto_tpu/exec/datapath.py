@@ -48,10 +48,14 @@ Hop semantics (cross-hop overlap is deliberate: hops are independent
 attributions of one byte stream at different stages, not a partition
 of wall time -- exchange_fetch CONTAINS page decode, and both record):
 
-  connector_read      host column materialization (file read or
-                      generator) -- bytes are host array bytes
-  decode              encoded -> engine-array decode (parquet/ORC row
-                      groups, SerializedPage payloads)
+  connector_read      host column materialization by a generator or a
+                      memory table (bytes are host array bytes); for
+                      a lake scan, file bytes -> arrow arrays (bytes
+                      are the compressed column chunks read)
+  decode              encoded -> engine-array decode: a lake scan's
+                      arrow arrays -> lanes and null masks, after its
+                      connector_read and beside nothing; SerializedPage
+                      payloads
   narrow_cast         narrow-width staging-time range re-proof + cast
   device_put          host -> HBM staging (batch_from_numpy); bytes
                       equal the staged batch (what QueryStats'

@@ -83,24 +83,58 @@ def _host_bytes(arrays, nulls=None) -> int:
     return total
 
 
+def _read_split(conn, node: "N.TableScanNode", sf: float, start: int,
+                count: int, predicate=None):
+    """One split's host columns and null masks (None where the
+    connector has no nulls), in `node.columns` order. A stored connector
+    that offers `read_columns` is asked once for both (a file is opened
+    and decoded once; it records its own ``connector_read`` and
+    ``decode`` hops, and with a `predicate` may give fewer rows than
+    `count`); the others' two calls are views or generated columns,
+    timed here as ``connector_read``."""
+    from .datapath import timed_hop
+    one_call = getattr(conn, "read_columns", None)
+    if one_call is not None:
+        data, nmap = one_call(node.table, node.columns, start, count,
+                              predicate)
+        return ([data[c] for c in node.columns],
+                [nmap[c] for c in node.columns])
+    with timed_hop("connector_read") as t_read:
+        data = conn.generate_columns(node.table, sf, node.columns,
+                                     start, count)
+        arrays = [data[c] for c in node.columns]
+        nulls = None
+        if hasattr(conn, "generate_nulls"):  # stored tables carry nulls
+            nmap = conn.generate_nulls(node.table, node.columns, start,
+                                       count)
+            nulls = [nmap[c] for c in node.columns]
+        t_read.bytes = _host_bytes(arrays, nulls)
+    return arrays, nulls
+
+
 def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
-                     count: int, capacity: int) -> Batch:
+                     count: int, capacity: int, predicate=None) -> Batch:
     """Stage one scan split honoring the node's narrow-width annotation
     (plan/widths.py): host columns generate, the staging-time range
     guard re-proves each narrowed lane against the actual values, and
     the batch stages at the narrowed physical dtypes -- the shared
-    staging path of the runner and the streaming executor. Falls back
-    to the connector's own generate_batch when the node carries no
-    width annotation (or the connector can't produce host columns).
+    staging path of the runner and the streaming executor. `predicate`
+    is the scan's pushed-down range, for a connector that prunes by
+    statistics. Falls back to the connector's own generate_batch when
+    the node carries no width annotation (or the connector can't
+    produce host columns), unless the connector reads files.
 
     Every path records its data-path hops (exec/datapath.py):
-    connector_read (host column materialization), narrow_cast (the
-    staging-time range re-proof), device_put (host -> HBM staging,
-    the bytes QueryStats' staging stage counts)."""
+    connector_read (host column materialization), decode (a file's
+    arrow arrays to lanes), narrow_cast (the staging-time range
+    re-proof), device_put (host -> HBM staging, the bytes QueryStats'
+    staging stage counts)."""
     from .datapath import timed_hop
     from .memory import batch_bytes
     phys = getattr(node, "physical_dtypes", None)
-    if not phys or not any(phys) or not hasattr(conn, "generate_columns"):
+    if not hasattr(conn, "read_columns") and (
+            not phys or not any(phys)
+            or not hasattr(conn, "generate_columns")):
         # the connector stages straight to a device batch: the whole
         # read+put attributes to connector_read (coarse by design --
         # connectors wanting finer hops expose generate_columns)
@@ -110,23 +144,16 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
                                     capacity=capacity)
             t_read.bytes = batch_bytes(b)
         return b
-    from ..plan.widths import checked_physical_dtypes
-    with timed_hop("connector_read") as t_read:
-        data = conn.generate_columns(node.table, sf, node.columns,
-                                     start, count)
-        arrays = [data[c] for c in node.columns]
-        nulls = None
-        if hasattr(conn, "generate_nulls"):
-            nmap = conn.generate_nulls(node.table, node.columns, start,
-                                       count)
-            nulls = [nmap[c] for c in node.columns]
-        t_read.bytes = _host_bytes(arrays, nulls)
-    with timed_hop("narrow_cast", t_read.bytes):
-        checked = checked_physical_dtypes(phys, node.column_types, arrays,
-                                          nulls=nulls)
+    arrays, nulls = _read_split(conn, node, sf, start, count, predicate)
+    if phys and any(phys):
+        from ..plan.widths import checked_physical_dtypes
+        with timed_hop("narrow_cast", _host_bytes(arrays, nulls)):
+            phys = checked_physical_dtypes(phys, node.column_types, arrays,
+                                           nulls=nulls)
     with timed_hop("device_put") as t_put:
         b = batch_from_numpy(node.column_types, arrays, nulls=nulls,
-                             capacity=capacity, physical_dtypes=checked)
+                             capacity=capacity,
+                             physical_dtypes=phys or None)
         # sync so the measured wall is the transfer, not the async
         # dispatch returning early (bench.py learned this on the
         # chip). The staging loop is synchronous today (stage ->
@@ -181,26 +208,20 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         # win here is smaller staged shapes)
         from .datapath import timed_hop
         from .dynfilter import apply_dynamic_filters
-        with timed_hop("connector_read") as t_read:
-            data = conn.generate_columns(node.table, sf, node.columns,
-                                         start, count)
-            t_read.bytes = _host_bytes(list(data.values()))
-        keep, pruned = apply_dynamic_filters(data, node.columns,
-                                             dyn_filters)
+        arrays, nulls = _read_split(conn, node, sf, start, count)
+        keep, pruned = apply_dynamic_filters(
+            dict(zip(node.columns, arrays)), node.columns, dyn_filters)
         if stats is not None:
             stats.add("dynamic_filter_rows_pruned", pruned)
             stats.add("dynamic_filter_rows_staged", len(keep) - pruned)
         if not pruned:
             keep = slice(None)  # every row stays: nothing to copy out
-        arrays = [data[c][keep] for c in node.columns]
+        arrays = [a[keep] for a in arrays]
         tys = node.column_types
         nrows = len(arrays[0])
         cap = max(-(-nrows // pad_multiple) * pad_multiple, pad_multiple)
-        nulls = None
-        if hasattr(conn, "generate_nulls"):  # stored tables carry nulls
-            nmap = conn.generate_nulls(node.table, node.columns,
-                                       start, count)
-            nulls = [nmap[c][keep] for c in node.columns]
+        if nulls is not None:
+            nulls = [n[keep] for n in nulls]
         phys = getattr(node, "physical_dtypes", None)
         if phys and any(phys):
             from ..plan.widths import checked_physical_dtypes
@@ -216,23 +237,12 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         return b
     cap = capacity_hint or max(-(-count // pad_multiple) * pad_multiple,
                                pad_multiple)
-    if node.pushdown is not None and scan_range is None \
-            and hasattr(conn, "row_groups_matching"):
-        # connector statistics pruning: skip row groups the pushed-down
-        # range provably excludes (the exact Filter still runs above).
-        # Coarse datapath attribution like stage_scan_split's fallback:
-        # the connector stages straight to device, so the whole
-        # read+put attributes to connector_read (the ledger must never
-        # show zero bytes for a staged scan)
-        from .datapath import timed_hop
-        from .memory import batch_bytes
-        with timed_hop("connector_read") as t_read:
-            b = conn.generate_batch(node.table, sf, node.columns,
-                                    start=start, count=count, capacity=cap,
-                                    predicate=tuple(node.pushdown))
-            t_read.bytes = batch_bytes(b)
-        return b
-    return stage_scan_split(conn, node, sf, start, count, cap)
+    # connector statistics pruning: a file scan skips the row groups the
+    # pushed-down range provably excludes (the exact Filter still runs
+    # above) and stages what is left like any other split
+    predicate = tuple(node.pushdown) \
+        if node.pushdown is not None and scan_range is None else None
+    return stage_scan_split(conn, node, sf, start, count, cap, predicate)
 
 
 def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
@@ -1384,10 +1394,13 @@ def _run_write_root(node: N.PlanNode, **kw) -> QueryResult:
         src = src.source
     if isinstance(src, N.TableWriterNode):
         # single-process (local/mesh) write: stage + atomic publish
-        h = mod.begin_insert(
-            finish.table,
-            create_columns=finish.create_columns if finish.create else None,
-            create_types=finish.create_types if finish.create else None)
+        created = {}
+        if finish.create:
+            created = {"create_columns": finish.create_columns,
+                       "create_types": finish.create_types}
+            if finish.create_properties:  # only a catalog that has any
+                created["properties"] = finish.create_properties
+        h = mod.begin_insert(finish.table, **created)
         try:
             return _count_result(_write_pages(mod, h, src, kw))
         except BaseException:
@@ -1447,6 +1460,8 @@ def _write_pages(mod, handle: str, writer: N.TableWriterNode, kw) -> int:
     case of one page. Each page's host side is a ``write`` stage with
     a ``write.page`` span under it, the last one's with the publish
     (``write.publish``) too; the SELECT's own stages are their siblings.
+    A page is let go before the next is computed: a sink that writes
+    as it goes (a lake file) leaves the host one page at a time.
     Nothing is visible to a reader before ``finish_insert``; the
     caller aborts the handle if any page raises."""
     select = N.OutputNode(writer.source, writer.column_names)
@@ -1467,7 +1482,10 @@ def _write_pages(mod, handle: str, writer: N.TableWriterNode, kw) -> int:
         del res
     note("write_pages", len(pages))
     note("write_rows", rows)
-    note("write_bytes", nbytes)
+    # what the sink holds of the table where it can say (a lake file's
+    # size), else the bytes of the pages handed to it
+    stored = getattr(mod, "stored_bytes", None)
+    note("write_bytes", stored(writer.table) if stored else nbytes)
     return published
 
 

@@ -21,7 +21,7 @@ Differences from the reference, by design:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 from .. import types as T
 from ..expr import ir as E
@@ -496,13 +496,16 @@ class TableFinishNode(PlanNode):
     """Commit point (spi/plan/TableFinishNode analog): sums the
     per-task written-row counts and atomically publishes the staged
     insert (ConnectorMetadata.finishInsert / finishCreateTable).
-    `create_*` carry CTAS table metadata."""
+    `create_*` carry CTAS table metadata (`create_properties` the
+    WITH (...) of the statement as its catalog checked them)."""
     source: PlanNode
     connector: str
     table: str
     create: bool = False
     create_columns: List[str] = dataclasses.field(default_factory=list)
     create_types: List[T.Type] = dataclasses.field(default_factory=list)
+    create_properties: Dict[str, str] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def sources(self):
@@ -680,7 +683,8 @@ def to_json(n: PlanNode) -> dict:
         return {**base, "@type": "tablefinish", "source": to_json(n.source),
                 "connector": n.connector, "table": n.table,
                 "create": n.create, "createColumns": n.create_columns,
-                "createTypes": [str(t) for t in n.create_types]}
+                "createTypes": [str(t) for t in n.create_types],
+                "createProperties": n.create_properties}
     if isinstance(n, OutputNode):
         return {**base, "@type": "output", "source": to_json(n.source),
                 "names": n.names}
@@ -778,7 +782,7 @@ def from_json(j: dict) -> PlanNode:
                                j["table"], j["create"],
                                j["createColumns"],
                                [T.parse_type(s) for s in j["createTypes"]],
-                               **kw)
+                               j.get("createProperties") or {}, **kw)
     if t == "output":
         return OutputNode(from_json(j["source"]), j["names"], **kw)
     raise ValueError(f"unknown plan node {t!r}")

@@ -161,28 +161,12 @@ def annotate_widths(root: N.PlanNode, sf: float, _memo=None) -> N.PlanNode:
     if replaced:
         root = dataclasses.replace(root, **replaced)
 
-    if isinstance(root, N.TableScanNode) and root.physical_dtypes is None \
-            and not _pushdown_bypasses_staging(root):
+    if isinstance(root, N.TableScanNode) and root.physical_dtypes is None:
         widths = infer_scan_widths(root, sf)
         if widths is not None:
             root = dataclasses.replace(root, physical_dtypes=widths)
     _memo[orig] = root
     return root
-
-
-def _pushdown_bypasses_staging(node: N.TableScanNode) -> bool:
-    """A scan with connector predicate pushdown stages through the
-    connector's own row-group reader (exec/runner._scan_batch), which
-    bypasses the narrowed staging path -- don't annotate what staging
-    would ignore (the annotation would render in EXPLAIN and then
-    silently not happen)."""
-    if node.pushdown is None:
-        return False
-    from ..connectors import catalog
-    try:
-        return hasattr(catalog(node.connector), "row_groups_matching")
-    except KeyError:
-        return False
 
 
 def checked_physical_dtypes(phys: Sequence[Optional[str]],

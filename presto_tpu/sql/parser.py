@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 __all__ = ["parse_sql", "Query", "Select", "TableRef", "Join", "OrderItem",
            "Literal", "Name", "Func", "BinOp", "NotOp", "Between", "InList",
@@ -221,6 +221,8 @@ class CreateTableAs:
     table: str
     query: object
     if_not_exists: bool = False
+    # WITH (name = value, ...): names lower-cased, values as written
+    properties: Dict[str, str] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -963,8 +965,8 @@ def parse_sql(text: str):
 
 
 def _parse_dml(p: "_Parser", first: str):
-    """INSERT INTO / CREATE TABLE [IF NOT EXISTS] t AS / DROP TABLE
-    [IF EXISTS] t. The write verbs are contextual identifiers (like the
+    """INSERT INTO / CREATE TABLE [IF NOT EXISTS] t [WITH (k = v, ...)]
+    AS / DROP TABLE [IF EXISTS] t. The write verbs are contextual identifiers (like the
     reference's nonReserved words), matched case-insensitively."""
 
     def ctx(word):
@@ -1024,12 +1026,26 @@ def _parse_dml(p: "_Parser", first: str):
             p.expect_kw("exists")
             if_not_exists = True
         table = qualified_name()
+        properties = {}
+        if p.accept_kw("with"):
+            p.expect_op("(")
+            while True:
+                name = p.expect_ident().lower()
+                p.expect_op("=")
+                k, v = p.next()
+                if k not in ("string", "ident", "number"):
+                    raise ValueError(f"table property {name!r} needs a "
+                                     f"literal value, got {(k, v)}")
+                properties[name] = str(v)
+                if not p.accept_op(","):
+                    break
+            p.expect_op(")")
         p.expect_kw("as")
         q = p.query()
         k, _ = p.peek()
         if k != "eof":
             raise ValueError(f"trailing tokens at {p.peek()}")
-        return CreateTableAs(table, q, if_not_exists)
+        return CreateTableAs(table, q, if_not_exists, properties)
     if first == "delete":
         p.expect_kw("from")
         table = qualified_name()

@@ -763,8 +763,9 @@ def plan_sql(query_text: str, max_groups: int = 1 << 16,
 def _writable_target(name: str):
     """'memory.t' or bare 't' -> (connector, table). Writable catalogs
     expose the sink contract (begin_insert/...; ConnectorPageSink
-    analog): memory and parquet; the generator connectors stay
-    read-only, like the reference's tpch/tpcds connectors."""
+    analog): memory and the lake catalogs (hive, parquet, orc); the
+    generator connectors stay read-only, like the reference's
+    tpch/tpcds connectors."""
     if "." in name:
         conn, table = name.split(".", 1)
     else:
@@ -777,7 +778,7 @@ def _writable_target(name: str):
     if not writable:
         raise NotImplementedError(
             f"catalog {conn!r} is read-only; writes go to the memory "
-            "or parquet connectors")
+            "or hive (parquet, orc) catalogs")
     return conn, table
 
 
@@ -845,6 +846,12 @@ def _plan_write(ast, max_groups: int, join_capacity):
 
     if isinstance(ast, P.CreateTableAs):
         conn, table = _writable_target(ast.table)
+        # WITH (...): the catalog says what it takes (hive: format)
+        check = getattr(get_catalog(conn), "table_properties", None)
+        if check is None and ast.properties:
+            raise ValueError(f"catalog {conn!r} takes no table properties "
+                             f"(WITH {sorted(ast.properties)})")
+        properties = check(dict(ast.properties)) if check else {}
         if ast.if_not_exists and table in get_catalog(conn).SCHEMA:
             # no-op create: zero rows written (reference behavior)
             return N.OutputNode(N.ValuesNode([T.BIGINT], [[0]]), ["rows"])
@@ -854,7 +861,8 @@ def _plan_write(ast, max_groups: int, join_capacity):
         writer = N.TableWriterNode(node, conn, table, list(names))
         finish = N.TableFinishNode(writer, conn, table, create=True,
                                    create_columns=list(names),
-                                   create_types=list(types))
+                                   create_types=list(types),
+                                   create_properties=properties)
         return N.OutputNode(finish, ["rows"])
 
     # INSERT

@@ -41,21 +41,23 @@ def test_corpus_query_over_parquet_matches_generator(lineitem_file):
 
 
 def test_rowgroup_pruning_measured(lineitem_file):
-    pq_conn.read_stats.update(groups_total=0, groups_read=0)
-    n = sql("SELECT count(*) FROM parquet.pq_lineitem "
-            "WHERE orderkey < 1000", sf=0.01).rows()[0][0]
+    res = sql("SELECT count(*) FROM parquet.pq_lineitem "
+              "WHERE orderkey < 1000", sf=0.01)
     want = sql("SELECT count(*) FROM lineitem WHERE orderkey < 1000",
                sf=0.01).rows()[0][0]
-    assert n == want
-    st = dict(pq_conn.read_stats)
+    assert res.rows()[0][0] == want
+    st = res.query_stats.counters
     # orderkey is sorted in dbgen order: most row groups prune away
-    assert st["groups_total"] > 0
-    assert st["groups_read"] < st["groups_total"], st
-    # pushdown never changes results: same query, pushdown off
-    n2 = sql("SELECT count(*) FROM parquet.pq_lineitem "
-             "WHERE orderkey < 1000", sf=0.01,
-             session={"scan_predicate_pushdown": False}).rows()[0][0]
-    assert n2 == want
+    assert st["lake_row_groups_total"] > 0
+    assert st["lake_row_groups_read"] < st["lake_row_groups_total"], st
+    # pushdown never changes results: same query, pushdown off, and
+    # the statement's own counters say every group was read
+    off = sql("SELECT count(*) FROM parquet.pq_lineitem "
+              "WHERE orderkey < 1000", sf=0.01,
+              session={"scan_predicate_pushdown": False})
+    assert off.rows()[0][0] == want
+    st = off.query_stats.counters
+    assert st["lake_row_groups_read"] == st["lake_row_groups_total"] > 0
 
 
 def test_ctas_insert_roundtrip(tmp_path):

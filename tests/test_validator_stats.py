@@ -17,15 +17,15 @@ def test_validate_clean_plan():
 
 
 def test_validate_rejects_unknown_function_and_connector():
-    scan = TableScanNode("hive", "t", ["x"], [T.BIGINT])
+    scan = TableScanNode("kudu", "t", ["x"], [T.BIGINT])
     f = FilterNode(scan, call("no_such_fn", T.BOOLEAN, input_ref(0, T.BIGINT)))
     v = validate_plan(OutputNode(f, ["x"]))
     assert any("no_such_fn" in s for s in v)
-    assert any("hive" in s for s in v)
+    assert any("kudu" in s for s in v)
 
 
 def test_run_query_rejects_invalid_plan():
-    scan = TableScanNode("hive", "t", ["x"], [T.BIGINT])
+    scan = TableScanNode("kudu", "t", ["x"], [T.BIGINT])
     with pytest.raises(ValueError, match="PlanChecker"):
         run_query(OutputNode(scan, ["x"]))
 
