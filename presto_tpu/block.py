@@ -32,6 +32,7 @@ sharding annotations apply leaf-wise.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -42,7 +43,7 @@ from . import types as T
 
 __all__ = ["Column", "StringColumn", "DictionaryColumn", "Int128Column",
            "Batch", "Block", "HostStrings", "from_numpy", "to_numpy",
-           "concat_batches"]
+           "BatchBuilder", "concat_batches"]
 
 
 def _register(cls, data_fields, meta_fields):
@@ -565,6 +566,70 @@ def batch_from_numpy(types: Sequence[T.Type], arrays: Sequence[np.ndarray],
     active = np.zeros(capacity, dtype=bool)
     active[:n] = True
     return Batch(cols, jnp.asarray(active))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _blank_lanes(rows: int, dtypes):
+    return tuple(jnp.zeros(rows, dtype=dt) for dt in dtypes)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _land_piece(lanes, piece, at):
+    return tuple(jax.lax.dynamic_update_slice(lane, part, (at,))
+                 for lane, part in zip(lanes, piece))
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _close_lanes(lanes, rows, capacity: int):
+    """The first `capacity` rows of every lane, the rows from `rows` on
+    as padding (values 0, masks True), and the `active` mask."""
+    live = jnp.arange(capacity) < rows
+    k = len(lanes) // 2
+    values = tuple(jnp.where(live, v[:capacity], jnp.zeros((), v.dtype))
+                   for v in lanes[:k])
+    nulls = tuple(jnp.where(live, m[:capacity], True) for m in lanes[k:])
+    return values, nulls, live
+
+
+class BatchBuilder:
+    """A Batch of plain `Column`s assembled on the device from pieces of
+    its rows put one at a time: what `batch_from_numpy` gives for the
+    pieces' concatenation (same capacity, dtypes, row order and masks),
+    with the put of one piece running while its caller prepares the
+    next. A piece lands in lanes allocated once, by a donated
+    `dynamic_update_slice`. A piece's arrays may be longer than the rows
+    it holds (the next piece overwrites the rest), so that a caller can
+    keep to a few array lengths, each of which compiles once, whatever
+    rows its pieces hold; `room` is the longest such array, and what the
+    lanes keep past `capacity` for the last piece to land in."""
+
+    def __init__(self, types: Sequence[T.Type], dtypes, capacity: int,
+                 room: int):
+        self.types, self.capacity = tuple(types), capacity
+        self.rows = 0
+        self._lanes = _blank_lanes(
+            capacity + room,
+            tuple(np.dtype(dt) for dt in dtypes)
+            + (np.dtype(bool),) * len(self.types))
+
+    def put(self, values: Sequence[np.ndarray], nulls: Sequence[np.ndarray],
+            rows: int) -> int:
+        """Put one piece (a lane and a mask a column, equally long, the
+        piece's `rows` rows first); returns the bytes handed over."""
+        piece = jax.device_put((*values, *nulls))
+        self._lanes = _land_piece(self._lanes, piece, self.rows)
+        self.rows += rows
+        return sum(a.nbytes for a in piece)
+
+    def finish(self) -> Batch:
+        if self.rows > self.capacity:
+            raise ValueError(f"{self.rows} rows put into a batch of "
+                             f"capacity {self.capacity}")
+        values, nulls, active = _close_lanes(self._lanes, self.rows,
+                                             self.capacity)
+        self._lanes = None
+        return Batch(tuple(Column(v, n, ty) for v, n, ty
+                           in zip(values, nulls, self.types)), active)
 
 
 def to_numpy(block: Block) -> Tuple[np.ndarray, np.ndarray]:

@@ -57,6 +57,12 @@ class _Hive:
                                                        **kwargs)
         return per_table
 
+    def scan_pieces(self, table, *args, **kwargs):
+        """The holding module's producer of decoded pieces; None for a
+        format that offers none (ORC: its scans assemble on the host)."""
+        offered = getattr(self._holding(table), "scan_pieces", None)
+        return offered and offered(table, *args, **kwargs)
+
     def table_properties(self, given: dict) -> dict:
         """The properties of a CREATE TABLE as the sink takes them: an
         unknown property or format is the statement's error."""

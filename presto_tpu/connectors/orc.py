@@ -15,8 +15,12 @@ statistics, so ORC scans do not prune stripes by predicate the way the
 parquet connector (and the reference's selective reader) does, and
 `column_range` proves nothing (a scan stages its logical widths); range
 splits and column pruning still apply. The conversion layer
-(engine_to_arrow / arrow_to_engine / assemble) is shared with parquet:
-one decode a scan, no Python object per value."""
+(engine_to_arrow / arrow_to_engine) and the producer of decoded pieces
+(PieceScan, with stripes as pieces, and its host-side consumer) are
+shared with parquet: one decode a scan, no Python object per value.
+Without stripe metadata the stripes a range needs are known only as
+they are read, one after another on the scan's thread, so this module
+offers no `scan_pieces` and its scans assemble on the host."""
 
 from __future__ import annotations
 
@@ -27,8 +31,8 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import types as T
-from .parquet import (_engine_type, arrow_schema, columns_batch,
-                      decode_scan, engine_to_arrow)
+from .parquet import (PieceScan, _engine_type, arrow_schema, columns_batch,
+                      engine_to_arrow, host_columns)
 
 __all__ = ["SCHEMA", "register_table", "unregister_table", "reset",
            "table_row_count", "read_columns", "generate_columns",
@@ -147,7 +151,9 @@ def read_columns(table: str, columns: Sequence[str], start: int = 0,
             pieces.append(t.slice(lo, min(start + count - g_lo,
                                           t.num_rows) - lo))
         t_read.bytes = sum(t.nbytes for t in pieces)
-    return decode_scan(pieces, schema, columns, len(pieces), t_read.bytes)
+    return host_columns(PieceScan(
+        [(t, 0, t.num_rows, t.num_rows) for t in pieces], None, schema,
+        columns, touched=len(pieces), file_bytes=t_read.bytes))
 
 
 def generate_columns(table: str, sf: float, columns: Sequence[str],

@@ -8,17 +8,21 @@ straight into the SAME columnar batches every other connector produces,
 so the whole engine -- stats, dynamic filtering, adaptive capacities,
 mesh sharding -- runs unchanged over files.
 
-A scan is one call, `read_columns`: the row groups its row range
-touches are its splits, those whose footer statistics exclude the
+A scan is one producer, `scan_pieces`: the row groups its row range
+touches are its pieces, those whose footer statistics exclude the
 pushed-down range are skipped, and the rest are read (file bytes ->
 arrow, hop ``connector_read``) and decoded (arrow -> the engine's lanes
-and null masks, hop ``decode``) once, on a small thread pool, each
-group into its own slice of the output. Nothing on the way makes a
-Python object per value: a short decimal is its int64 lane, a date its
-int32, a string column a `block.HostStrings` built from arrow's offsets
-and bytes. `column_range` answers from the footers' min/max, so
-plan/widths.py narrows a file scan's lanes as it narrows a memory
-table's.
+and null masks, hop ``decode``) once, a row group a task on a small
+thread pool, and handed over in file order while later groups are
+still being read. Who consumes them decides where the lanes are
+assembled: `read_columns` on the host, exec/runner's stager on the
+device, a group's lanes decoded straight into the narrowed dtypes the
+plan proved (plan/widths.py) and put while the next groups decompress.
+Nothing on the way makes a Python object per value: a short decimal is
+its int64 lane, a date its int32, a string column a `block.HostStrings`
+built from arrow's offsets and bytes. `column_range` answers from the
+footers' min/max, so plan/widths.py narrows a file scan's lanes as it
+narrows a memory table's.
 
 The writer (`engine_to_arrow`, `open_writer`; the staged commit is the
 shared `lake_sink.LakeSink`) is the same conversions the other way, a
@@ -34,18 +38,24 @@ varchar)."""
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import threading
+import time
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .. import types as T
 from ..block import HostStrings, batch_from_numpy
 
 __all__ = ["SCHEMA", "register_table", "unregister_table", "reset",
-           "table_row_count", "read_columns", "generate_columns",
+           "table_row_count", "scan_pieces", "read_columns",
+           "generate_columns",
            "generate_nulls", "generate_batch", "column_type",
            "column_range", "write_table", "row_groups_matching"]
 
@@ -428,78 +438,217 @@ def engine_to_arrow(columns: Dict[str, np.ndarray],
 
 _pool_lock = threading.Lock()
 _pool = None
+# what a scan may hold of pieces read and not yet taken by its consumer
+_IN_FLIGHT_BYTES = 1 << 30
+
+
+def _pool_width() -> int:
+    # past eight threads a scan's groups cost more thread-seconds each
+    # and the consumer's thread wants a core too (PERF.md, PR 33: on 13
+    # cores a scan took 464 ms on 8 threads, 552 on 10, 525 on 12)
+    return min(8, os.cpu_count() or 1)
 
 
 def _decode_pool():
-    """The few threads a scan's row groups decode on (pyarrow and
-    numpy's copies release the GIL)."""
+    """The threads every scan's pieces are read and decoded on (pyarrow
+    and numpy's passes over a piece release the GIL)."""
     global _pool
     with _pool_lock:
         if _pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-            _pool = ThreadPoolExecutor(
-                max_workers=min(8, os.cpu_count() or 1),
-                thread_name_prefix="lake-decode")
+            _pool = ThreadPoolExecutor(max_workers=_pool_width(),
+                                       thread_name_prefix="lake-decode")
         return _pool
+
+
+class Piece:
+    """One row group or stripe of a scan, decoded: `values` and `nulls`
+    by column over its `rows` rows (a mask is None where no row is
+    null); for a scan that names its lanes' dtypes, a lane and a mask
+    each of `whole` rows, zero past `rows`. `refused`: a value did not
+    fit the dtype named for its lane, and the piece holds nothing."""
+
+    __slots__ = ("rows", "values", "nulls", "refused", "read_at",
+                 "decode_at")
+
+    def __init__(self, rows: int):
+        self.rows = rows
+        self.values, self.nulls = {}, {}
+        self.refused = False
+        self.read_at = self.decode_at = None
+
+
+class PieceScan:
+    """The one producer of a lake scan's decoded pieces. Iterating it
+    reads and decodes each piece on the decode pool and yields them in
+    file order, at most `depth` of them submitted and not yet taken;
+    who iterates decides where the lanes are assembled: `host_columns`
+    into whole host lanes, exec/runner's stager on the device.
+
+    `sources` are `(source, first, rows, whole)`: `read(source)` gives
+    the arrow table of a piece of `whole` rows, of which the scan takes
+    `rows` from `first`; with no `read` a source is its table. With
+    `dtypes` (one a column: the lane's dtype) a column decodes straight
+    into a fresh lane of that dtype and of `whole` rows, proved against
+    it while the piece is cache-resident (plan/widths.lane_holds), and
+    into a mask of its own; without, into the logical lanes
+    `arrow_to_engine` gives. `room` is the most rows any piece of the
+    file holds, whatever this scan picked: a consumer that lands such
+    lanes one after another leaves that much past its last row.
+
+    Pool threads have no ambient collector: a piece carries the clock
+    readings of its read and its decode, and `record`, called by the
+    consumer on the statement's thread, records each hop once, from its
+    first piece's entry to its last piece's exit."""
+
+    def __init__(self, sources, read, schema: Dict[str, T.Type],
+                 columns: Sequence[str], touched: int, file_bytes: int,
+                 piece_bytes: int = 0, dtypes=None, room: int = 0):
+        self.sources = list(sources)
+        self.schema, self.columns = schema, list(columns)
+        self.touched, self.file_bytes = touched, file_bytes
+        self.dtypes = None if dtypes is None else \
+            [np.dtype(dt) for dt in dtypes]
+        self.rows = sum(src[2] for src in self.sources)
+        self.room = room
+        self.depth = max(2, min(2 * _pool_width(),
+                                _IN_FLIGHT_BYTES // max(piece_bytes, 1)))
+        self._read = read
+        self._read_at = self._decode_at = None
+
+    def _piece(self, src) -> Piece:
+        source, first, rows, whole = src
+        piece = Piece(rows)
+        if self._read is None:
+            tbl = source
+        else:
+            t0 = time.time()
+            with TraceAnnotation("presto:connector_read"):
+                tbl = self._read(source)
+            piece.read_at = (t0, time.time())
+        if rows != tbl.num_rows:
+            tbl = tbl.slice(first, rows)
+        t0 = time.time()
+        with TraceAnnotation("presto:decode"):
+            for k, c in enumerate(self.columns):
+                col = tbl.column(c)
+                arr = col.chunk(0) if col.num_chunks == 1 \
+                    else col.combine_chunks()
+                vals, nl = arrow_to_engine(arr, self.schema[c])
+                if self.dtypes is not None:
+                    vals, nl = _into_lane(vals, nl, self.dtypes[k], whole)
+                    if vals is None:
+                        piece.refused = True
+                        break
+                piece.values[c], piece.nulls[c] = vals, nl
+        piece.decode_at = (t0, time.time())
+        return piece
+
+    def __iter__(self):
+        pool, todo = _decode_pool(), iter(self.sources)
+        pending = collections.deque(
+            pool.submit(self._piece, src)
+            for src in itertools.islice(todo, self.depth))
+        try:
+            while pending:
+                piece = pending.popleft().result()
+                src = next(todo, None)
+                if src is not None:
+                    pending.append(pool.submit(self._piece, src))
+                self._read_at = _span_of(self._read_at, piece.read_at)
+                self._decode_at = _span_of(self._decode_at, piece.decode_at)
+                yield piece
+        finally:
+            # an error, or a consumer that stopped: nothing of this scan
+            # stays on the pool
+            for f in pending:
+                f.cancel()
+            wait(pending)
+
+    def record(self, decoded_bytes: int, pipelined: bool,
+               decode_end: float = 0.0) -> None:
+        """The scan's hops and the statement's ``lake_*`` counters, once
+        its pieces are all taken. `decoded_bytes`: the lanes and masks
+        the consumer got; `pipelined`: they went to the device piece by
+        piece; `decode_end`: where a consumer's own assembly of the
+        decoded pieces ended, if after the last piece's decode."""
+        from ..exec.datapath import hop_interval
+        from ..exec.stats import note
+        now = time.time()
+        if self._read is not None:
+            hop_interval("connector_read", self.file_bytes,
+                         *(self._read_at or (now, now)))
+        t0, t1 = self._decode_at or (now, now)
+        hop_interval("decode", decoded_bytes, t0, max(t1, decode_end))
+        note("lake_row_groups_total", self.touched)
+        note("lake_row_groups_read", len(self.sources))
+        note("lake_row_groups_pipelined",
+             len(self.sources) if pipelined else 0)
+        note("lake_file_bytes", self.file_bytes)
+        note("lake_decoded_bytes", decoded_bytes)
+
+
+def _span_of(so_far, at):
+    """The interval from the earliest entry to the latest exit."""
+    if so_far is None or at is None:
+        return so_far or at
+    return min(so_far[0], at[0]), max(so_far[1], at[1])
+
+
+def _into_lane(vals: np.ndarray, nl: Optional[np.ndarray], dt: np.dtype,
+               whole: int):
+    """A piece's decoded column as a fresh lane of `dt` and a mask, each
+    of `whole` rows; (None, None) where a value does not fit `dt`. A
+    null row's value is 0 (`arrow_to_engine`), which every lane holds,
+    so the proof over all rows is the proof over the rows that are not
+    null."""
+    from ..plan.widths import lane_holds
+    if dt != vals.dtype and not lane_holds(dt, vals):
+        return None, None
+    n = len(vals)
+    lane = np.empty(whole, dtype=dt)
+    lane[:n] = vals
+    lane[n:] = 0
+    mask = np.zeros(whole, dtype=bool)
+    if nl is not None:
+        mask[:n] = nl
+    return lane, mask
 
 
 def _empty_lane(ty: T.Type, n: int):
     if ty.is_string:
-        return None  # its groups are concatenated: widths differ
+        return None  # its pieces are concatenated: widths differ
     if ty.is_decimal and not ty.is_short_decimal:
         return np.zeros(n, dtype=object)
     return np.empty(n, dtype=ty.to_dtype())
 
 
-def assemble(pieces, schema: Dict[str, T.Type], columns: Sequence[str],
-             pool) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
-    """Arrow tables (one a row group or stripe, in row order) -> the
-    engine's columns and null masks: each piece decoded once, on
-    `pool`, into its own slice of lanes allocated whole."""
-    starts = np.concatenate([[0], np.cumsum([t.num_rows for t in pieces])]
-                            ).astype(np.int64)
-    total = int(starts[-1])
-    values = {c: _empty_lane(schema[c], total) for c in columns}
-    nulls = {c: np.zeros(total, dtype=bool) for c in columns}
-    strings = {c: [None] * len(pieces) for c in columns
-               if values[c] is None}
-
-    def decode(k: int) -> None:
-        lo, hi = int(starts[k]), int(starts[k + 1])
+def host_columns(scan: PieceScan
+                 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """The producer's host-side consumer: whole lanes and null masks,
+    each piece copied into its slice as it arrives while later pieces
+    are still being read (shared with the ORC module). For the callers
+    that need a scan's columns on the host: a dynamic-filtered scan,
+    string columns, `generate_columns`."""
+    schema, columns = scan.schema, scan.columns
+    values = {c: _empty_lane(schema[c], scan.rows) for c in columns}
+    nulls = {c: np.zeros(scan.rows, dtype=bool) for c in columns}
+    strings = {c: [] for c in columns if values[c] is None}
+    at = 0
+    for piece in scan:
+        rows = slice(at, at + piece.rows)
         for c in columns:
-            col = pieces[k].column(c)
-            arr = col.chunk(0) if col.num_chunks == 1 \
-                else col.combine_chunks()
-            vals, nl = arrow_to_engine(arr, schema[c])
-            if nl is not None:
-                nulls[c][lo:hi] = nl
+            if piece.nulls[c] is not None:
+                nulls[c][rows] = piece.nulls[c]
             if c in strings:
-                strings[c][k] = vals
+                strings[c].append(piece.values[c])
             else:
-                values[c][lo:hi] = vals
-
-    list(pool.map(decode, range(len(pieces))))
+                values[c][rows] = piece.values[c]
+        at += piece.rows
     for c, parts in strings.items():
         values[c] = HostStrings.concat(parts)
-    return values, nulls
-
-
-def decode_scan(pieces, schema: Dict[str, T.Type], columns: Sequence[str],
-                touched: int, file_bytes: int
-                ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
-    """The second half of a lake scan, shared with the ORC module: the
-    ``decode`` hop over the pieces read, and the statement's counters
-    (`touched` row groups or stripes met, `len(pieces)` read)."""
-    from ..exec.datapath import timed_hop
-    from ..exec.stats import note
-    with timed_hop("decode") as t_decode:
-        values, nulls = assemble(pieces, schema, columns, _decode_pool())
-        t_decode.bytes = sum(v.nbytes for v in values.values()) + \
-            sum(n.nbytes for n in nulls.values())
-    note("lake_row_groups_total", touched)
-    note("lake_row_groups_read", len(pieces))
-    note("lake_file_bytes", file_bytes)
-    note("lake_decoded_bytes", t_decode.bytes)
+    scan.record(sum(v.nbytes for v in values.values())
+                + sum(n.nbytes for n in nulls.values()),
+                pipelined=False, decode_end=time.time())
     return values, nulls
 
 
@@ -513,46 +662,57 @@ def columns_batch(values, nulls, schema: Dict[str, T.Type],
                             nulls=[nulls[c] for c in columns])
 
 
-def read_columns(table: str, columns: Sequence[str], start: int = 0,
-                 count: Optional[int] = None, predicate=None
-                 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
-    """Rows [start, start+count) of `columns` as (values, null masks):
-    the one decode of a scan. Row groups the range does not touch, and
-    those `predicate` (column, lo, hi) excludes by their statistics,
-    are not opened: with a predicate the answer may hold fewer rows
-    than `count`. Records the hops ``connector_read`` and ``decode``
-    and the statement's ``lake_*`` counters."""
+def scan_pieces(table: str, columns: Sequence[str], start: int = 0,
+                count: Optional[int] = None, predicate=None,
+                dtypes=None) -> PieceScan:
+    """The producer over rows [start, start+count) of `columns`, a row
+    group a piece. Row groups the range does not touch, and those
+    `predicate` (column, lo, hi) excludes by their statistics, are not
+    opened: with a predicate the pieces may hold fewer rows than
+    `count`."""
     import pyarrow.parquet as pq
-    from ..exec.datapath import timed_hop
     with _lock:
         ent = _tables[table]
     path, md, schema = ent["path"], ent["pf"].metadata, ent["schema"]
     count = md.num_rows - start if count is None else count
     columns = list(columns)
     keep = set(row_groups_matching(table, predicate))
-    picked, touched, at = [], 0, 0  # picked: (group, first row, rows)
-    for g in range(md.num_row_groups):
-        g_rows = md.row_group(g).num_rows
+    group_rows = [md.row_group(g).num_rows
+                  for g in range(md.num_row_groups)]
+    picked, touched, at = [], 0, 0  # picked: (group, first, rows, whole)
+    for g, g_rows in enumerate(group_rows):
         lo, hi = max(start - at, 0), min(start + count - at, g_rows)
         at += g_rows
         if lo < hi:
             touched += 1
             if g in keep:
-                picked.append((g, lo, hi - lo))
+                picked.append((g, lo, hi - lo, g_rows))
     index = [ent["pf"].schema_arrow.get_field_index(c) for c in columns]
-    file_bytes = sum(md.row_group(g).column(ci).total_compressed_size
-                     for g, _lo, _n in picked for ci in index)
+    chunks = [[md.row_group(g).column(ci) for ci in index]
+              for g, _lo, _n, _whole in picked]
 
-    def read(pick):
-        g, lo, n = pick
+    def read(g: int):
         # a reader of its own: a ParquetFile is one file position
-        t = pq.ParquetFile(path, metadata=md).read_row_group(
+        return pq.ParquetFile(path, metadata=md).read_row_group(
             g, columns=columns, use_threads=False)
-        return t if n == t.num_rows else t.slice(lo, n)
 
-    with timed_hop("connector_read", file_bytes):
-        pieces = list(_decode_pool().map(read, picked))
-    return decode_scan(pieces, schema, columns, touched, file_bytes)
+    return PieceScan(
+        picked, read, schema, columns, touched,
+        file_bytes=sum(c.total_compressed_size for g in chunks for c in g),
+        piece_bytes=max((sum(c.total_uncompressed_size for c in g)
+                         for g in chunks), default=0),
+        dtypes=dtypes, room=max(group_rows, default=0))
+
+
+def read_columns(table: str, columns: Sequence[str], start: int = 0,
+                 count: Optional[int] = None, predicate=None
+                 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+    """Rows [start, start+count) of `columns` as (values, null masks)
+    on the host: the one decode of a scan whose columns are needed
+    there. Records the hops ``connector_read`` and ``decode`` and the
+    statement's ``lake_*`` counters."""
+    return host_columns(scan_pieces(table, columns, start, count,
+                                    predicate))
 
 
 def generate_columns(table: str, sf: float, columns: Sequence[str],

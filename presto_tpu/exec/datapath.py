@@ -53,13 +53,24 @@ of wall time -- exchange_fetch CONTAINS page decode, and both record):
                       a lake scan, file bytes -> arrow arrays (bytes
                       are the compressed column chunks read)
   decode              encoded -> engine-array decode: a lake scan's
-                      arrow arrays -> lanes and null masks, after its
-                      connector_read and beside nothing; SerializedPage
-                      payloads
+                      arrow arrays -> lanes and null masks (bytes are
+                      those lanes and masks: logical lanes where the
+                      scan assembles on the host, the narrowed lanes,
+                      their range proof included, where it is staged
+                      piece by piece); SerializedPage payloads
   narrow_cast         narrow-width staging-time range re-proof + cast
-  device_put          host -> HBM staging (batch_from_numpy); bytes
-                      equal the staged batch (what QueryStats'
+                      (a lake scan staged piece by piece records none:
+                      its proof is in `decode`, its cast is the decode)
+  device_put          host -> HBM staging (batch_from_numpy, or a lake
+                      scan's pieces put and assembled on the device);
+                      bytes equal the staged batch (what QueryStats'
                       staging stage counts, the 1% reconciliation)
+
+A lake scan's hops overlap: its pieces (row groups) are read and
+decoded on a thread pool while earlier ones are put, so each of its
+hops is one interval from its first piece's entry to its last piece's
+exit (`hop_interval`), and together they exceed the `staging` wall
+that encloses them.
   kernel              compiled-program dispatch wall over staged bytes
   exchange_serialize  SerializedPage production
   exchange_fetch      cross-worker page pull + decode + restage
@@ -79,7 +90,7 @@ from ..utils.locks import OrderedLock
 
 __all__ = ["HOPS", "CEILING_KEYS", "HOP_CEILING", "HopStats",
            "DatapathLedger", "recording", "record_hop", "timed_hop",
-           "now_us",
+           "hop_interval", "now_us",
            "merge_hop_maps", "hop_map_to_json", "hop_map_from_json",
            "probe_ceilings", "ceilings_cached", "achieved_b_per_s",
            "bottleneck_verdict", "datapath_doc", "merge_datapath_docs",
@@ -317,6 +328,18 @@ class timed_hop:
         self._span.__exit__(*exc)
         record_hop(self.hop, self.bytes, self._span.t1 - self._span.t0)
         return False
+
+
+def hop_interval(hop: str, nbytes: int, t0: float, t1: float) -> None:
+    """A hop whose work ran on other threads (a lake scan's pieces on
+    the decode pool, which have no ambient collector): recorded once,
+    on the statement's thread, from two readings of `time.time()` --
+    its first piece's entry and its last piece's exit. The same two
+    sinks as `timed_hop`; the profiler's trace holds the pieces' own
+    ``presto:<hop>`` events instead of one."""
+    from .stats import interval
+    interval(hop, t0, t1)
+    record_hop(hop, nbytes, t1 - t0)
 
 
 def note_query(query_id: str, hops: Dict[str, HopStats]) -> None:

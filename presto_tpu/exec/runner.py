@@ -88,10 +88,10 @@ def _read_split(conn, node: "N.TableScanNode", sf: float, start: int,
     """One split's host columns and null masks (None where the
     connector has no nulls), in `node.columns` order. A stored connector
     that offers `read_columns` is asked once for both (a file is opened
-    and decoded once; it records its own ``connector_read`` and
-    ``decode`` hops, and with a `predicate` may give fewer rows than
-    `count`); the others' two calls are views or generated columns,
-    timed here as ``connector_read``."""
+    and decoded once, its pieces assembled on the host; it records its
+    own ``connector_read`` and ``decode`` hops, and with a `predicate`
+    may give fewer rows than `count`); the others' two calls are views
+    or generated columns, timed here as ``connector_read``."""
     from .datapath import timed_hop
     one_call = getattr(conn, "read_columns", None)
     if one_call is not None:
@@ -112,17 +112,70 @@ def _read_split(conn, node: "N.TableScanNode", sf: float, start: int,
     return arrays, nulls
 
 
+def _plain_lanes(types) -> bool:
+    """Every column stages as one `Column` (a lane and a mask)."""
+    return all(ty.is_fixed_width
+               and not (ty.is_decimal and not ty.is_short_decimal)
+               for ty in types)
+
+
+def _stage_pieces(scan, types, capacity: int) -> Optional[Batch]:
+    """The device-side consumer of a lake scan's producer
+    (connectors/parquet.PieceScan): each row group's lanes and masks,
+    decoded at the batch's physical dtypes on the decode pool, are put
+    and land in the batch's columns on the device while later groups are
+    still being read and decoded. None where a group refused its
+    narrowing (a value the range the plan trusted does not cover): the
+    caller stages the scan whole, that column wide.
+
+    The three hops overlap inside ``staging``: ``connector_read`` and
+    ``decode`` run from their first group's entry to their last group's
+    exit on the pool (`PieceScan.record`), ``device_put`` from the first
+    group's put to the assembled batch being ready."""
+    from ..block import BatchBuilder
+    from .datapath import hop_interval
+    from .memory import batch_bytes
+    builder = BatchBuilder(types, scan.dtypes, capacity, scan.room)
+    decoded, put_at = 0, None
+    pieces = iter(scan)
+    try:
+        for piece in pieces:
+            if piece.refused:
+                return None
+            put_at = put_at or time.time()
+            with jax.profiler.TraceAnnotation("presto:device_put"):
+                decoded += builder.put(
+                    [piece.values[c] for c in scan.columns],
+                    [piece.nulls[c] for c in scan.columns], piece.rows)
+    finally:
+        pieces.close()  # nothing of a scan given up stays on the pool
+    b = jax.block_until_ready(builder.finish())
+    done = time.time()
+    hop_interval("device_put", batch_bytes(b), put_at or done, done)
+    scan.record(decoded, pipelined=True)
+    return b
+
+
 def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
                      count: int, capacity: int, predicate=None) -> Batch:
     """Stage one scan split honoring the node's narrow-width annotation
-    (plan/widths.py): host columns generate, the staging-time range
-    guard re-proves each narrowed lane against the actual values, and
-    the batch stages at the narrowed physical dtypes -- the shared
-    staging path of the runner and the streaming executor. `predicate`
-    is the scan's pushed-down range, for a connector that prunes by
-    statistics. Falls back to the connector's own generate_batch when
-    the node carries no width annotation (or the connector can't
-    produce host columns), unless the connector reads files.
+    (plan/widths.py) -- the shared staging path of the runner and the
+    streaming executor. `predicate` is the scan's pushed-down range, for
+    a connector that prunes by statistics. What the code observes picks
+    the form:
+
+    * a connector that offers a producer of decoded pieces
+      (`scan_pieces`: a lake file's row groups) and a scan of plain
+      fixed-width columns: staged piece by piece (`_stage_pieces`),
+      read, decode-to-narrow-lanes and put running as one pipeline;
+    * host columns otherwise (`read_columns` of a lake file with string
+      columns or a refused narrowing, `generate_columns` of the rest):
+      the staging-time range guard re-proves each narrowed lane against
+      the actual values, and the batch stages at the narrowed physical
+      dtypes in one `batch_from_numpy`;
+    * the connector's own `generate_batch` where the node carries no
+      width annotation (or the connector can't produce host columns)
+      and the connector reads no files.
 
     Every path records its data-path hops (exec/datapath.py):
     connector_read (host column materialization), decode (a file's
@@ -144,6 +197,16 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
                                     capacity=capacity)
             t_read.bytes = batch_bytes(b)
         return b
+    offered = getattr(conn, "scan_pieces", None)
+    if offered is not None and node.columns \
+            and _plain_lanes(node.column_types):
+        scan = offered(node.table, node.columns, start, count, predicate,
+                       dtypes=[dt or ty.to_dtype() for dt, ty in zip(
+                           phys or [None] * len(node.columns),
+                           node.column_types)])
+        b = scan and _stage_pieces(scan, node.column_types, capacity)
+        if b is not None:
+            return b
     arrays, nulls = _read_split(conn, node, sf, start, count, predicate)
     if phys and any(phys):
         from ..plan.widths import checked_physical_dtypes
@@ -155,12 +218,11 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
                              capacity=capacity,
                              physical_dtypes=phys or None)
         # sync so the measured wall is the transfer, not the async
-        # dispatch returning early (bench.py learned this on the
-        # chip). The staging loop is synchronous today (stage ->
-        # execute, ROADMAP item 3) and the caller host-reads
-        # b.active immediately after, so this adds no real
-        # serialization; item 3's producer/consumer pipeline will
-        # record this hop from its prefetch threads instead.
+        # dispatch returning early (bench.py learned this on the chip):
+        # the caller host-reads b.active right after, so this adds no
+        # serialization. Nothing overlaps on this path: host columns
+        # are whole before the first byte is put (a lake scan's pieces
+        # overlap in `_stage_pieces`).
         jax.block_until_ready(b)
         t_put.bytes = batch_bytes(b)
     return b

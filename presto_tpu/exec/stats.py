@@ -52,7 +52,8 @@ from .datapath import HopStats, hop_map_from_json, hop_map_to_json, \
 
 __all__ = ["RuntimeStats", "timed", "OperatorStats", "StageStats",
            "QueryStats", "StatsCollector", "current_collector",
-           "collecting", "joining", "stage", "span", "note", "note_max"]
+           "collecting", "joining", "stage", "span", "interval", "note",
+           "note_max"]
 
 
 @dataclasses.dataclass
@@ -534,6 +535,18 @@ def span(name: str, attrs: Optional[dict] = None):
     but summed nowhere (the datapath hops: their sums live in
     ``QueryStats.datapath``); see stage."""
     return _SpanTimer(current_collector(), name, {}, attrs, False)
+
+
+def interval(name: str, start_s: float, end_s: float) -> None:
+    """A span of the ambient collector from two readings of
+    `time.time()` taken elsewhere, under this thread's open span: an
+    interval whose work ran on threads that have no collector."""
+    c = current_collector()
+    if c is None:
+        return
+    stack = _open_spans()
+    parent = stack[-1][1] if stack and stack[-1][0] is c else None
+    c._record_span(name, start_s, end_s, parent=parent)
 
 
 def note(name: str, delta: int = 1) -> None:

@@ -169,6 +169,15 @@ def annotate_widths(root: N.PlanNode, sf: float, _memo=None) -> N.PlanNode:
     return root
 
 
+def lane_holds(dt, values: np.ndarray) -> bool:
+    """Whether every one of `values` (integers) fits a lane of `dt`:
+    the range proof of the staging-time guard, over one array."""
+    if not len(values):
+        return True
+    info = np.iinfo(np.dtype(dt))
+    return info.min <= int(values.min()) and int(values.max()) <= info.max
+
+
 def checked_physical_dtypes(phys: Sequence[Optional[str]],
                             types: Sequence[T.Type],
                             arrays: Sequence[np.ndarray],
@@ -196,9 +205,7 @@ def checked_physical_dtypes(phys: Sequence[Optional[str]],
             if not len(live):
                 out.append(dt)  # all-null: any lane holds the mask
                 continue
-        info = np.iinfo(np.dtype(dt))
-        lo, hi = int(live.min()), int(live.max())
-        out.append(dt if info.min <= lo and hi <= info.max else None)
+        out.append(dt if lane_holds(dt, live) else None)
     return tuple(out)
 
 

@@ -7,6 +7,8 @@ around the catalog but to read a file back and compare."""
 import gc
 import os
 import sys
+import threading
+import time
 import weakref
 
 import numpy as np
@@ -341,26 +343,38 @@ def test_q6_over_hive_and_memory_run_programs_of_the_same_shapes(lake):
 
 
 def test_a_scan_decodes_once_and_its_hops_tile_staging(lake, monkeypatch):
+    """Each column of each group is decoded once; each hop is recorded
+    once a scan, inside `staging` (they overlap there and no longer
+    tile it: `test_the_hops_of_a_pipelined_scan_overlap`)."""
+    from presto_tpu.exec.stats import StatsCollector, collecting
     calls = []
     real = parquet.arrow_to_engine
     monkeypatch.setattr(parquet, "arrow_to_engine",
                         lambda arr, ty: calls.append(len(arr))
                         or real(arr, ty))
-    qs = sql(Q6.format(t="hive.lineitem"), sf=SF).query_stats
+    collector = StatsCollector()
+    with collecting(collector):
+        qs = sql(Q6.format(t="hive.lineitem"), sf=SF).query_stats
     groups = qs.counters["lake_row_groups_read"]
     # four columns of every row group, each decoded once
     assert len(calls) == 4 * groups and sum(calls) == 4 * 60000
+    assert qs.counters["lake_row_groups_pipelined"] == groups
     hops = qs.datapath
+    assert "narrow_cast" not in hops  # its proof and cast are the decode
     assert [hops[h].invocations for h in
-            ("connector_read", "decode", "narrow_cast", "device_put")] \
-        == [1, 1, 1, 1]
+            ("connector_read", "decode", "device_put")] == [1, 1, 1]
     assert hops["connector_read"].bytes == qs.counters["lake_file_bytes"] > 0
+    # the narrowed lanes (2 + 4 + 1 + 2 bytes a row) and four masks
     assert hops["decode"].bytes == qs.counters["lake_decoded_bytes"] \
-        == 60000 * (8 + 8 + 8 + 4 + 4)
-    staging = qs.stages["staging"].wall_us
-    tiled = sum(hops[h].wall_us for h in
-                ("connector_read", "decode", "narrow_cast", "device_put"))
-    assert tiled <= staging and staging - tiled < max(0.2 * staging, 5000)
+        == 60000 * (9 + 4)
+    assert hops["device_put"].bytes == qs.stages["staging"].bytes
+    spans = {name: (t0, t1, span, parent)
+             for name, t0, t1, _a, span, parent in collector.spans}
+    s0, s1, staging, _ = spans["staging"]
+    for h in ("connector_read", "decode", "device_put"):
+        t0, t1, _span, parent = spans[h]
+        assert parent == staging and s0 <= t0 <= t1 <= s1, h
+        assert abs(hops[h].wall_us - (t1 - t0) * 1e6) <= 1
 
 
 def test_a_join_filtered_by_its_build_side_reads_the_file_once(lake,
@@ -417,6 +431,239 @@ def test_the_writer_holds_one_page_at_a_time(warehouse, monkeypatch):
     assert done.query_stats.counters["write_pages"] == len(pages) >= 3
     # when a page arrives, every page before it has been let go
     assert alive_before == [0] * len(pages)
+
+
+# -- (f) the pipeline: a row group is the unit of staging ---------------------
+
+Q6_COLS = ["quantity", "extendedprice", "discount", "shipdate"]
+
+
+@pytest.fixture(scope="module")
+def sorted_file(warehouse):
+    """lineitem's Q6 columns sorted by shipdate, 59 row groups of 1,024
+    rows: more groups than the pool has threads or a scan has in
+    flight."""
+    data = g.generate_columns("lineitem", SF, Q6_COLS)
+    order = np.argsort(data["shipdate"], kind="stable")
+    path = os.path.join(warehouse, "sorted_1k.parquet")
+    parquet.write_table(path, {c: data[c][order] for c in Q6_COLS},
+                        dict(g.TPCH_SCHEMA["lineitem"]), row_group_size=1024)
+    parquet.register_table("sorted_1k", path)
+    yield {c: data[c][order] for c in Q6_COLS}
+    parquet.unregister_table("sorted_1k")
+
+
+def _numpy_q6(data):
+    keep = (data["shipdate"] >= 8766) & (data["shipdate"] < 9131) \
+        & (data["discount"] >= 5) & (data["discount"] <= 7) \
+        & (data["quantity"] < 2400)
+    return int((data["extendedprice"][keep] * data["discount"][keep]).sum())
+
+
+def _leaves(batch):
+    import jax
+    return [np.asarray(x) for x in jax.tree_util.tree_leaves(batch)]
+
+
+def test_a_stale_range_stages_that_column_wide_and_never_wraps(
+        lake, sorted_file):
+    """A `column_range` that lies narrower than one group's values: the
+    group refuses its lane, the scan stages whole and that column wide,
+    and the answer is memory's and numpy's."""
+    want = sql(Q6.format(t="memory.lineitem"), sf=SF).rows()
+    assert want == [(_numpy_q6(sorted_file),)]
+    honest = parquet.column_range("sorted_1k", "extendedprice")
+    assert honest[1] > 127
+    parquet._tables["sorted_1k"]["ranges"]["extendedprice"] = (0, 100)
+    try:
+        node = _scan(Q6.format(t="hive.sorted_1k"))
+        lanes = dict(zip(node.columns, node.physical_dtypes))
+        assert lanes["extendedprice"] == "int8"  # the plan trusted it
+        got = sql(Q6.format(t="hive.sorted_1k"), sf=SF)
+        assert got.rows() == want
+        c = got.query_stats.counters
+        assert c["lake_row_groups_pipelined"] == 0 < c["lake_row_groups_read"]
+        assert got.query_stats.datapath["narrow_cast"].invocations == 1
+        # the other three stay narrow: 8 + 2 + 1 + 2 and four masks, and
+        # the batch's own mask, a row
+        assert got.query_stats.stages["staging"].bytes == 60000 * (13 + 5)
+    finally:
+        parquet._tables["sorted_1k"]["ranges"]["extendedprice"] = honest
+    again = sql(Q6.format(t="hive.sorted_1k"), sf=SF).query_stats.counters
+    assert again["lake_row_groups_pipelined"] == again["lake_row_groups_read"]
+
+
+def test_a_pruned_scan_through_the_pipeline_is_the_whole_scan_row_for_row(
+        sorted_file):
+    """Pruned groups, fewer rows than `count`: the batch the pipeline
+    assembles on the device is the one `batch_from_numpy` makes of the
+    host columns, leaf for leaf, order included."""
+    from presto_tpu.block import batch_from_numpy
+    from presto_tpu.exec.runner import stage_scan_split
+    from presto_tpu.plan.widths import checked_physical_dtypes
+    node = _scan(Q6.format(t="hive.sorted_1k"))
+    assert node.pushdown is not None and any(node.physical_dtypes)
+    predicate = tuple(node.pushdown)
+    # the whole file; a range that cuts its first and last group; one
+    # inside 1994 at a capacity under a row group's rows
+    for start, count, capacity, pruned in ((0, 60000, 60000, True),
+                                           (1500, 40000, 40960, True),
+                                           (20000, 3000, 3000, False)):
+        got = stage_scan_split(catalog("hive"), node, SF, start, count,
+                               capacity, predicate)
+        values, nulls = parquet.read_columns("sorted_1k", node.columns,
+                                             start, count, predicate)
+        arrays = [values[c] for c in node.columns]
+        masks = [nulls[c] for c in node.columns]
+        phys = checked_physical_dtypes(node.physical_dtypes,
+                                       node.column_types, arrays, masks)
+        assert phys == node.physical_dtypes
+        want = batch_from_numpy(node.column_types, arrays, nulls=masks,
+                                capacity=capacity, physical_dtypes=phys)
+        # statistics excluded groups: fewer rows than `count`
+        assert 0 < len(arrays[0]) <= count - pruned
+        assert int(np.asarray(got.active).sum()) == len(arrays[0])
+        for a, b in zip(_leaves(got), _leaves(want)):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+    # every group excluded: an empty batch of the same lanes
+    none = stage_scan_split(catalog("hive"), node, SF, 0, 700, 1024,
+                            predicate)
+    assert not np.asarray(none.active).any()
+    assert [str(c.values.dtype) for c in none.columns] == \
+        list(node.physical_dtypes)
+    assert all(np.asarray(c.nulls).all() for c in none.columns)
+
+
+def test_nulls_of_every_fixed_width_type_survive_the_narrowed_decode(
+        warehouse):
+    made = sql("CREATE TABLE hive.nulls_fixed AS SELECT linenumber AS i, "
+               "orderkey AS b, CAST(quantity AS DOUBLE) AS d, "
+               "extendedprice AS m, shipdate AS dt, quantity > 1 AS f "
+               "FROM lineitem WHERE orderkey < 0", sf=SF)
+    try:
+        assert made.rows() == [(0,)]
+        rows = ("(1, 10, 2, 1.25, date '1995-01-02', true), "
+                "(NULL, NULL, NULL, NULL, NULL, NULL), "
+                "(3, 7, 4, 0.75, date '1969-12-31', false)")
+        sql(f"INSERT INTO hive.nulls_fixed VALUES {rows}", sf=SF)
+        node = _scan("SELECT i, b, d, m, dt, f FROM hive.nulls_fixed")
+        lanes = dict(zip(node.columns, node.physical_dtypes))
+        assert lanes["i"] == lanes["b"] == lanes["m"] == "int8"
+        assert lanes["dt"] == "int16" and lanes["d"] is lanes["f"] is None
+        got = sql("SELECT i, b, d, m, dt, f FROM hive.nulls_fixed", sf=SF)
+        assert got.rows() == [(1, 10, 2.0, 125, 9132, True), (None,) * 6,
+                              (3, 7, 4.0, 75, -1, False)]
+        c = got.query_stats.counters
+        assert c["lake_row_groups_pipelined"] == c["lake_row_groups_read"] > 0
+        assert sql("SELECT count(*), count(i), count(f), sum(m), max(dt) "
+                   "FROM hive.nulls_fixed", sf=SF).rows() == \
+            [(3, 2, 2, 200, 9132)]
+    finally:
+        sql("DROP TABLE hive.nulls_fixed", sf=SF)
+
+
+def _watch_pieces(monkeypatch):
+    """Counts of the pieces the pool began and finished."""
+    seen = {"began": 0, "ended": 0}
+    lock = threading.Lock()
+    real = parquet.PieceScan._piece
+
+    def watching(self, src):
+        with lock:
+            seen["began"] += 1
+        try:
+            return real(self, src)
+        finally:
+            with lock:
+                seen["ended"] += 1
+    monkeypatch.setattr(parquet.PieceScan, "_piece", watching)
+    return seen
+
+
+def test_a_decode_that_raises_fails_the_statement_and_clears_the_pool(
+        sorted_file, monkeypatch):
+    seen = _watch_pieces(monkeypatch)
+    real = parquet.arrow_to_engine
+    calls = []
+
+    def failing(arr, ty):
+        calls.append(1)
+        if len(calls) == 5:  # a group in the middle of the first wave
+            raise RuntimeError("page checksum mismatch")
+        time.sleep(0.001)
+        return real(arr, ty)
+    monkeypatch.setattr(parquet, "arrow_to_engine", failing)
+    with pytest.raises(RuntimeError, match="page checksum mismatch"):
+        sql("SELECT sum(quantity) FROM hive.sorted_1k", sf=SF)
+    # the error surfaced with nothing of the scan left on the pool
+    assert seen["began"] == seen["ended"] < 59
+    assert parquet._decode_pool()._work_queue.empty()
+    time.sleep(0.05)
+    assert seen["began"] == seen["ended"]
+    monkeypatch.undo()
+    assert sql("SELECT count(*) FROM hive.sorted_1k", sf=SF).rows() == \
+        [(60000,)]
+
+
+@pytest.mark.parametrize("dtypes", [None, ["int16", "int32", "int8",
+                                           "int16"]])
+def test_the_groups_in_flight_never_exceed_the_bound(sorted_file,
+                                                     monkeypatch, dtypes):
+    """Both consumers' form of the producer: the groups the pool has
+    begun and the consumer has not yet taken stay within `depth`, and a
+    slow consumer finds it full."""
+    seen = _watch_pieces(monkeypatch)
+    scan = parquet.scan_pieces("sorted_1k", Q6_COLS, dtypes=dtypes)
+    assert len(scan.sources) == 59 > scan.depth >= 2
+    taken, ahead, rows = 0, [], []
+    for piece in scan:
+        time.sleep(0.002)  # the consumer is the slow side
+        ahead.append(seen["began"] - taken)
+        taken += 1
+        rows.append(piece.values["shipdate"][:piece.rows])
+    assert taken == 59 and max(ahead) <= scan.depth + 1
+    assert max(ahead) >= scan.depth  # the bound is what held it back
+    assert np.array_equal(np.concatenate(rows), sorted_file["shipdate"])
+
+
+def test_the_hops_of_a_pipelined_scan_overlap(sorted_file, monkeypatch):
+    """Where groups are still being read while others are put, the
+    hops' walls sum to more than the `staging` that encloses them."""
+    text = "SELECT sum(quantity), max(shipdate) FROM hive.sorted_1k"
+    sql(text, sf=SF)  # the programs that assemble the batch compile here
+    real = parquet.arrow_to_engine
+
+    def slow(arr, ty):
+        time.sleep(0.002)  # 59 groups of two columns on at most 16 threads
+        return real(arr, ty)
+    monkeypatch.setattr(parquet, "arrow_to_engine", slow)
+    qs = sql(text, sf=SF).query_stats
+    assert qs.counters["lake_row_groups_pipelined"] == 59
+    assert qs.counters.get("xla_compiles", 0) == 0
+    hops = qs.datapath
+    walls = [hops[h].wall_us for h in ("connector_read", "decode",
+                                       "device_put")]
+    staging = qs.stages["staging"].wall_us
+    assert max(walls) <= staging < sum(walls)
+
+
+def test_q6_pipelines_every_group_and_a_filtered_join_none_of_lineitems(
+        lake):
+    c = sql(Q6.format(t="hive.lineitem"), sf=SF).query_stats.counters
+    assert c["lake_row_groups_pipelined"] == c["lake_row_groups_read"] > 0
+    text = ("SELECT count(*) FROM hive.lineitem l JOIN hive.part p "
+            "ON l.partkey = p.partkey WHERE p.size = 1")
+    got = sql(text, sf=SF)
+    assert got.stats["dynamic_filter_rows_pruned"]["total"] > 0
+    c = got.query_stats.counters
+    import pyarrow.parquet as pq
+    groups = {t: pq.ParquetFile(os.path.join(
+        parquet._sink.warehouse_dir(), t + ".parquet")).metadata
+        .num_row_groups for t in ("lineitem", "part")}
+    # lineitem's scan is pruned on the host by the filter part built
+    assert c["lake_row_groups_pipelined"] % groups["part"] == 0
+    assert c["lake_row_groups_pipelined"] == \
+        c["lake_row_groups_read"] - groups["lineitem"]
 
 
 # -- the protocol: what the benchmark's load sends ---------------------------

@@ -122,6 +122,33 @@ def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
     assert "conditional" in text
 
 
+def test_a_lake_scans_row_groups_land_in_place_at_sf10_shapes(one_chip):
+    """`block.BatchBuilder`'s three programs at `lake_sf10.scan`'s
+    shapes (60M rows of Q6's narrowed lanes and their masks, a row
+    group of 1,048,576 landing): the donated `dynamic_update_slice`
+    writes into the lanes it was given -- every lane aliased, no
+    scratch the size of one -- so landing 60 groups moves 60 groups'
+    bytes and holds one copy of the lanes, and the closing pass, the
+    only one that holds two, stays far inside the chip."""
+    from presto_tpu import block
+    rows, group = 60_000_000, 1 << 20
+    dtypes = (jnp.int16, jnp.int32, jnp.int8, jnp.int16) + (jnp.bool_,) * 4
+    lanes = tuple(_shape((rows + group,), dt, one_chip) for dt in dtypes)
+    piece = tuple(_shape((group,), dt, one_chip) for dt in dtypes)
+    at = _shape((), jnp.int64, one_chip)
+    nbytes = sum((rows + group) * jnp.dtype(dt).itemsize for dt in dtypes)
+    land = block._land_piece.lower(lanes, piece, at).compile()
+    mem = land.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes  # padded to tiles
+    assert mem.temp_size_in_bytes < 64 << 20
+    close = block._close_lanes.lower(lanes, at, rows).compile()
+    mem = close.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < 3_206_279_680  # the load's peak
+    block._blank_lanes.lower(rows + group, tuple(
+        jnp.dtype(dt) for dt in dtypes)).compile()
+
+
 # -- whole statement programs, as the SQL front door builds them ----------
 
 def _program(text, sharding):
