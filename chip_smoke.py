@@ -248,8 +248,9 @@ def run_four_chips(args, devices) -> bool:
                 line[f"{label}_{temp}_wall_s"] = round(time.time() - t0, 3)
             line[label] = res.rows()
             if m is not None:
-                # read before the one-device run adds to device 0: a
-                # mesh that never left device 0 shows as three zeros
+                # scans are staged shard by shard, each on its own chip
+                # (exec/runner.stage_scan_split): read before the
+                # one-device run adds to device 0
                 line["peak_bytes_per_device_after_mesh"] = [
                     (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                     for d in devices[:4]]

@@ -66,7 +66,10 @@ WIDE_OK_SITES: Dict[str, Set[str]] = {
     # range-exchange splitter sampling packs order words and sample
     # positions in 64 bits (position arithmetic (2s-1)*count must not
     # wrap at large per-worker counts)
-    "exchange.py": {"exchange_by_range", "exchange_by_hash"},
+    # ... and a routed batch's 64-bit lanes travel as two 32-bit words
+    # each: putting them together again makes the lane the batch had
+    "exchange.py": {"exchange_by_range", "exchange_by_hash",
+                    "_pack_lanes", "_pack_lanes.unpack"},
     # row-id / grouping-set-id iotas are logical BIGINT output columns
     # (AssignUniqueIdNode / GroupIdNode lowering)
     "planner.py": {"compile_plan"},

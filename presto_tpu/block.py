@@ -31,6 +31,7 @@ sharding annotations apply leaf-wise.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 from typing import Optional, Sequence, Tuple, Union
@@ -555,17 +556,70 @@ def from_numpy(ty: T.Type, values: np.ndarray, nulls: Optional[np.ndarray] = Non
 def batch_from_numpy(types: Sequence[T.Type], arrays: Sequence[np.ndarray],
                      nulls: Optional[Sequence[Optional[np.ndarray]]] = None,
                      capacity: Optional[int] = None,
-                     physical_dtypes=None) -> Batch:
+                     physical_dtypes=None, sharding=None) -> Batch:
+    """Host columns to a device Batch. With `sharding` (rows over a
+    mesh's devices: `NamedSharding(mesh, P(axis))`, `capacity` a
+    multiple of their number) the batch is staged shard by shard
+    (`_sharded_batch`)."""
     n = arrays[0].shape[0]
     capacity = capacity or n
     nulls = nulls or [None] * len(arrays)
     physical_dtypes = physical_dtypes or [None] * len(arrays)
+    if sharding is not None:
+        return _sharded_batch(types, arrays, nulls, capacity,
+                              physical_dtypes, sharding)
     cols = tuple(from_numpy(t, a, m, capacity, physical_dtype=p)
                  for t, a, m, p in zip(types, arrays, nulls,
                                        physical_dtypes))
     active = np.zeros(capacity, dtype=bool)
     active[:n] = True
     return Batch(cols, jnp.asarray(active))
+
+
+def _shards_alike(ty: T.Type, values) -> bool:
+    """A column whose staged leaves have one shape whatever rows a
+    shard holds: not the nested types (an array's width is its longest
+    row's) nor strings still to be encoded (a matrix as wide as the
+    longest string)."""
+    if ty.base in ("array", "map", "row"):
+        return False
+    return not ty.is_string or isinstance(values, HostStrings) \
+        or values.dtype == np.uint8
+
+
+def _sharded_batch(types, arrays, nulls, capacity: int, physical_dtypes,
+                   sharding) -> Batch:
+    """The batch of `batch_from_numpy`, its rows in contiguous ranges
+    over the sharding's devices: each device's range is cut from the
+    host columns, narrowed and padded to the shard's capacity and put
+    on that device, the devices side by side on a thread each (numpy's
+    casts and the transfers run outside the interpreter's lock). Nothing
+    passes through the first device and a program sharded the same way
+    takes the batch as it lies. Every shard but the last is full; the
+    padding is at the end of the table's order, as on one device."""
+    devices = list(sharding.mesh.devices.flat)
+    n = arrays[0].shape[0]
+    per = capacity // len(devices)
+    assert per * len(devices) == capacity, (capacity, len(devices))
+    if not all(_shards_alike(t, a) for t, a in zip(types, arrays)):
+        whole = batch_from_numpy(types, arrays, nulls, capacity,
+                                 physical_dtypes)
+        return jax.device_put(whole, sharding)
+
+    def stage(k: int) -> Batch:
+        lo, hi = min(k * per, n), min((k + 1) * per, n)
+        with jax.default_device(devices[k]):
+            return batch_from_numpy(
+                types, [a[lo:hi] for a in arrays],
+                [None if m is None else m[lo:hi] for m in nulls],
+                per, physical_dtypes)
+
+    with concurrent.futures.ThreadPoolExecutor(len(devices)) as pool:
+        shards = list(pool.map(stage, range(len(devices))))
+    return jax.tree_util.tree_map(
+        lambda *leaves: jax.make_array_from_single_device_arrays(
+            (capacity,) + leaves[0].shape[1:], sharding, list(leaves)),
+        *shards)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))

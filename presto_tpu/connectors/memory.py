@@ -35,13 +35,22 @@ from ..block import HostStrings, batch_from_numpy
 __all__ = ["SCHEMA", "create_table", "drop_table", "reset",
            "table_row_count", "generate_columns", "generate_batch",
            "column_type", "begin_insert", "append", "finish_insert",
-           "abort_insert", "table_names"]
+           "abort_insert", "table_names", "table_properties",
+           "table_workers"]
 
 
 class _Table:
-    def __init__(self, columns: List[str], types: List[T.Type]):
+    def __init__(self, columns: List[str], types: List[T.Type],
+                 workers: int = 1):
         self.columns = list(columns)
         self.types = list(types)
+        # how many workers the table's rows are spread over (WITH
+        # (workers = N)): N contiguous row ranges in the table's order,
+        # as upstream's memory connector keeps a table's pages on the
+        # workers that wrote them. No key is implied. A statement runs
+        # over the chips its tables are spread over (exec/runner.
+        # placement_mesh); 1 is one chip, as every table was before
+        self.workers = workers
         # one column + null mask per column: HostStrings for strings,
         # object dtype for long decimals/arrays, native dtypes otherwise
         self.values: List[np.ndarray] = [_stored(t, []) for t in types]
@@ -137,15 +146,49 @@ def reset() -> None:
         _pending.clear()
 
 
+def table_properties(given: dict) -> dict:
+    """The properties of a CREATE TABLE as the store takes them: the
+    one it has is `workers`, a positive integer; anything else, or
+    another property, is the statement's error."""
+    unknown = sorted(set(given) - {"workers"})
+    if unknown:
+        raise ValueError(f"catalog 'memory' has no table property "
+                         f"{unknown[0]!r} (it has: workers)")
+    if "workers" not in given:
+        return {}
+    text = str(given["workers"])
+    if not text.isdigit() or int(text) < 1:
+        raise ValueError(f"memory table property workers needs a "
+                         f"positive integer, got {text!r}")
+    return {"workers": int(text)}
+
+
+def table_workers(table: str) -> int:
+    """Workers `table`'s rows are spread over; 1 for an unknown table
+    (the scan's own error says so where it is read)."""
+    with _lock:
+        t = _tables.get(table)
+        return t.workers if t is not None else 1
+
+
+def table_properties_of(table: str) -> dict:
+    """What `table` was created WITH, as `table_properties` gave it."""
+    workers = table_workers(table)
+    return {"workers": workers} if workers > 1 else {}
+
+
 def create_table(name: str, columns: Sequence[str],
                  types: Sequence[T.Type],
-                 if_not_exists: bool = False) -> None:
+                 if_not_exists: bool = False,
+                 properties: Optional[dict] = None) -> None:
     with _lock:
         if name in _tables:
             if if_not_exists:
                 return
             raise ValueError(f"memory table {name!r} already exists")
-        _tables[name] = _Table(list(columns), list(types))
+        _tables[name] = _Table(
+            list(columns), list(types),
+            table_properties(properties or {}).get("workers", 1))
         _bump_version(name)
 
 
@@ -256,14 +299,17 @@ def generate_batch(table: str, sf: float, columns: Sequence[str],
 
 def begin_insert(table: str,
                  create_columns: Optional[Sequence[str]] = None,
-                 create_types: Optional[Sequence[T.Type]] = None) -> str:
+                 create_types: Optional[Sequence[T.Type]] = None,
+                 properties: Optional[dict] = None) -> str:
     """Start a staged insert; with create_columns/types this is CTAS:
-    the (empty) table is created NOW so concurrent CTAS to one name
-    conflict early, and dropped again on abort."""
+    the (empty) table is created NOW, with the statement's
+    `properties`, so concurrent CTAS to one name conflict early, and
+    dropped again on abort."""
     with _lock:
         created = False
         if create_columns is not None:
-            create_table(table, create_columns, create_types)
+            create_table(table, create_columns, create_types,
+                         properties=properties)
             created = True
         if table not in _tables:
             raise KeyError(f"no memory table {table!r}")

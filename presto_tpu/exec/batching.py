@@ -747,6 +747,11 @@ class BatchingExecutor:
         if isinstance(inner, (N.DdlNode, N.TableFinishNode,
                               N.TableWriterNode, N.TableRewriteNode)):
             return None, None, None, None
+        from .runner import placement_mesh
+        if placement_mesh(root) is not None:
+            # a statement over tables spread over several chips runs as
+            # one SPMD program of its own: no batch is formed of it
+            return None, None, None, None
         # the batched path shares staged scans across members, so the
         # per-literal staging optimizations must not specialize them:
         # pushdown pruning and dynamic filters stage different rows for

@@ -314,13 +314,15 @@ class timed_hop:
     and the span's own two clock readings are the wall the hop is
     recorded with on exit."""
 
-    def __init__(self, hop: str, nbytes: int = 0):
+    def __init__(self, hop: str, nbytes: int = 0,
+                 attrs: Optional[dict] = None):
         self.hop = hop
         self.bytes = nbytes
+        self.attrs = attrs
 
     def __enter__(self):
         from .stats import span
-        self._span = span(self.hop)
+        self._span = span(self.hop, self.attrs)
         self._span.__enter__()
         return self
 

@@ -36,8 +36,10 @@ from ..ops.join import hash_join, semi_join_mask
 from ..ops.misc import distinct as distinct_op
 from ..ops.misc import limit as limit_op
 from ..ops.sort import SortKey, sort_batch, top_n
-from ..parallel.exchange import (broadcast_build, exchange_by_hash,
-                                 exchange_by_range, gather_to_root)
+from ..parallel.exchange import (RANGE_HEADROOM, SLOT_HEADROOM, ExchangeLog,
+                                 broadcast_build, exchange_by_hash,
+                                 exchange_by_range, gather_to_root,
+                                 logging_exchanges, slot_for)
 from ..parallel.mesh import WORKERS_AXIS
 from ..plan import nodes as N
 
@@ -89,6 +91,20 @@ class CompiledPlan:
     expand_steps: Dict[tuple, Optional[int]] = dataclasses.field(
         default_factory=dict)
 
+    # what the program's exchanges are (`ExchangeLog.counters`: how many
+    # of each kind, the bytes one chip's collectives move), None for a
+    # program without a mesh: constants of the shapes, kept like
+    # `expand_steps`, so that a plan-cache hit reports them too
+    traced_exchanges: Optional[Dict[str, int]] = None
+    exchanges: Dict[tuple, Optional[Dict[str, int]]] = dataclasses.field(
+        default_factory=dict)
+
+    def exchanges_of(self, batches) -> Optional[Dict[str, int]]:
+        """The exchange counters of a dispatch of `fn` on `batches`
+        that has just returned (as `expand_steps_of`)."""
+        return self.exchanges.setdefault(shape_key(batches),
+                                         self.traced_exchanges)
+
     def expand_steps_of(self, batches) -> Optional[int]:
         """The counter join_expand_steps of a dispatch of `fn` on
         `batches` that has just returned (under the plan's call lock):
@@ -136,11 +152,14 @@ def _preorder(root: N.PlanNode) -> Dict[int, int]:
 def compile_plan(root: N.PlanNode, mesh=None,
                  default_join_capacity: int = 1 << 16,
                  exchange_slot_scale: int = 1) -> CompiledPlan:
-    """`exchange_slot_scale` geometrically grows every exchange's
-    per-destination slot capacity (clamped at the sender's row capacity,
-    where overflow is impossible): the runner's overflow->rerun policy
-    passes 1, 2, 4, ... until the plan fits -- the memory-feedback
-    analog of the reference's reserve/revoke loop.
+    """An exchange's per-destination slots are sized from the sender's
+    own shard (`parallel/exchange.slot_for`: a little over an even
+    split), so a receiver's capacity is a little over its sender's and
+    the operators after an exchange run at the shard's size.
+    `exchange_slot_scale` geometrically grows every slot (clamped at the
+    sender's row capacity, where overflow is impossible): the runner's
+    overflow->rerun policy passes 1, 2, 4, ... until the plan fits --
+    the memory-feedback analog of the reference's reserve/revoke loop.
 
     Every device op the program lowers to is named by where it came
     from: one ``<NodeType>.<k>`` scope per plan node on the path down
@@ -158,9 +177,13 @@ def compile_plan(root: N.PlanNode, mesh=None,
     axis = WORKERS_AXIS
     dist = mesh is not None
 
-    def _scaled_slot(base: int, sender_capacity: int) -> int:
+    n_workers = mesh.devices.size if dist else 1
+
+    def _scaled_slot(given: Optional[int], sender_capacity: int,
+                     headroom: float = SLOT_HEADROOM) -> int:
         # a sender never has more than `sender_capacity` rows for any
         # one destination, so slots beyond that cannot overflow
+        base = given or slot_for(sender_capacity, n_workers, headroom)
         return min(base * exchange_slot_scale, max(sender_capacity, 1))
 
     def lower(node: N.PlanNode, inputs: Dict[str, Batch]) -> Batch:
@@ -365,13 +388,8 @@ def compile_plan(root: N.PlanNode, mesh=None,
                 if isinstance(src_node, N.SortNode):
                     src_node = src_node.source
                 inner = lower(src_node, inputs)
-                n_workers = mesh.devices.size
-                slot = _scaled_slot(
-                    node.slot_capacity
-                    or max(4 * inner.capacity // max(n_workers, 1), 64),
-                    inner.capacity)
-                from ..parallel.stages import _note_exchange
-                _note_exchange("range", axis)
+                slot = _scaled_slot(node.slot_capacity, inner.capacity,
+                                    RANGE_HEADROOM)
                 out, ovf = exchange_by_range(inner, node.sort_keys, axis,
                                              slot)
                 _note_overflow(ovf, scalable=True)
@@ -379,23 +397,17 @@ def compile_plan(root: N.PlanNode, mesh=None,
             src = lower(node.source, inputs)
             if node.scope == "LOCAL" or not dist:
                 return src
-            from ..parallel.stages import _note_exchange
             if node.kind == "REPARTITION":
-                slot = _scaled_slot(
-                    node.slot_capacity or max(src.capacity, 1),
-                    src.capacity)
-                _note_exchange("hash", axis)
+                slot = _scaled_slot(node.slot_capacity, src.capacity)
                 out, ovf = exchange_by_hash(src, node.partition_channels,
                                             axis, slot)
                 _note_overflow(ovf, scalable=True)
                 return out
             if node.kind == "REPLICATE":
-                _note_exchange("broadcast", axis)
                 return broadcast_build(src, axis)
             if node.kind == "GATHER":
                 # every worker receives all rows; only worker 0 keeps them
                 # active so the global (concatenated) view has one copy
-                _note_exchange("gather", axis)
                 g = gather_to_root(src, axis)
                 is_root = jax.lax.axis_index(axis) == 0
                 return g.with_active(g.active & is_root)
@@ -423,7 +435,10 @@ def compile_plan(root: N.PlanNode, mesh=None,
         compacted.clear()
         _lower_memo.clear()
         inputs = {n.id: b for n, b in zip(scans, scan_batches)}
-        out = lower(root, inputs)
+        log = ExchangeLog()
+        with logging_exchanges(log):
+            out = lower(root, inputs)
+        plan.traced_exchanges = log.counters() if dist else None
         plan.traced_expand_steps = sum(expand_steps) if expand_steps \
             else None
         hard = jnp.zeros((), dtype=bool)   # join/group capacity
@@ -446,9 +461,17 @@ def compile_plan(root: N.PlanNode, mesh=None,
         # join_search_steps, join_probe_compacted; `split_flags` takes
         # it apart)
         steps = jnp.minimum(steps, (1 << STEP_BITS) - 1)
-        return out, (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
-                     + (steps << FLAG_BITS)
-                     + (took << (FLAG_BITS + STEP_BITS)))
+        word = (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
+                + (steps << FLAG_BITS)
+                + (took << (FLAG_BITS + STEP_BITS)))
+        if not dist:
+            return out, word
+        # under a mesh one more scalar rides beside the word, still one
+        # host read: the bytes of rows the hash and range exchanges
+        # routed, a chip's mean (the counter exchange_row_bytes)
+        routed = sum(log.routed, jnp.zeros((), dtype=jnp.int64))
+        routed = jax.lax.psum(routed, axis) // n_workers
+        return out, jnp.stack([word.astype(jnp.int64), routed])
 
     plan = CompiledPlan(run, scans, root.output_types(), dist, root)
     if dist:

@@ -15,6 +15,7 @@ without changing lowered kernels.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import dataclasses
 import threading
@@ -156,8 +157,34 @@ def _stage_pieces(scan, types, capacity: int) -> Optional[Batch]:
     return b
 
 
+def _checked_by_shard(phys, types, arrays, nulls, shards: int, per: int):
+    """`checked_physical_dtypes` shard by shard, the shards side by side
+    (numpy's reductions run outside the interpreter's lock): a lane
+    keeps its narrowing where every shard that holds rows proves it."""
+    from ..plan.widths import checked_physical_dtypes
+    rows = len(arrays[0])
+    cuts = [(k * per, min((k + 1) * per, rows)) for k in range(shards)
+            if k * per < rows]
+
+    if not cuts:
+        return checked_physical_dtypes(phys, types, arrays, nulls=nulls)
+
+    def prove(cut):
+        lo, hi = cut
+        return checked_physical_dtypes(
+            phys, types, [a[lo:hi] for a in arrays],
+            nulls=None if nulls is None else
+            [None if m is None else m[lo:hi] for m in nulls])
+
+    with concurrent.futures.ThreadPoolExecutor(len(cuts)) as pool:
+        proofs = list(pool.map(prove, cuts))
+    return tuple(dt if all(p[i] == dt for p in proofs) else None
+                 for i, dt in enumerate(proofs[0]))
+
+
 def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
-                     count: int, capacity: int, predicate=None) -> Batch:
+                     count: int, capacity: int, predicate=None,
+                     sharding=None) -> Batch:
     """Stage one scan split honoring the node's narrow-width annotation
     (plan/widths.py) -- the shared staging path of the runner and the
     streaming executor. `predicate` is the scan's pushed-down range, for
@@ -177,6 +204,13 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
       width annotation (or the connector can't produce host columns)
       and the connector reads no files.
 
+    With `sharding` (a statement over a mesh: rows over its devices)
+    the host columns are proved, narrowed, padded and put shard by
+    shard, each on its own chip and the chips side by side
+    (`block._sharded_batch`): the hops keep their names and carry the
+    shard count as the attribute `shards`. A connector that can only
+    give a device batch has it laid out again by one `device_put`.
+
     Every path records its data-path hops (exec/datapath.py):
     connector_read (host column materialization), decode (a file's
     arrow arrays to lanes), narrow_cast (the staging-time range
@@ -185,9 +219,11 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
     from .datapath import timed_hop
     from .memory import batch_bytes
     phys = getattr(node, "physical_dtypes", None)
+    shards = len(sharding.mesh.devices.flat) if sharding is not None else 1
+    by_shard = {"shards": shards} if sharding is not None else None
     if not hasattr(conn, "read_columns") and (
-            not phys or not any(phys)
-            or not hasattr(conn, "generate_columns")):
+            not hasattr(conn, "generate_columns")
+            or (sharding is None and (not phys or not any(phys)))):
         # the connector stages straight to a device batch: the whole
         # read+put attributes to connector_read (coarse by design --
         # connectors wanting finer hops expose generate_columns)
@@ -195,10 +231,12 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
             b = conn.generate_batch(node.table, sf, node.columns,
                                     start=start, count=count,
                                     capacity=capacity)
+            if sharding is not None:
+                b = jax.device_put(b, sharding)
             t_read.bytes = batch_bytes(b)
         return b
     offered = getattr(conn, "scan_pieces", None)
-    if offered is not None and node.columns \
+    if offered is not None and node.columns and sharding is None \
             and _plain_lanes(node.column_types):
         scan = offered(node.table, node.columns, start, count, predicate,
                        dtypes=[dt or ty.to_dtype() for dt, ty in zip(
@@ -210,13 +248,17 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
     arrays, nulls = _read_split(conn, node, sf, start, count, predicate)
     if phys and any(phys):
         from ..plan.widths import checked_physical_dtypes
-        with timed_hop("narrow_cast", _host_bytes(arrays, nulls)):
-            phys = checked_physical_dtypes(phys, node.column_types, arrays,
-                                           nulls=nulls)
-    with timed_hop("device_put") as t_put:
+        with timed_hop("narrow_cast", _host_bytes(arrays, nulls), by_shard):
+            phys = checked_physical_dtypes(
+                phys, node.column_types, arrays, nulls=nulls) \
+                if sharding is None else _checked_by_shard(
+                    phys, node.column_types, arrays, nulls, shards,
+                    capacity // shards)
+    with timed_hop("device_put", attrs=by_shard) as t_put:
         b = batch_from_numpy(node.column_types, arrays, nulls=nulls,
                              capacity=capacity,
-                             physical_dtypes=phys or None)
+                             physical_dtypes=phys or None,
+                             sharding=sharding)
         # sync so the measured wall is the transfer, not the async
         # dispatch returning early (bench.py learned this on the chip):
         # the caller host-reads b.active right after, so this adds no
@@ -231,7 +273,10 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
 def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
                 pad_multiple: int,
                 scan_range: Optional[Tuple[int, int]] = None,
-                dyn_filters=None, stats=None) -> Batch:
+                dyn_filters=None, stats=None, sharding=None) -> Batch:
+    """One scan leaf's staged batch; with `sharding` (a statement over
+    a mesh) laid out over the mesh's devices, a table's rows shard by
+    shard from the host (`stage_scan_split`)."""
     if isinstance(node, N.ValuesNode):
         arrays = []
         null_masks = []
@@ -254,9 +299,9 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
             import jax.numpy as jnp
             active = np.zeros(cap, dtype=bool)
             active[:len(node.rows)] = True
-            return Batch((), jnp.asarray(active))
+            return jax.device_put(Batch((), jnp.asarray(active)), sharding)
         return batch_from_numpy(node.types, arrays, nulls=null_masks,
-                                capacity=cap)
+                                capacity=cap, sharding=sharding)
     assert isinstance(node, N.TableScanNode)
     from ..connectors import catalog
     conn = catalog(node.connector)
@@ -304,7 +349,47 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
     # above) and stages what is left like any other split
     predicate = tuple(node.pushdown) \
         if node.pushdown is not None and scan_range is None else None
-    return stage_scan_split(conn, node, sf, start, count, cap, predicate)
+    return stage_scan_split(conn, node, sf, start, count, cap, predicate,
+                            sharding)
+
+
+def _process_chips() -> int:
+    """Chips this process has (a test stands in for a one-chip host
+    here)."""
+    return len(jax.devices())
+
+
+def placement_mesh(root: N.PlanNode, mesh=None):
+    """The mesh a statement runs over: the caller's `mesh` where one was
+    handed in; else the chips its tables are spread over, which is the
+    widest placement among the tables it scans (a memory table's
+    `workers`), held to the chips the process has; None, one chip and
+    today's path line for line, where that is 1. Read from the plan's
+    scans, prepared or not, so that `prepare_plan` and `run_query`, and
+    with them the server, `sql()`, the dbapi and a worker, agree."""
+    if mesh is not None:
+        return mesh
+    from ..connectors import catalog
+    workers, seen, todo = 1, set(), [root]
+    while todo:
+        n = todo.pop()
+        if id(n) in seen:
+            continue
+        seen.add(id(n))
+        if isinstance(n, N.TableScanNode):
+            try:
+                spread = getattr(catalog(n.connector), "table_workers", None)
+            except KeyError:  # no such catalog: the plan checker's to say
+                spread = None
+            if spread is not None:
+                workers = max(workers, spread(n.table))
+        todo.extend(n.sources)
+    if workers > 1:
+        workers = min(workers, _process_chips())
+    if workers <= 1:
+        return None
+    from ..parallel.mesh import make_mesh
+    return make_mesh(workers)
 
 
 def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
@@ -328,6 +413,7 @@ def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
         return session_flag(session, name, True)
 
     _check_schemas(root, sf)
+    mesh = placement_mesh(root, mesh)
     # rule-based simplification + channel pruning (IterativeOptimizer /
     # PruneUnreferencedOutputs analog): narrows intermediates before
     # stats and distribution decide capacities and exchange widths
@@ -372,16 +458,14 @@ def prepare_plan(root: N.PlanNode, sf: float = 0.01, mesh=None,
         # exchanges they need (AddExchanges; idempotent for plans that
         # already carry PARTIAL/FINAL + exchange structure). The session's
         # join_distribution_type picks broadcast vs partitioned joins
-        # (DetermineJoinDistributionType; AUTOMATIC -> broadcast in
-        # round 1, CBO pending)
+        # (DetermineJoinDistributionType); AUTOMATIC, upstream's default
+        # and this engine's, decides per join from the build side's
+        # estimated rows (plan/distribute._BROADCAST_ROW_LIMIT)
         from ..plan.distribute import add_exchanges
-        strategy = "broadcast"
-        if session is not None:
-            jd = session.get("join_distribution_type")
-            if jd == "PARTITIONED":
-                strategy = "partitioned"
-            elif jd == "AUTOMATIC":
-                strategy = "automatic"
+        jd = str(session_value(session, "join_distribution_type",
+                               "AUTOMATIC")).upper()
+        strategy = {"BROADCAST": "broadcast",
+                    "PARTITIONED": "partitioned"}.get(jd, "automatic")
         root = add_exchanges(root, join_strategy=strategy, sf=sf)
     from ..plan.validator import validate_plan
     violations = validate_plan(root, distributed=mesh is not None)
@@ -508,6 +592,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         return res
     t_query0 = time.time()
     with stage("plan"):
+        mesh = placement_mesh(root, mesh)
         if not prepared:
             with stage("plan.prepare"):
                 root = prepare_plan(root, sf=sf, mesh=mesh, session=session)
@@ -568,6 +653,11 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                                   dp=dp, acc=acc, sf=sf)
             return res
     pad = (mesh.devices.size if mesh is not None else 1) * 8
+    sharding = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from ..parallel.mesh import WORKERS_AXIS
+        sharding = NamedSharding(mesh, PartitionSpec(WORKERS_AXIS))
     hints = capacity_hints or {}
     scan_ranges = scan_ranges or {}
     remote_sources = remote_sources or {}
@@ -694,7 +784,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                         s, sf, hints.get(s.id), pad,
                         scan_ranges.get(s.id),
                         dyn_filters=dyn_filters.get(s.id),
-                        stats=stats))
+                        stats=stats, sharding=sharding))
                 collector.operator(
                     _scan_key(si, s), _scan_label(s),
                     wall_us=int((time.time() - t_scan0) * 1e6))
@@ -881,27 +971,55 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
 
 
 # adaptive-capacity feedback (HBO-lite, HistoryBasedPlanStatistics
-# analog): plan fingerprint -> the capacity scale that made it fit.
+# analog): plan fingerprint -> the capacity scale that made it fit, and
+# under a mesh the exchange-slot scale beside it.
 # Bounded process-local memory; structurally identical future
 # submissions start at the known-good size instead of re-laddering.
 _CAPACITY_FEEDBACK: Dict[str, int] = {}
+_SLOT_FEEDBACK: Dict[str, int] = {}
 _MAX_CAPACITY_SCALE = 1 << 10
+# Over a mesh the join and group ladder starts where the capacities
+# reach this share of the largest scan's rows a chip, not at the
+# default: every rung is one more SPMD program to compile (minutes each
+# at tens of millions of rows a chip: PERF.md, PR 34), while a capacity
+# of a sixteenth of the largest scan holds, a lane, a sixteenth of what
+# that scan already does.
+_MESH_LADDER_SHARE = 16
 
 
-def _read_status(word, expand_steps: Optional[int]) -> int:
+def _mesh_ladder_start(batches, mesh, default_join_capacity: int) -> int:
+    """The capacity scale a meshed program's ladder starts from: the
+    power of four (the ladder's step) at which `default_join_capacity`
+    reaches 1 / `_MESH_LADDER_SHARE` of the largest staged scan's rows a
+    chip; 1 for small tables."""
+    per_chip = max((b.capacity for b in batches), default=0) \
+        // mesh.devices.size
+    scale = 1
+    while default_join_capacity * scale * _MESH_LADDER_SHARE < per_chip \
+            and scale < _MAX_CAPACITY_SCALE:
+        scale *= 4
+    return scale
+
+
+def _read_status(word, expand_steps: Optional[int]) -> Tuple[int, int]:
     """The one host read of the word a program returns beside its batch:
     its overflow flags come back, the trips its joins' lookups took go
     to the statement's counters, and with them `expand_steps`, the
     trips of its joins' expansions (`CompiledPlan.expand_steps_of`:
     None for a program without a join), and how many of its joins
-    compacted their probe."""
-    flags, steps, compacted = split_flags(int(np.asarray(word)))
+    compacted their probe. A program over a mesh returns a second
+    scalar beside the word, the bytes of rows its exchanges routed (a
+    chip's mean), which comes back with the flags."""
+    status = np.asarray(word)
+    routed = int(status[1]) if status.ndim else 0
+    flags, steps, compacted = split_flags(
+        int(status[0]) if status.ndim else int(status))
     if steps:
         note("join_search_steps", steps)
     if expand_steps is not None:  # 0 too: a join whose table is its
         note("join_expand_steps", expand_steps)  # own directory
         note("join_probe_compacted", compacted)
-    return flags
+    return flags, routed
 
 
 def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
@@ -910,9 +1028,11 @@ def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
     analysis of the executable gives them, aliased bytes counted once.
     Lowering the call again finds the executable jit keeps for these
     shapes (no trace, no compile), and the answer stays with the
-    compiled plan. Where the executable gives no analysis (one read
-    from a compile cache may not), the allocator's peak: then the
-    process's peak so far, not this program's."""
+    compiled plan. A program over a mesh plans that much on each of its
+    chips: the analysis of an SPMD executable is one device's. Where the
+    executable gives no analysis (one read from a compile cache may
+    not), the allocator's peak: then the process's peak so far, not
+    this program's."""
     key = shape_key(batches)
     if key not in plan.hbm_bytes:
         found = 0
@@ -957,17 +1077,19 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
     Returns (out, device_s, dispatch_fn, call_lock, cap_scale, scale,
     plan)."""
     device_s = 0.0
-    scale = 1
+    scale = _SLOT_FEEDBACK.get(fp, 1) if fp and mesh is not None else 1
     cap_scale = _CAPACITY_FEEDBACK.get(fp, 1) if fp else 1
+    if mesh is not None and cap_scale == 1:
+        cap_scale = _mesh_ladder_start(batches, mesh, default_join_capacity)
     exec_root = root if cap_scale == 1 else None  # set below
-    if cap_scale > 1:
+    if cap_scale > 1 or scale > 1:
         # HBO-lite: a structurally identical plan overflowed before;
         # start from the capacities that worked
         from ..plan.stats import scale_capacities
         exec_root = scale_capacities(root, cap_scale)
         plan, jfn, call_lock = _compile_any(
             exec_root, mesh, default_join_capacity * cap_scale,
-            1, use_cache)
+            scale, use_cache)
         stats.add("capacity_feedback_scale", cap_scale)
     from .datapath import now_us as _now_us
     region = {"region": tag}
@@ -980,11 +1102,13 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                 dispatch_fn = fn
                 out, overflow = fn(tuple(batches))
                 expand_steps = plan.expand_steps_of(batches)
+                exchanges = plan.exchanges_of(batches)
             else:
                 dispatch_fn = jfn
                 with call_lock:  # serialize trace-time closure state
                     out, overflow = jfn(tuple(batches))
                     expand_steps = plan.expand_steps_of(batches)
+                    exchanges = plan.exchanges_of(batches)
         with stage("device_wait", region):
             jax.block_until_ready(out)
             # host-observed device occupancy of this dispatch: the
@@ -992,7 +1116,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # the only per-kernel timing one fused program exposes -- on
             # the monotonic now_us clock
             device_s += (_now_us() - t_disp0) / 1e6
-            flags = _read_status(overflow, expand_steps)
+            flags, routed = _read_status(overflow, expand_steps)
         note_max("program_hbm_bytes",
                  _program_hbm_bytes(plan, dispatch_fn, call_lock, batches))
         if prog is not None:  # each landed dispatch advances
@@ -1000,6 +1124,16 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
         if flags == 0:
             if cap_scale > 1 and fp:
                 _CAPACITY_FEEDBACK[fp] = cap_scale
+            if scale > 1 and fp:
+                _SLOT_FEEDBACK[fp] = scale
+            if mesh is not None:
+                # the program that answered, a plan-cache hit or not:
+                # its chips, its exchanges (constants kept with the
+                # compiled plan) and the bytes of rows they routed
+                note_max("mesh_chips", mesh.devices.size)
+                for name, value in (exchanges or {}).items():
+                    note(name, value)
+                note("exchange_row_bytes", routed)
             break
         if flags & 1:
             # hard (join/group/unnest) overflow: adaptive rerun with
@@ -1031,6 +1165,7 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                 "exchange slot overflow did not converge")
         scale *= 2
         stats.add("exchange_slot_reruns", 1)
+        note("capacity_reruns")  # a slot is a capacity too
         plan, jfn, call_lock = _compile_any(
             exec_root if exec_root is not None else root, mesh,
             default_join_capacity * cap_scale, scale, use_cache)
@@ -1163,7 +1298,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                     jax.block_until_ready(out)
                     dev_s = (_now_us() - t_don0) / 1e6
                     # no join is overflow-incapable: nothing expands
-                    oflags = _read_status(overflow, None)
+                    oflags, _ = _read_status(overflow, None)
                 if prog is not None:
                     prog.advance()
                 if oflags:  # unreachable: whitelist admits no overflow op
@@ -1500,7 +1635,8 @@ def _write_page_ranges(select: N.OutputNode, kw) -> List[Optional[dict]]:
     if not budget:
         budget = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
     if not isinstance(scan, N.TableScanNode) or not budget \
-            or kw.get("mesh") is not None or kw.get("scan_ranges") \
+            or placement_mesh(select, kw.get("mesh")) is not None \
+            or kw.get("scan_ranges") \
             or kw.get("split_rows") is not None:
         return [None]
     from ..connectors import catalog

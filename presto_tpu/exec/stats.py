@@ -205,8 +205,12 @@ class QueryStats:
     stages: Dict[str, StageStats] = dataclasses.field(default_factory=dict)
     operators: Dict[str, OperatorStats] = \
         dataclasses.field(default_factory=dict)
-    # free-form summed counters (exchange collective counts noted at
-    # trace time, cache hits, ...); merged by addition
+    # free-form summed counters (cache hits, capacity reruns, ...; for a
+    # statement over a mesh `mesh_chips`, its program's `exchanges`,
+    # `exchange.<kind>`, `exchange_bytes`, `exchange_slot_bytes`, kept
+    # with the compiled plan and so noted on a plan-cache hit too, and
+    # `exchange_row_bytes`, read beside the status word); merged by
+    # addition
     counters: Dict[str, int] = dataclasses.field(default_factory=dict)
     # per-hop data-path ledger (exec/datapath.py): bytes/wall per hop,
     # merged by HopStats' own sums-add/maxes-max law -- this is how a

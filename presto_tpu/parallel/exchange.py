@@ -17,7 +17,10 @@ All functions here must run INSIDE shard_map over the workers axis.
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+import contextlib
+import math
+import threading
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,10 +29,115 @@ import numpy as np
 from ..block import (Batch, Block, Column, DictionaryColumn, Int128Column,
                      StringColumn)
 from ..expr.functions import combine_hash, hash64_block
+from ..ops.join import _running_sum
 from ..ops.keys import lex_sort
 
 __all__ = ["exchange_by_hash", "exchange_by_range", "broadcast_build",
-           "gather_to_root"]
+           "gather_to_root", "slot_for", "ExchangeLog", "logging_exchanges"]
+
+# A hash exchange's slots start at this share over an even split of the
+# sender's capacity. What matters is that only active rows take a place
+# in a slot: a filter's or a join's dropped rows never leave their chip.
+# Over what is left, a hash spreads rows evenly to well under a percent
+# at the sizes where a slot's size matters (a million rows a destination
+# stray by a thousand), so a quarter is room for a batch that is nearly
+# full; `_SLOT_FLOOR` rows more cover the small tables, where a handful
+# of groups can all hash to one chip. A slot that overflows sets the
+# status word's slot bit and the runner's ladder reruns at twice the
+# slots (clamped at the sender's capacity, where nothing can overflow).
+SLOT_HEADROOM = 1.25
+# A range exchange's splitters come from 64 samples a worker: a range's
+# share lies within tens of percent of even, not within one.
+RANGE_HEADROOM = 2.0
+_SLOT_FLOOR = 64
+
+
+def slot_for(sender_capacity: int, n_workers: int,
+             headroom: float = SLOT_HEADROOM) -> int:
+    """Rows a sender keeps for each destination, sized from its own
+    shard: the receiver's capacity is `n_workers` of these, a little
+    over the sender's, where a slot as large as the sender's whole
+    capacity made it `n_workers` times that."""
+    even = math.ceil(headroom * sender_capacity / max(n_workers, 1))
+    slot = -(-(even + _SLOT_FLOOR) // 8) * 8
+    return max(min(slot, sender_capacity), 1)
+
+
+class ExchangeLog:
+    """What the exchanges of one traced program are: constants of its
+    shapes (how many of each kind, the bytes one chip's collectives
+    move) and, traced, the bytes of rows the hash and range exchanges
+    really routed. `compile_plan` keeps the constants with the
+    `CompiledPlan` by argument shapes, so a statement served by a cached
+    program reports what the one that traced it did."""
+
+    def __init__(self):
+        self.kinds: Dict[str, int] = {}
+        self.moved_bytes = 0      # slots and gathered copies, one chip
+        self.slot_bytes = 0       # the hash and range exchanges' share
+        self.routed: List = []    # traced: active rows routed x row bytes
+
+    def counters(self) -> Dict[str, int]:
+        out = {"exchanges": sum(self.kinds.values()),
+               "exchange_bytes": self.moved_bytes,
+               "exchange_slot_bytes": self.slot_bytes}
+        out.update({f"exchange.{k}": v for k, v in self.kinds.items()})
+        return out
+
+
+_ambient = threading.local()
+
+
+@contextlib.contextmanager
+def logging_exchanges(log: ExchangeLog):
+    """The exchanges lowered inside the block note themselves on `log`."""
+    before = getattr(_ambient, "log", None)
+    _ambient.log = log
+    try:
+        yield log
+    finally:
+        _ambient.log = before
+
+
+def _note_exchange(kind: str, axis_name: str, moved_bytes: int,
+                   routed=None) -> None:
+    """Every exchange notes itself where it is lowered: its kind, the
+    bytes of slots or gathered copies one chip's collective moves (a
+    constant of the shapes) and, for a hash or range exchange, the
+    traced count of bytes of rows it really routes. Under
+    `compile_plan` that goes to the program's `ExchangeLog`, which the
+    compiled plan keeps: a statement reports its program's exchanges
+    (`exchanges`, `exchange.<kind>`, `exchange_bytes`,
+    `exchange_row_bytes`) on a plan-cache hit as on the statement that
+    traced it. An exchange lowered outside a compiled plan
+    (`parallel/stages.py` called by hand) counts its kind on the ambient
+    collector, once, where it is traced."""
+    log: Optional[ExchangeLog] = getattr(_ambient, "log", None)
+    if log is not None:
+        log.kinds[kind] = log.kinds.get(kind, 0) + 1
+        log.moved_bytes += moved_bytes
+        if routed is not None:
+            log.slot_bytes += moved_bytes
+            log.routed.append(routed)
+        return
+    from ..exec.stats import current_collector
+    c = current_collector()
+    if c is not None:
+        c.note(f"exchange.{kind}")
+        c.note("exchanges")
+        # exchange shape is a silent plan decision a post-mortem wants
+        # on the timeline
+        from ..server.flight_recorder import record_event
+        record_event("exchange_shape", query_id=c.query_id,
+                     shape=kind, axis=axis_name)
+
+
+def _row_bytes(batch: Batch) -> int:
+    """Bytes one row of `batch` takes over all its lanes and masks."""
+    return sum(x.dtype.itemsize * int(np.prod(x.shape[1:], dtype=np.int64))
+               for x in jax.tree_util.tree_leaves(batch))
+
+
 
 
 def _row_hash(cols: Sequence[Block]) -> jnp.ndarray:
@@ -62,11 +170,12 @@ def _map_block(b: Block, fn) -> Block:
     return Column(fn(b.values), fn(b.nulls), b.type)
 
 
+@jax.named_scope("exchange_by_hash")
 def exchange_by_hash(batch: Batch, key_channels: Sequence[int], axis_name: str,
                      slot_capacity: int) -> Tuple[Batch, jnp.ndarray]:
     """All-to-all repartition by key hash (call inside shard_map).
 
-    Every worker packs its rows into `n_workers` buckets of
+    Every worker packs its active rows into `n_workers` buckets of
     `slot_capacity` rows each and exchanges bucket i with worker i. The
     returned batch has capacity n_workers * slot_capacity and holds all
     rows whose keys hash to this worker. Also returns an `overflow` flag
@@ -81,49 +190,147 @@ def exchange_by_hash(batch: Batch, key_channels: Sequence[int], axis_name: str,
     h = _row_hash([batch.column(c) for c in key_channels])
     dest = (h % jnp.uint64(n)).astype(jnp.int32)
     dest = jnp.where(batch.active, dest, n)  # inactive rows -> dropped bucket
-    return _route_rows(batch, dest, n, axis_name, slot_capacity)
+    out, overflow, routed = _route_rows(batch, dest, n, axis_name,
+                                        slot_capacity)
+    width = _row_bytes(batch)
+    _note_exchange("hash", axis_name, n * slot_capacity * width,
+                   routed.astype(jnp.int64) * width)
+    return out, overflow
 
 
+_SMALL = {1: jnp.uint8, 2: jnp.uint16}
+
+
+def _pack_lanes(leaves):
+    """The integer and boolean lanes of `leaves` (1-D, one a row) as
+    32-bit words, a row's bits side by side: a 64-bit lane is two words
+    (a 64-bit gather or scatter costs the chip two 32-bit ones anyway),
+    a 32-bit lane one, and the 16-bit, 8-bit and boolean lanes share
+    words, a mask taking a bit. Moving a row is an index a word, where
+    it was an index a lane and a mask (PERF.md, PR 34). Returns the
+    lanes to move (the words, then every leaf that is not packed: floats
+    and the matrices of strings, as they are) and the function that
+    takes the moved lanes apart again."""
+    words, plan, loose = [], [], []
+    small = []  # (leaf index, bits)
+    for i, x in enumerate(leaves):
+        kind, size = x.dtype.kind, x.dtype.itemsize
+        if x.ndim != 1 or kind not in "iub":
+            plan.append(("loose", len(loose)))
+            loose.append(x)
+        elif size == 8:
+            u = jax.lax.bitcast_convert_type(x, jnp.uint64)
+            plan.append(("wide", len(words)))
+            words.append((u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
+            words.append((u >> jnp.uint64(32)).astype(jnp.uint32))
+        elif size == 4:
+            plan.append(("word", len(words)))
+            words.append(jax.lax.bitcast_convert_type(x, jnp.uint32))
+        else:
+            plan.append(None)  # placed below
+            small.append((i, 1 if kind == "b" else 8 * size))
+    # first fit, widest first: a word holds 32 bits of small lanes
+    room: List[int] = []      # bits taken in each shared word
+    shared: List = []         # the shared words, built up by OR
+    for i, bits in sorted(small, key=lambda f: -f[1]):
+        x = leaves[i]
+        u = x.astype(jnp.uint32) if x.dtype.kind == "b" else \
+            jax.lax.bitcast_convert_type(
+                x, _SMALL[x.dtype.itemsize]).astype(jnp.uint32)
+        k = next((k for k, taken in enumerate(room) if taken + bits <= 32),
+                 len(room))
+        if k == len(room):
+            room.append(0)
+            shared.append(jnp.zeros_like(u))
+        shared[k] = shared[k] | (u << jnp.uint32(room[k]))
+        plan[i] = ("bits", k, room[k], bits)
+        room[k] += bits
+    n_words = len(words)
+    lanes = words + shared + loose
+
+    def unpack(moved):
+        out = []
+        for x, how in zip(leaves, plan):
+            if how[0] == "loose":
+                out.append(moved[n_words + len(shared) + how[1]])
+            elif how[0] == "wide":
+                lo, hi = moved[how[1]], moved[how[1] + 1]
+                u = lo.astype(jnp.uint64) | (hi.astype(jnp.uint64)
+                                             << jnp.uint64(32))
+                out.append(jax.lax.bitcast_convert_type(u, x.dtype))
+            elif how[0] == "word":
+                out.append(jax.lax.bitcast_convert_type(moved[how[1]],
+                                                        x.dtype))
+            else:
+                _, k, at, bits = how
+                u = (moved[n_words + k] >> jnp.uint32(at)) \
+                    & jnp.uint32((1 << bits) - 1)
+                if x.dtype.kind == "b":
+                    out.append(u != 0)
+                else:
+                    out.append(jax.lax.bitcast_convert_type(
+                        u.astype(_SMALL[x.dtype.itemsize]), x.dtype))
+        return out
+
+    return lanes, unpack
+
+
+def _slot_places(dest: jnp.ndarray, n: int, slot_capacity: int):
+    """Where each row goes in a send buffer of `n` slots: its
+    destination's slot, at its rank among the rows before it that go
+    the same way (a running sum a destination, `ops/join._running_sum`:
+    no sort). A row that stays behind (dest == n, or beyond a full
+    slot) gets an index of its own past the buffer's end, which a
+    scatter in "drop" mode leaves out. Also the overflow flag and the
+    rows placed (int32)."""
+    place = n * slot_capacity + jnp.arange(dest.shape[0], dtype=jnp.int32)
+    overflow = jnp.zeros((), dtype=bool)
+    routed = jnp.zeros((), dtype=jnp.int32)
+    for d in range(n):
+        to_d = dest == d
+        rank = _running_sum(to_d.astype(jnp.int32))  # 1 for the first
+        count = rank[-1]
+        overflow = overflow | (count > slot_capacity)
+        routed = routed + jnp.minimum(count, slot_capacity)
+        place = jnp.where(to_d & (rank <= slot_capacity),
+                          d * slot_capacity + rank - 1, place)
+    return place, overflow, routed
+
+
+@jax.named_scope("_route_rows")
 def _route_rows(batch: Batch, dest: jnp.ndarray, n, axis_name: str,
-                slot_capacity: int) -> Tuple[Batch, jnp.ndarray]:
+                slot_capacity: int):
     """Pack rows into per-destination send slots and all_to_all them.
     `dest` is an int32 per-row destination in [0, n); rows with dest == n
     are dropped (inactive). Shared data plane of the hash and range
-    exchanges."""
+    exchanges. Returns the received batch (capacity n * slot_capacity),
+    the overflow flag and the rows this chip routed (int32).
+
+    A row's place comes from `_slot_places`; the batch's lanes and
+    masks are packed into 32-bit words (`_pack_lanes`) and each word is
+    scattered once into the send buffer."""
     cap = batch.capacity
-    # slot within destination bucket: rank among same-dest rows
-    order = jax.lax.sort([dest, jnp.arange(cap, dtype=jnp.int32)], num_keys=1)
-    s_dest, perm = order
-    bucket_start = jnp.searchsorted(s_dest, jnp.arange(n + 1, dtype=jnp.int32))
-    pos_in_sorted = jnp.arange(cap, dtype=jnp.int32)
-    slot = pos_in_sorted - bucket_start[jnp.clip(s_dest, 0, n)]
-    counts = bucket_start[1:] - bucket_start[:-1]  # per-dest counts (n,)
-    overflow = jnp.any(counts > slot_capacity)
-
     send_rows = n * slot_capacity
-    flat = jnp.clip(s_dest, 0, n - 1) * slot_capacity + jnp.clip(slot, 0, slot_capacity - 1)
-    keep = (s_dest < n) & (slot < slot_capacity)
-    # dropped/overflowed rows park in an extra scratch slot that is
-    # sliced away -- never a real slot (scatter order is unspecified)
-    idx = jnp.where(keep, flat, send_rows)
+    place, overflow, routed = _slot_places(dest, n, slot_capacity)
 
-    def pack(arr):
-        # arr: (cap, ...) in original row order -> (send_rows, ...) bucketed
-        src = arr[perm]
-        zeros = jnp.zeros((send_rows + 1,) + arr.shape[1:], dtype=arr.dtype)
-        return zeros.at[idx].set(src)[:send_rows]
+    leaves, treedef = jax.tree_util.tree_flatten(batch.with_active(
+        jnp.ones(cap, dtype=bool)))
+    lanes, unpack = _pack_lanes(leaves)
 
-    sent_active = jnp.zeros(send_rows + 1, dtype=bool).at[idx].set(True)[:send_rows]
+    def move(lane):
+        sent = jnp.zeros((send_rows,) + lane.shape[1:], dtype=lane.dtype) \
+            .at[place].set(lane, mode="drop", unique_indices=True)
+        return jax.lax.all_to_all(sent, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=True)
 
-    def a2a(arr):
-        return jax.lax.all_to_all(arr, axis_name, split_axis=0, concat_axis=0,
-                                  tiled=True)
-
-    new_cols = tuple(_map_block(c, lambda a: a2a(pack(a))) for c in batch.columns)
-    new_active = a2a(sent_active)
-    return Batch(new_cols, new_active), overflow
+    # the `active` lane was set to ones before packing: a place that no
+    # row was scattered to reads 0 in every lane, so it arrives inactive
+    out = jax.tree_util.tree_unflatten(
+        treedef, unpack([move(lane) for lane in lanes]))
+    return out, overflow, routed
 
 
+@jax.named_scope("exchange_by_range")
 def exchange_by_range(batch: Batch, sort_keys, axis_name: str,
                       slot_capacity: int,
                       samples_per_worker: int = 64
@@ -176,22 +383,38 @@ def exchange_by_range(batch: Batch, sort_keys, axis_name: str,
         ge = (r > sv) | ((r == sv) & ge)
     dest = jnp.sum(ge, axis=0, dtype=jnp.int32)
     dest = jnp.where(batch.active, dest, n)
-    return _route_rows(batch, dest, n, axis_name, slot_capacity)
+    out, overflow, routed = _route_rows(batch, dest, n, axis_name,
+                                        slot_capacity)
+    width = _row_bytes(batch)
+    _note_exchange("range", axis_name, n * slot_capacity * width,
+                   routed.astype(jnp.int64) * width)
+    return out, overflow
 
 
-def broadcast_build(batch: Batch, axis_name: str) -> Batch:
-    """Replicate a (typically small) build-side batch to every worker:
-    the FIXED_BROADCAST_DISTRIBUTION / BroadcastOutputBuffer analog, as
-    an all_gather over ICI. Output capacity = n_workers * capacity."""
+def _all_gather(batch: Batch, axis_name: str) -> Batch:
     def ag(arr):
-        g = jax.lax.all_gather(arr, axis_name, axis=0, tiled=True)
-        return g
+        return jax.lax.all_gather(arr, axis_name, axis=0, tiled=True)
     cols = tuple(_map_block(c, ag) for c in batch.columns)
     return Batch(cols, ag(batch.active))
 
 
+@jax.named_scope("broadcast_build")
+def broadcast_build(batch: Batch, axis_name: str) -> Batch:
+    """Replicate a (typically small) build-side batch to every worker:
+    the FIXED_BROADCAST_DISTRIBUTION / BroadcastOutputBuffer analog, as
+    an all_gather over ICI. Output capacity = n_workers * capacity."""
+    n = jax.lax.psum(1, axis_name)
+    _note_exchange("broadcast", axis_name,
+                   n * batch.capacity * _row_bytes(batch))
+    return _all_gather(batch, axis_name)
+
+
+@jax.named_scope("gather_to_root")
 def gather_to_root(batch: Batch, axis_name: str) -> Batch:
     """Gather all workers' rows everywhere (root picks its copy): the
     single-node SINGLE_DISTRIBUTION output stage / coordinator result
     fetch analog."""
-    return broadcast_build(batch, axis_name)
+    n = jax.lax.psum(1, axis_name)
+    _note_exchange("gather", axis_name,
+                   n * batch.capacity * _row_bytes(batch))
+    return _all_gather(batch, axis_name)

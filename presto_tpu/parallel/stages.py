@@ -21,32 +21,10 @@ import jax.numpy as jnp
 from ..block import Batch
 from ..ops.aggregation import AggSpec, GroupByResult, group_by, merge_partials
 from ..ops.join import JoinResult, hash_join
-from .exchange import broadcast_build, exchange_by_hash
+from .exchange import broadcast_build, exchange_by_hash, gather_to_root
 from .mesh import WORKERS_AXIS
 
 __all__ = ["distributed_group_by", "distributed_hash_join", "two_stage_group_by"]
-
-
-def _note_exchange(kind: str, axis_name: str) -> None:
-    """Trace-time telemetry: these helpers run under jit, so per-
-    exchange wall time is fused away by design -- what IS host-visible
-    is the program's exchange structure at trace time. Each lowered
-    collective bumps a QueryStats counter on the ambient collector
-    (exec/stats.py), so EXPLAIN ANALYZE / the coordinator can report
-    how many hash / broadcast / gather exchanges one SPMD program
-    contains. Cache-hit dispatches skip tracing and report none (the
-    structure was already attributed to the compiling query)."""
-    from ..exec.stats import current_collector
-    c = current_collector()
-    if c is not None:
-        c.note(f"exchange.{kind}")
-        c.note("exchanges")
-        # exchange shape is a silent plan decision a post-mortem wants
-        # on the timeline; trace-time only (cache hits skip it), so the
-        # cost is one ring append per lowered collective
-        from ..server.flight_recorder import record_event
-        record_event("exchange_shape", query_id=c.query_id,
-                     shape=kind, axis=axis_name)
 
 
 def distributed_group_by(shard: Batch, key_channels: Sequence[int],
@@ -61,7 +39,6 @@ def distributed_group_by(shard: Batch, key_channels: Sequence[int],
     nkeys = len(key_channels)
     if slot_capacity is None:
         slot_capacity = max_groups
-    _note_exchange("hash", axis_name)
     ex, ex_overflow = exchange_by_hash(part.batch, list(range(nkeys)),
                                        axis_name, slot_capacity)
     final = merge_partials(ex, nkeys, aggs, max_groups)
@@ -79,8 +56,7 @@ def two_stage_group_by(shard: Batch, key_channels: Sequence[int],
     replicated -- the coordinator-facing root stage shape."""
     final, overflow = distributed_group_by(shard, key_channels, aggs,
                                            max_groups, axis_name)
-    _note_exchange("gather", axis_name)
-    gathered = broadcast_build(final.batch, axis_name)
+    gathered = gather_to_root(final.batch, axis_name)
     nkeys = len(key_channels)
     # merge the per-worker disjoint tables into one dense table (no key
     # collisions across workers; merge combinators are idempotent over
@@ -106,17 +82,14 @@ def distributed_hash_join(probe_shard: Batch, build_shard: Batch,
     """
     overflow = jnp.zeros((), dtype=bool)
     if strategy == "broadcast":
-        _note_exchange("broadcast", axis_name)
         build_all = broadcast_build(build_shard, axis_name)
         res = hash_join(probe_shard, build_all, probe_keys, build_keys,
                         out_capacity, join_type, build_output_channels)
     else:
         if slot_capacity is None:
             slot_capacity = probe_shard.capacity
-        _note_exchange("hash", axis_name)
         p_ex, p_ovf = exchange_by_hash(probe_shard, probe_keys, axis_name,
                                        slot_capacity)
-        _note_exchange("hash", axis_name)
         b_ex, b_ovf = exchange_by_hash(build_shard, build_keys, axis_name,
                                        slot_capacity)
         overflow = p_ovf | b_ovf

@@ -28,8 +28,12 @@ Distribution rules (cost-based join choice per ROADMAP):
   * Window / RowNumber unpartitioned
                                 -> GATHER -> op (single-node semantics)
   * MarkDistinct                -> REPARTITION(keys) -> MarkDistinct
-  * Join                        -> distribution=broadcast (build side is
-                                   all_gathered by the lowering)
+  * Join                        -> AUTOMATIC (the default): a build side
+                                   estimated at or under
+                                   _BROADCAST_ROW_LIMIT rows is
+                                   replicated (all_gather), a larger or
+                                   unknown one repartitions with its
+                                   probe on the join keys (all_to_all)
   * SemiJoin                    -> filtering side broadcast (lowering)
 """
 
@@ -55,8 +59,7 @@ def split_single_agg(agg: "N.AggregationNode",
     kind = exchange_kind or ("REPARTITION" if nkeys else "GATHER")
     if kind == "REPARTITION":
         ex = N.ExchangeNode(partial, kind="REPARTITION", scope="REMOTE",
-                            partition_channels=list(range(nkeys)),
-                            slot_capacity=agg.max_groups)
+                            partition_channels=list(range(nkeys)))
     else:
         ex = N.ExchangeNode(partial, kind="GATHER", scope="REMOTE")
     return N.AggregationNode(ex, list(range(nkeys)), agg.aggregates,
@@ -100,13 +103,13 @@ _BROADCAST_ROW_LIMIT = 1 << 20
 def add_exchanges(node: N.PlanNode,
                   join_strategy: str = "broadcast",
                   sf: float = None) -> N.PlanNode:
-    """join_strategy: "broadcast" replicates every build side (the safe
-    default); "partitioned" repartitions BOTH join sides by the join
-    keys (DetermineJoinDistributionType's PARTITIONED choice -- right
-    for large builds); "automatic" decides per join from connector
+    """join_strategy: "broadcast" replicates every build side;
+    "partitioned" repartitions BOTH join sides by the join keys
+    (DetermineJoinDistributionType's PARTITIONED choice -- right for
+    large builds); "automatic" decides per join from connector
     statistics (DetermineJoinDistributionType.java's AUTOMATIC with a
-    row-count cost model) and needs `sf` for the row estimates --
-    without it, unknown-size builds fall back to broadcast."""
+    row-count cost model) and needs `sf` for the row estimates: a build
+    whose size it cannot estimate repartitions."""
     return _visit(node, join_strategy, order_root=True, under=None, sf=sf,
                   memo={})
 
@@ -172,8 +175,7 @@ def _rewrite(node: N.PlanNode, join_strategy: str, order_root: bool,
         if _is_repartition_on(node.source, keys):
             return node
         ex = N.ExchangeNode(node.source, kind="REPARTITION", scope="REMOTE",
-                            partition_channels=keys,
-                            slot_capacity=node.max_groups)
+                            partition_channels=keys)
         return _dc.replace(node, source=ex)
 
     if isinstance(node, N.SortNode):
@@ -231,16 +233,17 @@ def _rewrite(node: N.PlanNode, join_strategy: str, order_root: bool,
             # DetermineJoinDistributionType
             strategy = "partitioned"
         if strategy == "automatic":
-            # cost model: broadcast only when the build side is provably
-            # small (its replicated copy must fit every worker); unknown
-            # sizes (or no sf to cost with) default to broadcast,
-            # matching the pre-CBO behavior
-            strategy = "broadcast"
-            if sf is not None:
-                from .stats import estimate_rows
-                build = estimate_rows(node.right, sf)
-                if build is not None and build > _BROADCAST_ROW_LIMIT:
-                    strategy = "partitioned"
+            # cost model: broadcast only where the build side is
+            # estimated small (its replicated copy has to fit every
+            # worker); a build of unknown size repartitions, since a
+            # wrong broadcast at scale is an out-of-memory and a wrong
+            # repartition is only slower. A join without keys has
+            # nothing to repartition by
+            strategy = "partitioned" if node.left_keys else "broadcast"
+            from .stats import estimate_rows
+            build = estimate_rows(node.right, sf) if sf is not None else None
+            if build is not None and build <= _BROADCAST_ROW_LIMIT:
+                strategy = "broadcast"
         if strategy == "partitioned":
             # repartition BOTH sides by the join keys: consumers then see
             # co-partitioned inputs and join locally (the large-build

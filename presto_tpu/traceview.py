@@ -229,13 +229,16 @@ SPAN = "presto:"          # exec/stats.py's annotation prefix
 OPS_LINE = "XLA Ops"      # the device plane's line of executed ops
 MODULES_LINE = "XLA Modules"  # ... and of the programs that held them
 TOP = 24                  # rows of a printed ranking
-# the ``ops/`` functions that open a jax.named_scope
-# (tests/test_traceview_xplane.py holds this list to the source)
+# the ``ops/`` and ``parallel/`` functions that open a jax.named_scope
+# (tests/test_traceview_xplane.py holds this list to the source): the
+# operators' kernels, and a meshed program's exchanges
 OPS_SCOPES = frozenset([
     "lex_sort", "hash_join", "_sort_build", "_pack_ranks",
     "_match_ranges", "_slot_rows", "_compact_probe", "semi_join_mask",
     "_group_ids_hash", "_group_ids_sort", "_group_by_sorted", "top_n",
-    "limb_partial_sums"])
+    "limb_partial_sums",
+    "exchange_by_hash", "exchange_by_range", "_route_rows",
+    "broadcast_build", "gather_to_root"])
 _NODE = re.compile(r"[A-Za-z]+Node\.\d+$")
 
 

@@ -193,7 +193,7 @@ def test_format_picks_the_module_and_the_old_names_still_reach_it(warehouse):
     ("CREATE TABLE hive.x WITH (bucket_count = 4) AS SELECT 1 AS a",
      "no table property 'bucket_count'"),
     ("CREATE TABLE memory.x WITH (format = 'ORC') AS SELECT 1 AS a",
-     "takes no table properties"),
+     "catalog 'memory' has no table property 'format'"),
 ])
 def test_an_unknown_property_or_format_is_an_error(warehouse, text, says):
     with pytest.raises(ValueError, match=says):

@@ -117,9 +117,11 @@ def test_automatic_join_distribution_uses_row_estimates():
     small = join_of(add_exchanges(root, join_strategy="automatic", sf=0.01))
     assert big.distribution == "partitioned"
     assert small.distribution == "broadcast"
-    # without sf, AUTOMATIC cannot cost anything -> safe broadcast
+    # without sf, AUTOMATIC cannot cost anything: a build of unknown
+    # size repartitions (a wrong broadcast at scale is an OOM, a wrong
+    # repartition is only slower)
     unk = join_of(add_exchanges(root, join_strategy="automatic"))
-    assert unk.distribution == "broadcast"
+    assert unk.distribution == "partitioned"
 
 
 def test_unknown_columns_keep_default_capacity():

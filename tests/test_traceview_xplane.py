@@ -27,16 +27,20 @@ def test_scope_of_keeps_what_the_program_named(op_name, scope):
     assert tv.scope_of(op_name) == scope
 
 
-def test_ops_scopes_are_the_named_scopes_of_ops():
-    ops = os.path.join(os.path.dirname(tv.__file__), "ops")
+def test_ops_scopes_are_the_named_scopes_of_ops_and_exchanges():
     found = set()
-    for name in os.listdir(ops):
-        if name.endswith(".py"):
-            with open(os.path.join(ops, name)) as f:
-                text = f.read()
-            found |= set(re.findall(r'named_scope\("([^"]+)"\)', text))
-            found |= set(re.findall(r'\bname="([a-z_]+)",\n\s*\)\(', text))
+    for package in ("ops", "parallel"):
+        there = os.path.join(os.path.dirname(tv.__file__), package)
+        for name in os.listdir(there):
+            if name.endswith(".py"):
+                with open(os.path.join(there, name)) as f:
+                    text = f.read()
+                found |= set(re.findall(r'named_scope\("([^"]+)"\)', text))
+                found |= set(re.findall(r'\bname="([a-z_]+)",\n\s*\)\(',
+                                        text))
     assert found == set(tv.OPS_SCOPES)
+    assert {"exchange_by_hash", "_route_rows", "broadcast_build",
+            "exchange_by_range"} <= found
 
 
 def test_scope_seconds_gives_an_op_its_own_time():
