@@ -515,7 +515,7 @@ def _stage_and_time(host_cols, columns, capacity, pipeline_fn, iters,
     fn = (lambda b: pipeline_fn([b])) if wrap_seq else pipeline_fn
     run = jax.jit(fn)
     warm = jax.device_get(run(batch))  # warm-up / compile + round trip
-    if wrap_seq and int(np.asarray(warm[1])) != 0:
+    if wrap_seq and int(np.asarray(warm[1]).reshape(-1)[0]) != 0:
         raise RuntimeError("benchmark plan overflowed a static capacity; "
                            "timing would measure garbage")
 

@@ -851,7 +851,8 @@ class BatchingExecutor:
                 jax.block_until_ready(out)
             finally:
                 prog.release(state="FINISHED")
-            flags, steps, compacted = split_flags(np.asarray(overflow))
+            flags, steps, compacted = split_flags(
+                plan.split_status(overflow)[0])
             if int(flags.max()) != 0:
                 # a member overflowed a static bucket: the serial
                 # ladder owns adaptive reruns; collapse the whole batch

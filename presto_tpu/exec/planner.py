@@ -26,6 +26,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .. import types as T
@@ -42,6 +43,7 @@ from ..parallel.exchange import (RANGE_HEADROOM, SLOT_HEADROOM, ExchangeLog,
                                  logging_exchanges, slot_for)
 from ..parallel.mesh import WORKERS_AXIS
 from ..plan import nodes as N
+from ..plan.stats import capacities, is_counted, preorder, preorder_index
 
 __all__ = ["compile_plan", "CompiledPlan", "split_flags"]
 
@@ -64,7 +66,8 @@ def split_flags(word):
 
 @dataclasses.dataclass
 class CompiledPlan:
-    """fn(scans: Dict[node_id, Batch]) -> (Batch, status word).
+    """fn(scans: Dict[node_id, Batch]) -> (Batch, status): the status
+    word, or the vector `split_status` takes apart.
     `scan_nodes` lists the TableScanNode/ValuesNode leaves in the order
     their batches must be supplied; distributed plans expect each scan
     batch shard-able along axis 0 by the mesh."""
@@ -98,6 +101,27 @@ class CompiledPlan:
     traced_exchanges: Optional[Dict[str, int]] = None
     exchanges: Dict[tuple, Optional[Dict[str, int]]] = dataclasses.field(
         default_factory=dict)
+
+    # pre-order index -> the capacity this program was built with, for
+    # the nodes whose need it counts (`plan.stats.is_counted`: a join's
+    # output rows, a keyed aggregation's groups); the status carries
+    # one need a node, in the keys' order
+    counted: Dict[int, int] = dataclasses.field(default_factory=dict)
+
+    def split_status(self, status):
+        """What `fn` returns beside its batch, on the host: (status
+        word, exchange_row_bytes, {pre-order index: need}). A program
+        with neither a mesh nor a counted node returns the word alone;
+        else one int64 vector: the word, under a mesh the bytes of rows
+        its exchanges routed, then the needs. A vmapped program returns
+        a row of that per member: the word and the needs are then
+        arrays along the members."""
+        status = np.asarray(status)
+        if not self.distributed and not self.counted:
+            return status, 0, {}
+        at = 2 if self.distributed else 1
+        return (status[..., 0], status[..., 1] if self.distributed else 0,
+                {k: status[..., at + i] for i, k in enumerate(self.counted)})
 
     def exchanges_of(self, batches) -> Optional[Dict[str, int]]:
         """The exchange counters of a dispatch of `fn` on `batches`
@@ -134,21 +158,6 @@ def _collect_scans(node: N.PlanNode, out: List[N.PlanNode], _seen=None):
         _collect_scans(s, out, _seen)
 
 
-def _preorder(root: N.PlanNode) -> Dict[int, int]:
-    """id(node) -> its structural pre-order index (a shared subtree
-    counts once, where it is first met): stable across plannings of one
-    SQL text, which `node.id`, a process-wide counter, is not."""
-    index: Dict[int, int] = {}
-
-    def walk(n):
-        if id(n) not in index:
-            index[id(n)] = len(index)
-            for s in n.sources:
-                walk(s)
-    walk(root)
-    return index
-
-
 def compile_plan(root: N.PlanNode, mesh=None,
                  default_join_capacity: int = 1 << 16,
                  exchange_slot_scale: int = 1) -> CompiledPlan:
@@ -173,7 +182,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
     not depend on them."""
     scans: List[N.PlanNode] = []
     _collect_scans(root, scans)
-    order = _preorder(root)
+    order = preorder_index(root)  # stable across plannings: plan/stats.py
     axis = WORKERS_AXIS
     dist = mesh is not None
 
@@ -216,6 +225,13 @@ def compile_plan(root: N.PlanNode, mesh=None,
                 r = group_by(src, node.group_channels, node.aggregates,
                              node.max_groups)
             _note_overflow(r.overflow)
+            if is_counted(node):
+                # a kernel whose count stops at its table says "four
+                # times the capacity" when it overflows: the ladder's
+                # step before it had counts
+                needs[order[id(node)]] = r.num_groups if r.counted \
+                    else jnp.where(r.overflow, jnp.maximum(
+                        r.num_groups, 4 * node.max_groups), r.num_groups)
             out = r.batch
             if node.step in ("SINGLE", "FINAL"):
                 out = finalize_states(out, len(node.group_channels),
@@ -258,6 +274,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
             r = hash_join(probe, build, node.left_keys, node.right_keys,
                           cap, node.join_type, node.right_output_channels)
             _note_overflow(r.overflow)
+            needs[order[id(node)]] = r.num_rows
             search_steps.append(r.search_steps)
             expand_steps.append(r.expand_steps)
             compacted.append(r.compacted)
@@ -420,6 +437,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
     search_steps: List = []  # one trip count per join lookup lowered
     expand_steps: List[int] = []  # one per join expansion lowered
     compacted: List = []  # one 0/1 per join: its probe was compacted
+    needs: Dict[int, jax.Array] = {}  # pre-order index -> rows needed
     _lower_memo: Dict[int, Batch] = {}
 
     def _note_overflow(flag, scalable: bool = False):
@@ -433,6 +451,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
         search_steps.clear()
         expand_steps.clear()
         compacted.clear()
+        needs.clear()
         _lower_memo.clear()
         inputs = {n.id: b for n, b in zip(scans, scan_batches)}
         log = ExchangeLog()
@@ -464,16 +483,32 @@ def compile_plan(root: N.PlanNode, mesh=None,
         word = (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
                 + (steps << FLAG_BITS)
                 + (took << (FLAG_BITS + STEP_BITS)))
-        if not dist:
-            return out, word
-        # under a mesh one more scalar rides beside the word, still one
-        # host read: the bytes of rows the hash and range exchanges
-        # routed, a chip's mean (the counter exchange_row_bytes)
-        routed = sum(log.routed, jnp.zeros((), dtype=jnp.int64))
-        routed = jax.lax.psum(routed, axis) // n_workers
-        return out, jnp.stack([word.astype(jnp.int64), routed])
+        # beside the word, still one host read (`split_status`): what
+        # each counted node needed, its join's output rows or its
+        # groups, which the ladder sizes the node from; a capacity is a
+        # shard's shape, so under a mesh the largest shard's
+        if not dist and not plan.counted:
+            return out, word  # the parent's program, op for op
+        status = [word.astype(jnp.int64)]
+        counts = [needs[k].astype(jnp.int64) for k in plan.counted]
+        if dist:
+            # under a mesh one more scalar rides beside the word: the
+            # bytes of rows the hash and range exchanges routed, a
+            # chip's mean (the counter exchange_row_bytes)
+            routed = sum(log.routed, jnp.zeros((), dtype=jnp.int64))
+            status.append(jax.lax.psum(routed, axis) // n_workers)
+            # XLA:TPU reduces 64-bit lanes by sum alone: the maximum is
+            # taken in 32 bits, and a need that does not fit them is
+            # far past every capacity's ceiling anyway
+            counts = [jax.lax.pmax(jnp.minimum(c, (1 << 31) - 1).astype(
+                jnp.int32), axis).astype(jnp.int64) for c in counts]
+        return out, jnp.stack(status + counts)
 
     plan = CompiledPlan(run, scans, root.output_types(), dist, root)
+    nodes = preorder(root)
+    plan.counted = {
+        k: c for k, c in capacities(root, default_join_capacity).items()
+        if is_counted(nodes[k])}
     if dist:
         in_specs = tuple(P(WORKERS_AXIS) for _ in scans)
         plan.fn = jax.shard_map(run, mesh=mesh, in_specs=(in_specs,),

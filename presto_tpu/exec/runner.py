@@ -883,7 +883,7 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     memory_pool, plan_fp_root=plan_fingerprint(root),
                     sf=sf)
             else:
-                (out, device_s, dispatch_fn, call_lock, cap_scale,
+                (out, device_s, dispatch_fn, call_lock, ran_caps,
                  scale, plan) = _dispatch_ladder(
                     root, plan, jfn, call_lock, batches, mesh,
                     default_join_capacity, use_cache, fp, stats,
@@ -909,10 +909,10 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         if session_flag(session, "query_cost_analysis", False) \
                 and not multi_region:
             fp_cost = fp if fp is not None else plan_fingerprint(root)
-            # cap_scale distinguishes the scaled rerun's program from
-            # the unscaled one (same fingerprint + shapes otherwise)
+            # the capacities tell a rerun's or a refit's program from
+            # the plan's own (same fingerprint + shapes otherwise)
             cost = _stage_cost(dispatch_fn, batches,
-                               (fp_cost, cap_scale, scale), call_lock)
+                               (fp_cost, ran_caps, scale), call_lock)
             if cost:
                 collector.bump_stage("compile", **cost)
                 stats.add("xla_flops", cost["flops"])
@@ -971,13 +971,22 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
 
 
 # adaptive-capacity feedback (HBO-lite, HistoryBasedPlanStatistics
-# analog): plan fingerprint -> the capacity scale that made it fit, and
-# under a mesh the exchange-slot scale beside it.
+# analog, kept per plan node as upstream's is): plan fingerprint ->
+# {pre-order index: the capacity the node last fitted at}, and under a
+# mesh the exchange-slot scale beside it. A counted node's capacity
+# comes from the count its program reported (`plan/stats.py`).
 # Bounded process-local memory; structurally identical future
-# submissions start at the known-good size instead of re-laddering.
-_CAPACITY_FEEDBACK: Dict[str, int] = {}
+# submissions start at the known-good sizes instead of re-laddering.
+_CAPACITY_FEEDBACK: Dict[str, Dict[int, int]] = {}
 _SLOT_FEEDBACK: Dict[str, int] = {}
-_MAX_CAPACITY_SCALE = 1 << 10
+# a ladder that has not fitted after this many reruns gives up (every
+# rerun sizes at least one node from its count or grows all four times)
+_MAX_CAPACITY_RERUNS = 12
+# a plan that fitted is compiled and run again at its fitted capacities
+# within the statement only where that frees this share of the capacity
+# rows that ran: a program is not built again for less
+_REFIT_SHARE = 8
+_MAX_CAPACITY_SCALE = 1 << 10  # the mesh ladder's start stops here
 # Over a mesh the join and group ladder starts where the capacities
 # reach this share of the largest scan's rows a chip, not at the
 # default: every rung is one more SPMD program to compile (minutes each
@@ -1001,25 +1010,25 @@ def _mesh_ladder_start(batches, mesh, default_join_capacity: int) -> int:
     return scale
 
 
-def _read_status(word, expand_steps: Optional[int]) -> Tuple[int, int]:
-    """The one host read of the word a program returns beside its batch:
-    its overflow flags come back, the trips its joins' lookups took go
-    to the statement's counters, and with them `expand_steps`, the
-    trips of its joins' expansions (`CompiledPlan.expand_steps_of`:
-    None for a program without a join), and how many of its joins
-    compacted their probe. A program over a mesh returns a second
-    scalar beside the word, the bytes of rows its exchanges routed (a
-    chip's mean), which comes back with the flags."""
-    status = np.asarray(word)
-    routed = int(status[1]) if status.ndim else 0
-    flags, steps, compacted = split_flags(
-        int(status[0]) if status.ndim else int(status))
+def _read_status(status, plan, expand_steps: Optional[int]
+                 ) -> Tuple[int, int, Dict[int, int]]:
+    """The one host read of what a program returns beside its batch
+    (`CompiledPlan.split_status`): its overflow flags come back, the
+    trips its joins' lookups took go to the statement's counters, and
+    with them `expand_steps`, the trips of its joins' expansions
+    (`CompiledPlan.expand_steps_of`: None for a program without a
+    join), and how many of its joins compacted their probe. With the
+    flags come the bytes of rows a meshed program's exchanges routed (a
+    chip's mean) and what each counted node needed, by pre-order
+    index."""
+    word, routed, needs = plan.split_status(status)
+    flags, steps, compacted = split_flags(int(word))
     if steps:
         note("join_search_steps", steps)
     if expand_steps is not None:  # 0 too: a join whose table is its
         note("join_expand_steps", expand_steps)  # own directory
         note("join_probe_compacted", compacted)
-    return flags, routed
+    return flags, int(routed), {k: int(v) for k, v in needs.items()}
 
 
 def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
@@ -1052,6 +1061,13 @@ def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
     return plan.hbm_bytes[key]
 
 
+def _worth_refit(ran: Dict[int, int], fitted: Dict[int, int]) -> bool:
+    """Whether a plan that fitted at `ran` is built again at `fitted`:
+    where that frees 1 / `_REFIT_SHARE` of the capacity rows or more."""
+    total = sum(ran.values())
+    return (total - sum(fitted.values())) * _REFIT_SHARE >= total > 0
+
+
 def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                      mesh, default_join_capacity: int, use_cache: bool,
                      fp: Optional[str], stats, adaptive_off: bool,
@@ -1062,38 +1078,63 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
     Exchange-slot overflow (flag bit1) -> rerun with geometrically
     larger slots; slots clamp at the sender capacity, where overflow is
     impossible, so this converges. Join/group overflow (bit0) reruns
-    with geometrically larger capacities up to the adaptive ceiling.
-    This is the memory-feedback loop the reference runs as
-    reserve/revoke -- here it recompiles with bigger static buckets
+    with larger capacities: a node whose count passed its capacity is
+    sized from the count, the nodes above it and those that report a
+    flag alone grow four times (`plan/stats.grown_capacities`), up to
+    the ceilings. This is the memory-feedback loop the reference runs
+    as reserve/revoke -- here it recompiles with bigger static buckets
     instead. Under the region executor only the overflowing REGION
     re-dispatches; upstream regions' materialized outputs are reused.
+
+    A dispatch that fitted has exact counts: where the ladder had
+    taken the plan above its own capacities and it ran with much more
+    room than its nodes needed (`_worth_refit`: the fourfold steps, the
+    start under a mesh), the plan is built once more at the fitted
+    capacities and run within the same statement (counter
+    ``capacity_refits``), so that the fingerprint's next statement
+    finds the fitted program compiled; the answer is the same rows
+    either way. A plan that fitted as planned is left as planned: a
+    statement that never overflowed pays for no second program. The
+    capacities that fitted are remembered per node
+    (`_CAPACITY_FEEDBACK`); should the data outgrow them, the flags
+    rerun as ever. ``adaptive_capacity=false`` runs the plan as
+    planned: no feedback, no rerun, no refit.
 
     Each turn is two stages of the ambient collector, children of
     ``execute`` with the region's `tag`: ``dispatch`` (the call of the
     jitted program until it returns: flatten, jit-cache lookup,
     trace/lower/compile or cache read on a miss, enqueue) and
-    ``device_wait`` (block_until_ready plus the overflow flag's read).
+    ``device_wait`` (block_until_ready plus the status read).
 
-    Returns (out, device_s, dispatch_fn, call_lock, cap_scale, scale,
-    plan)."""
+    Returns (out, device_s, dispatch_fn, call_lock, the capacities that
+    ran as a hashable, scale, plan)."""
+    from ..plan.stats import (capacities, fitted_capacities,
+                              grown_capacities, scaled_capacities,
+                              with_capacities)
     device_s = 0.0
     scale = _SLOT_FEEDBACK.get(fp, 1) if fp and mesh is not None else 1
-    cap_scale = _CAPACITY_FEEDBACK.get(fp, 1) if fp else 1
-    if mesh is not None and cap_scale == 1:
-        cap_scale = _mesh_ladder_start(batches, mesh, default_join_capacity)
-    exec_root = root if cap_scale == 1 else None  # set below
-    if cap_scale > 1 or scale > 1:
-        # HBO-lite: a structurally identical plan overflowed before;
-        # start from the capacities that worked
-        from ..plan.stats import scale_capacities
-        exec_root = scale_capacities(root, cap_scale)
-        plan, jfn, call_lock = _compile_any(
-            exec_root, mesh, default_join_capacity * cap_scale,
-            scale, use_cache)
-        stats.add("capacity_feedback_scale", cap_scale)
+    adapt = bool(fp) and not adaptive_off
+    base = capacities(root, default_join_capacity)
+    caps = _CAPACITY_FEEDBACK.get(fp) if adapt else None
+    if caps is None:
+        caps = base
+        if mesh is not None:
+            caps = scaled_capacities(root, base, _mesh_ladder_start(
+                batches, mesh, default_join_capacity))
+
+    def build():
+        # HBO-lite: the capacities a structurally identical plan fitted
+        # at, a rung's, or the fitted ones: explicit on every node
+        return _compile_any(
+            with_capacities(root, caps) if caps != base else root, mesh,
+            default_join_capacity, scale, use_cache)
+
+    if caps != base or scale > 1:
+        plan, jfn, call_lock = build()
     from .datapath import now_us as _now_us
     region = {"region": tag}
     note("capacity_reruns", 0)  # in every statement's counters, 0 too
+    reruns, refitted = 0, False
     while True:
         t_disp0 = _now_us()
         with stage("dispatch", region):
@@ -1116,16 +1157,31 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             # the only per-kernel timing one fused program exposes -- on
             # the monotonic now_us clock
             device_s += (_now_us() - t_disp0) / 1e6
-            flags, routed = _read_status(overflow, expand_steps)
+            flags, routed, needs = _read_status(overflow, plan,
+                                                expand_steps)
         note_max("program_hbm_bytes",
                  _program_hbm_bytes(plan, dispatch_fn, call_lock, batches))
         if prog is not None:  # each landed dispatch advances
             prog.advance()
         if flags == 0:
-            if cap_scale > 1 and fp:
-                _CAPACITY_FEEDBACK[fp] = cap_scale
+            fitted = fitted_capacities(base, caps, needs)
+            if adapt and not refitted and caps != base \
+                    and _worth_refit(caps, fitted):
+                refitted = True
+                caps = fitted
+                stats.add("capacity_refits", 1)
+                note("capacity_refits")
+                plan, jfn, call_lock = build()
+                continue
+            if adapt and caps != base:
+                _CAPACITY_FEEDBACK[fp] = caps
             if scale > 1 and fp:
                 _SLOT_FEEDBACK[fp] = scale
+            if needs:
+                # how full the program that answered ran its counted
+                # nodes: the capacities it was built with, what they held
+                note("capacity_rows", sum(plan.counted.values()))
+                note("capacity_live_rows", sum(needs.values()))
             if mesh is not None:
                 # the program that answered, a plan-cache hit or not:
                 # its chips, its exchanges (constants kept with the
@@ -1137,9 +1193,11 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             break
         if flags & 1:
             # hard (join/group/unnest) overflow: adaptive rerun with
-            # geometrically larger capacities (the memory-feedback loop
-            # that replaces per-query hand hints; reserve/revoke analog)
-            if cap_scale >= _MAX_CAPACITY_SCALE or adaptive_off:
+            # larger capacities (the memory-feedback loop that replaces
+            # per-query hand hints; reserve/revoke analog)
+            grown = caps if adaptive_off or reruns >= _MAX_CAPACITY_RERUNS \
+                else grown_capacities(root, caps, needs)
+            if grown == caps:
                 hint = (" (note: connector NDV statistics shrank "
                         "group capacities this run; set session "
                         "stats_capacity_refinement=false if a "
@@ -1150,15 +1208,12 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                     "(join/group capacity) beyond the adaptive "
                     "rerun ceiling; rerun with larger capacity "
                     "hints (max_groups / join_capacity)" + hint)
-            from ..plan.stats import scale_capacities
-            cap_scale *= 4
+            caps = grown
+            reruns += 1
             stats.add("capacity_reruns", 1)
             note("capacity_reruns")
-            exec_root = scale_capacities(root, cap_scale)
             scale = 1
-            plan, jfn, call_lock = _compile_any(
-                exec_root, mesh, default_join_capacity * cap_scale,
-                1, use_cache)
+            plan, jfn, call_lock = build()
             continue
         if mesh is None or scale >= 1 << 20:  # unreachable: clamp
             raise RuntimeError(
@@ -1166,10 +1221,9 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
         scale *= 2
         stats.add("exchange_slot_reruns", 1)
         note("capacity_reruns")  # a slot is a capacity too
-        plan, jfn, call_lock = _compile_any(
-            exec_root if exec_root is not None else root, mesh,
-            default_join_capacity * cap_scale, scale, use_cache)
-    return out, device_s, dispatch_fn, call_lock, cap_scale, scale, plan
+        plan, jfn, call_lock = build()
+    return (out, device_s, dispatch_fn, call_lock,
+            tuple(sorted(caps.items())), scale, plan)
 
 
 def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
@@ -1298,7 +1352,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                     jax.block_until_ready(out)
                     dev_s = (_now_us() - t_don0) / 1e6
                     # no join is overflow-incapable: nothing expands
-                    oflags, _ = _read_status(overflow, None)
+                    oflags, _, _ = _read_status(overflow, plan, None)
                 if prog is not None:
                     prog.advance()
                 if oflags:  # unreachable: whitelist admits no overflow op
@@ -1318,7 +1372,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                              leaves=len(prep.donate_idx))
                 dispatch_fn = None
             else:
-                out, dev_s, dispatch_fn, dlock, cap_scale, scale, _ = \
+                out, dev_s, dispatch_fn, dlock, ran_caps, scale, _ = \
                     _dispatch_ladder(
                         reg.root, plan, jfn, call_lock, rbatches, None,
                         default_join_capacity, use_cache, rfp, stats,
@@ -1329,7 +1383,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                 # ANALYZE keeps its compile-stage roofline inputs under
                 # fusion=0 / refusal / demotion
                 cost = _stage_cost(dispatch_fn, rbatches,
-                                   (rfp, cap_scale, scale), dlock)
+                                   (rfp, ran_caps, scale), dlock)
                 if cost:
                     collector.bump_stage("compile", **cost)
                     stats.add("xla_flops", cost["flops"])

@@ -136,15 +136,19 @@ class GroupByResult:
     then aggregate state columns), active for slots < num_groups.
     `overflow` is True when distinct keys exceeded max_groups (results
     for the overflowed tail are dropped -- exec layer must re-run with a
-    bigger bucket or spill)."""
+    bigger bucket or spill). `counted` says whether `num_groups` is the
+    distinct keys' count whatever it is (the sorted kernel), or stops
+    at the kernel's table once that overflows (the small and hash
+    kernels: then a lower bound)."""
     batch: Batch
     num_groups: jnp.ndarray
     overflow: jnp.ndarray
+    counted: bool = True
 
 
 jax.tree_util.register_dataclass(GroupByResult,
                                  data_fields=["batch", "num_groups", "overflow"],
-                                 meta_fields=[])
+                                 meta_fields=["counted"])
 
 
 from ..expr.functions import _GOLD as _GOLDEN, _mix64 as _splitmix64
@@ -1345,7 +1349,7 @@ def group_by(batch: Batch, key_channels: Sequence[int], aggs: Sequence[AggSpec],
     for f in sub_overflow:
         overflow = overflow | f
     out = Batch(tuple(out_cols), slot_active)
-    return GroupByResult(out, num_groups, overflow)
+    return GroupByResult(out, num_groups, overflow, counted=False)
 
 
 def grouped_aggregate(batch: Batch, key_channels: Sequence[int],

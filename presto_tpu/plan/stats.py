@@ -1,10 +1,13 @@
 """Plan statistics: column provenance, NDV and row estimates.
 
-Also home of `scale_capacities`, the adaptive-rerun rewrite: when a
-static bucket overflows at runtime, the runner re-plans with every
-capacity geometrically enlarged (the memory-feedback analog of the
-reference's reserve/revoke loop) instead of failing the query -- the
-piece that lets NDV-driven sizing stand WITHOUT per-query hand hints.
+Also home of the adaptive-rerun rewrite (`capacities`,
+`with_capacities`, `grown_capacities`, `fitted_capacities`): when a
+static bucket overflows at runtime, the runner re-plans with the
+capacity of each node that the program counted sized from its count and
+the others geometrically enlarged (the memory-feedback analog of the
+reference's reserve/revoke loop) instead of failing the query, and a
+plan that fitted is kept at what its nodes needed -- the piece that
+lets NDV-driven sizing stand WITHOUT per-query hand hints.
 
 Reference surface: the cost/stats stack --
 presto-main-base/.../cost/StatsCalculator.java (per-PlanNode stats
@@ -33,7 +36,10 @@ from ..expr import ir as E
 from . import nodes as N
 
 __all__ = ["column_source", "estimate_distinct", "estimate_group_bound",
-           "estimate_rows", "refine_capacities"]
+           "estimate_rows", "refine_capacities", "preorder", "preorder_index",
+           "is_counted",
+           "capacities", "scaled_capacities", "with_capacities",
+           "fitted_capacities", "grown_capacities"]
 
 # guessed fraction of rows surviving one filter conjunct (Presto's
 # UNKNOWN_FILTER_COEFFICIENT analog, FilterStatsCalculator.java)
@@ -245,15 +251,96 @@ def estimate_rows(node: N.PlanNode, sf: float) -> Optional[float]:
 
 _MAX_GROUPS_CEILING = 1 << 23
 _CAPACITY_CEILING = 1 << 24
+# A fitted capacity never goes under this many rows, nor under the
+# plan's own value where that is smaller still (a hand-set hint or an
+# NDV-refined table of a few groups chose its kernel: the ladder never
+# went under it either). Under a thousand rows an operator's cost is
+# not its capacity, while every distinct capacity is a program.
+_FIT_FLOOR = 1 << 10
 
 
-def scale_capacities(root: N.PlanNode, factor: int) -> N.PlanNode:
-    """Rebuild the plan with every static capacity multiplied by
-    `factor` (group tables, join/unnest out-capacities), preserving
-    shared subtrees (CTE DAGs). Exchange slot capacities are excluded:
-    slot overflow has its own (cheaper) rerun loop in the executor."""
+def preorder(root: N.PlanNode) -> list:
+    """The plan's nodes in structural pre-order (a shared subtree
+    counts once, where it is first met). A node's place in the list is
+    its pre-order index: stable across plannings of one SQL text, which
+    `node.id`, a process-wide counter, is not; the device scopes
+    (exec/planner.py) and the capacity feedback name a node by it."""
+    seen: set = set()
+    out: list = []
+
+    def walk(n):
+        if id(n) not in seen:
+            seen.add(id(n))
+            out.append(n)
+            for s in n.sources:
+                walk(s)
+    walk(root)
+    return out
+
+
+def preorder_index(root: N.PlanNode) -> dict:
+    """id(node) -> its pre-order index (`preorder`)."""
+    return {id(n): k for k, n in enumerate(preorder(root))}
+
+
+def _pow2_at_or_above(rows: int) -> int:
+    return 1 << max(rows - 1, 0).bit_length()
+
+
+def is_counted(n: N.PlanNode) -> bool:
+    """Whether the program reports how many rows the node needed: a
+    join's output rows, a keyed aggregation's groups (exact, overflow
+    or not, from the join and the sorted group-by; the small and hash
+    group kernels stop counting at their table and say four times the
+    capacity when they overflow: `exec/planner.py`). Distinct,
+    mark-distinct and unnest report an overflow flag alone."""
+    return isinstance(n, N.JoinNode) or (
+        isinstance(n, N.AggregationNode) and bool(n.group_channels))
+
+
+def _capacity_field(n: N.PlanNode) -> Optional[str]:
+    if is_counted(n):
+        return "out_capacity" if isinstance(n, N.JoinNode) else "max_groups"
+    if isinstance(n, (N.DistinctNode, N.MarkDistinctNode)):
+        return "max_groups"
+    if isinstance(n, N.UnnestNode) and n.out_capacity is not None:
+        return "out_capacity"  # None: four times its input, whatever that is
+    return None
+
+
+def _ceiling(n: N.PlanNode) -> int:
+    return _CAPACITY_CEILING if isinstance(n, (N.JoinNode, N.UnnestNode)) \
+        else _MAX_GROUPS_CEILING
+
+
+def capacities(root: N.PlanNode, default_join_capacity: int) -> dict:
+    """{pre-order index: capacity} of every node that has a static
+    capacity the ladder can change: joins, keyed aggregations (the
+    counted nodes), distinct, mark-distinct, unnest."""
+    found = {}
+    for k, n in enumerate(preorder(root)):
+        field = _capacity_field(n)
+        if field is not None:
+            found[k] = getattr(n, field) or default_join_capacity
+    return found
+
+
+def scaled_capacities(root: N.PlanNode, caps: dict, factor: int) -> dict:
+    """`caps`, every one `factor` times as large, up to the ceilings."""
+    nodes = preorder(root)
+    return {k: max(min(c * factor, _ceiling(nodes[k])), c)
+            for k, c in caps.items()}
+
+
+def with_capacities(root: N.PlanNode, caps: dict) -> N.PlanNode:
+    """Rebuild the plan with the capacity of node `k` set to `caps[k]`
+    (explicit `out_capacity`, `max_groups`), preserving shared subtrees
+    (CTE DAGs); `root` itself where nothing changes. Exchange slot
+    capacities are excluded: slot overflow has its own (cheaper) rerun
+    loop in the executor."""
     import dataclasses
 
+    index = preorder_index(root)
     memo: dict = {}
 
     def walk(n: N.PlanNode) -> N.PlanNode:
@@ -270,18 +357,63 @@ def scale_capacities(root: N.PlanNode, factor: int) -> N.PlanNode:
                 w = [walk(x) for x in v]
                 if any(a is not b for a, b in zip(w, v)):
                     changes[f.name] = w
-        if isinstance(n, (N.AggregationNode, N.DistinctNode,
-                          N.MarkDistinctNode)):
-            changes["max_groups"] = min(n.max_groups * factor,
-                                        _MAX_GROUPS_CEILING)
-        if isinstance(n, N.JoinNode) and n.out_capacity is not None:
-            changes["out_capacity"] = min(n.out_capacity * factor,
-                                          _CAPACITY_CEILING)
-        if isinstance(n, N.UnnestNode) and n.out_capacity is not None:
-            changes["out_capacity"] = min(n.out_capacity * factor,
-                                          _CAPACITY_CEILING)
+        cap = caps.get(index[id(n)])
+        field = _capacity_field(n)
+        if cap is not None and field and getattr(n, field) != cap:
+            changes[field] = cap
         out = dataclasses.replace(n, **changes) if changes else n
         memo[id(n)] = out
         return out
 
     return walk(root)
+
+
+def fitted_capacities(base: dict, ran: dict, needs: dict) -> dict:
+    """After a dispatch that fitted, every count is exact: a counted
+    node's capacity is the power of two at or above its need (powers of
+    two keep `ops/join._slot_rows`' block rule and the compile cache's
+    key space small), not under `_FIT_FLOOR` rows or the plan's own
+    capacity `base`, whichever is smaller. A node without a count keeps
+    what it ran at."""
+    fitted = dict(ran)
+    for k, need in needs.items():
+        fitted[k] = max(_pow2_at_or_above(need), min(base[k], _FIT_FLOOR))
+    return fitted
+
+
+def grown_capacities(root: N.PlanNode, ran: dict, needs: dict) -> dict:
+    """After a dispatch that overflowed: a counted node whose need
+    passed its capacity is sized from the need (the power of two at or
+    above it); the nodes above such a node saw truncated input, and the
+    nodes that report a flag alone may be the ones that overflowed, so
+    both grow four times; a counted node below every overflow has an
+    exact count that fitted and stays. Where no count passed its
+    capacity (a hash table's probe budget, a distinct) every capacity
+    grows four times. All held to the ceilings: a result equal to `ran`
+    means nothing can grow."""
+    index = preorder_index(root)
+    over = {k for k, need in needs.items() if need > ran[k]}
+    grown = {}
+    memo: dict = {}
+
+    def walk(n: N.PlanNode) -> bool:
+        """Whether an overflow lies at or below `n`."""
+        if id(n) in memo:
+            return memo[id(n)]
+        k = index[id(n)]
+        below = False
+        for s in n.sources:  # every source: no short circuit
+            below = walk(s) or below
+        if k in ran:
+            if k in over:
+                grown[k] = _pow2_at_or_above(needs[k])
+            elif below or not over or k not in needs:
+                grown[k] = ran[k] * 4
+            else:
+                grown[k] = ran[k]
+            grown[k] = max(min(grown[k], _ceiling(n)), ran[k])
+        memo[id(n)] = below or k in over or (k in ran and k not in needs)
+        return memo[id(n)]
+
+    walk(root)
+    return grown

@@ -172,9 +172,11 @@ SESSION_PROPERTIES = (
          "worker's data-versioned cache (FileFragmentResultCacheManager "
          "analog); disable when benchmarking raw execution")
     .add("adaptive_capacity", "bool", True,
-         "on bucket overflow, re-plan with geometrically larger "
-         "capacities instead of failing (exec/runner.py rerun ladder + "
-         "plan-fingerprint feedback)")
+         "on bucket overflow, re-plan with larger capacities instead "
+         "of failing, each node sized from the rows it counted "
+         "(exec/runner.py rerun ladder + per-node plan-fingerprint "
+         "feedback); false runs the plan as planned: no rerun, no "
+         "refit, no feedback")
     .add("spill_path", "str", "",
          "directory for the DISK spill tier: spilled bucket outputs "
          "flush from host DRAM to .npz run files once they exceed "

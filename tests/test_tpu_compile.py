@@ -95,7 +95,13 @@ def test_lex_sort_compiles_as_one_single_key_sort(one_chip):
 @pytest.mark.parametrize("npr,slots,nb", [
     (60_000_000, 4_194_304, 15_000_000),  # Q3's lineitem x orders
     (60_000_000, 1_048_576, 2_000_000),   # Q14's lineitem x part
-], ids=["sf10-q3-join7", "sf10-q14-join4"])
+    # Q3 at the capacities its nodes need (the ladder fits a node from
+    # its own count): lineitem x orders, and x customer, whose probe is
+    # now four times its output and so holds both forms too
+    (60_000_000, 2_097_152, 15_000_000),
+    (2_097_152, 524_288, 1_500_000),
+], ids=["sf10-q3-join7", "sf10-q14-join4", "sf10-q3-join7-fitted",
+        "sf10-q3-join6-fitted"])
 def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
                                                             slots, nb):
     """`hash_join`'s probe side holds its two forms in a `cond`, and
@@ -120,6 +126,46 @@ def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
         _shape((npr,), jnp.int32, one_chip),
         _shape((npr,), jnp.bool_, one_chip)).compile().as_text()
     assert "conditional" in text
+
+
+def test_a_meshed_programs_needs_cross_the_chips_in_32_bits(one_chip):
+    """Under a mesh a counted node's need is the largest shard's: a
+    `pmax` over the chips. XLA:TPU lowers a 64-bit all-reduce for sums
+    alone ("Supported lowering only of Sum all reduce"), which no CPU
+    mesh shows, so the needs cross in int32; this compiles a join under
+    a group-by over the described 2x2 and holds them to it."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from presto_tpu import types as T
+    from presto_tpu.block import Batch, Column
+    from presto_tpu.exec.planner import compile_plan
+    from presto_tpu.parallel.mesh import WORKERS_AXIS
+    from presto_tpu.plan import nodes as N
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:4]), (WORKERS_AXIS,))
+    rows = 4096
+    left, right = (N.ValuesNode([T.BIGINT, T.BIGINT], [(0, 0)])
+                   for _ in range(2))
+    join = N.JoinNode(
+        N.ExchangeNode(left, kind="REPARTITION", scope="REMOTE",
+                       partition_channels=[0]),
+        N.ExchangeNode(right, kind="REPARTITION", scope="REMOTE",
+                       partition_channels=[0]),
+        [0], [0], distribution="partitioned", out_capacity=2048)
+    plan = compile_plan(N.AggregationNode(
+        join, [0], [], step="PARTIAL", max_groups=1024), mesh)
+    assert len(plan.counted) == 2
+    shard = NamedSharding(mesh, PartitionSpec(WORKERS_AXIS))
+    lane = _shape((rows,), jnp.int64, shard)
+    mask = _shape((rows,), jnp.bool_, shard)
+    batch = Batch((Column(lane, mask, T.BIGINT),
+                   Column(lane, mask, T.BIGINT)), mask)
+    out, status = jax.eval_shape(plan.fn, (batch, batch))
+    assert status.shape == (2 + 2,) and status.dtype == jnp.int64
+    text = jax.jit(plan.fn).lower((batch, batch)).compile().as_text()
+    assert "all-to-all" in text
 
 
 def test_a_lake_scans_row_groups_land_in_place_at_sf10_shapes(one_chip):
