@@ -119,14 +119,11 @@ def audit_staged_query(plan, batches, *, mesh=None, query_id: str = "query",
     if memory_pool is not None and report["peak_bytes_estimate"]:
         note = getattr(memory_pool, "note_audit_estimate", None)
         if note is not None:
+            # True where the estimate alone exceeds the WHOLE pool: this
+            # plan cannot fit even an empty pool -- the flight event
+            # below says so before execution proves it the hard way
             over_capacity = bool(note(query_id,
                                       report["peak_bytes_estimate"]))
-            if over_capacity and collector is not None:
-                # the estimate alone exceeds the WHOLE pool: this plan
-                # cannot fit even an empty pool -- surface it on the
-                # query's telemetry before execution proves it the
-                # hard way
-                collector.note("kernel_audit_over_pool_capacity")
     from ..server.flight_recorder import record_event
     record_event("kernel_audit", query_id=query_id, findings=total,
                  passes=",".join(f"{c}:{n}"

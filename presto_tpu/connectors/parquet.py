@@ -498,7 +498,11 @@ class PieceScan:
     Pool threads have no ambient collector: a piece carries the clock
     readings of its read and its decode, and `record`, called by the
     consumer on the statement's thread, records each hop once, from its
-    first piece's entry to its last piece's exit."""
+    first piece's entry to its last piece's exit, and beside the two
+    envelopes what they hide: the pieces' own durations summed (the
+    pool's thread-seconds: ``lake_read_thread_us``,
+    ``lake_decode_thread_us``) and how long the statement's thread
+    stood waiting for a piece (``lake_consumer_wait_us``)."""
 
     def __init__(self, sources, read, schema: Dict[str, T.Type],
                  columns: Sequence[str], touched: int, file_bytes: int,
@@ -514,6 +518,7 @@ class PieceScan:
                                 _IN_FLIGHT_BYTES // max(piece_bytes, 1)))
         self._read = read
         self._read_at = self._decode_at = None
+        self._read_s = self._decode_s = self._wait_s = 0.0
 
     def _piece(self, src) -> Piece:
         source, first, rows, whole = src
@@ -550,12 +555,17 @@ class PieceScan:
             for src in itertools.islice(todo, self.depth))
         try:
             while pending:
+                t0 = time.time()
                 piece = pending.popleft().result()
+                self._wait_s += time.time() - t0
                 src = next(todo, None)
                 if src is not None:
                     pending.append(pool.submit(self._piece, src))
                 self._read_at = _span_of(self._read_at, piece.read_at)
                 self._decode_at = _span_of(self._decode_at, piece.decode_at)
+                if piece.read_at is not None:
+                    self._read_s += piece.read_at[1] - piece.read_at[0]
+                self._decode_s += piece.decode_at[1] - piece.decode_at[0]
                 yield piece
         finally:
             # an error, or a consumer that stopped: nothing of this scan
@@ -577,6 +587,7 @@ class PieceScan:
         if self._read is not None:
             hop_interval("connector_read", self.file_bytes,
                          *(self._read_at or (now, now)))
+            note("lake_read_thread_us", round(self._read_s * 1e6))
         t0, t1 = self._decode_at or (now, now)
         hop_interval("decode", decoded_bytes, t0, max(t1, decode_end))
         note("lake_row_groups_total", self.touched)
@@ -585,6 +596,8 @@ class PieceScan:
              len(self.sources) if pipelined else 0)
         note("lake_file_bytes", self.file_bytes)
         note("lake_decoded_bytes", decoded_bytes)
+        note("lake_decode_thread_us", round(self._decode_s * 1e6))
+        note("lake_consumer_wait_us", round(self._wait_s * 1e6))
 
 
 def _span_of(so_far, at):

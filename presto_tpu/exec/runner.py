@@ -316,19 +316,24 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         from .datapath import timed_hop
         from .dynfilter import apply_dynamic_filters
         arrays, nulls = _read_split(conn, node, sf, start, count)
-        keep, pruned = apply_dynamic_filters(
-            dict(zip(node.columns, arrays)), node.columns, dyn_filters)
-        if stats is not None:
-            stats.add("dynamic_filter_rows_pruned", pruned)
-            stats.add("dynamic_filter_rows_staged", len(keep) - pruned)
-        if not pruned:
-            keep = slice(None)  # every row stays: nothing to copy out
-        arrays = [a[keep] for a in arrays]
+        # the filter's host side, between the read and the re-proof: the
+        # keep mask over the key columns and every column gathered by it
+        kept = {"rows_in": len(arrays[0]) if arrays else 0}
+        with stage("prune", kept):
+            keep, pruned = apply_dynamic_filters(
+                dict(zip(node.columns, arrays)), node.columns, dyn_filters)
+            kept["rows_kept"] = len(keep) - pruned
+            if stats is not None:
+                stats.add("dynamic_filter_rows_pruned", pruned)
+                stats.add("dynamic_filter_rows_staged", kept["rows_kept"])
+            if not pruned:
+                keep = slice(None)  # every row stays: nothing to copy out
+            arrays = [a[keep] for a in arrays]
+            if nulls is not None:
+                nulls = [n[keep] for n in nulls]
         tys = node.column_types
         nrows = len(arrays[0])
         cap = max(-(-nrows // pad_multiple) * pad_multiple, pad_multiple)
-        if nulls is not None:
-            nulls = [n[keep] for n in nulls]
         phys = getattr(node, "physical_dtypes", None)
         if phys and any(phys):
             from ..plan.widths import checked_physical_dtypes
@@ -351,6 +356,63 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         if node.pushdown is not None and scan_range is None else None
     return stage_scan_split(conn, node, sf, start, count, cap, predicate,
                             sharding)
+
+
+def _count_staged(scan_leaves, batches, collector: StatsCollector,
+                  stats: RuntimeStats, prog, sf: float,
+                  query_id: str) -> int:
+    """Staging's own bookkeeping, the child span ``scan_count`` of
+    ``staging``: each scan's rows, counted by reading its `active` mask
+    back whole (attribute `bytes_read_back`: the masks' bytes), its
+    bytes, its operator and accuracy records, what narrowing saved; the
+    sums go to the ``staging`` stage. Returns the staged bytes."""
+    from ..plan.widths import batch_narrowed_bytes_saved, note_narrowed
+    from .accuracy import est_rows_of as _acc_est
+    from .accuracy import record_node as _acc_record
+    from .memory import batch_bytes
+    staged_rows = staged_bytes = 0
+    narrowed_cols = narrowed_saved = 0
+    with stage("scan_count", {
+            "scans": len(batches),
+            "bytes_read_back": sum(int(b.active.nbytes) for b in batches)}):
+        for si, (s, b) in enumerate(zip(scan_leaves, batches)):
+            rows = int(np.asarray(b.active).sum())
+            nbytes = batch_bytes(b)
+            staged_rows += rows
+            staged_bytes += nbytes
+            stats.add("scan_rows", rows)
+            collector.operator(_scan_key(si, s), output_rows=rows,
+                               output_bytes=nbytes)
+            # estimate-vs-actual (exec/accuracy.py): the scan leaf's
+            # planner estimate against the rows it actually staged --
+            # structural keys line up with the operator rows and across
+            # workers running the same fragment
+            _acc_record(_scan_key(si, s), _scan_label(s), unit="rows",
+                        est=_acc_est(s, sf), actual=rows)
+            if prog is not None:  # processed-input counters (monotonic)
+                prog.advance(rows=rows, bytes=nbytes)
+            if getattr(s, "physical_dtypes", None):
+                nc, nb = batch_narrowed_bytes_saved(b)
+                narrowed_cols += nc
+                narrowed_saved += nb
+        collector.bump_stage("staging", rows=staged_rows,
+                             bytes=staged_bytes)
+        if narrowed_saved:
+            # staged bytes saved vs logical lanes: the QueryStats
+            # counter the acceptance criteria name, plus the
+            # process-lifetime /v1/metrics totals
+            # (server/metrics.narrowing_families)
+            stats.add("narrowed_bytes_saved", narrowed_saved)
+            collector.note("narrowed_bytes_saved", narrowed_saved)
+            collector.note("narrowed_columns", narrowed_cols)
+            note_narrowed(narrowed_cols, narrowed_saved)
+            # narrow-width decisions are exactly the kind of silent
+            # plan choice a post-mortem wants on the timeline (flight
+            # recorder)
+            from ..server.flight_recorder import record_event
+            record_event("narrow_width", query_id=query_id,
+                         columns=narrowed_cols, bytes_saved=narrowed_saved)
+    return staged_bytes
 
 
 def _process_chips() -> int:
@@ -692,7 +754,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     f"failpoint ({type(e).__name__})")
                 # the shared demotion counter (both paths) + the forced-
                 # path discriminator, correlated by the flight event reason
-                stats.add("fusion_demotions", 1)
                 stats.add("fusion_forced_demotions", 1)
                 collector.note("fusion_demotions")
                 from ..server.flight_recorder import record_event
@@ -764,7 +825,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                                 scan_ranges.get(s.id), remote_sources)
             for s in scan_leaves)
         memory_pool.reserve(query_id, reserved)
-        stats.add("reserved_bytes", reserved)
         if prog is not None:
             prog.note_memory(reserved)
     try:
@@ -790,51 +850,14 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     wall_us=int((time.time() - t_scan0) * 1e6))
                 if prog is not None:  # one split staged = one heartbeat
                     prog.advance(splits=1)
+            staged_bytes = _count_staged(scan_leaves, batches, collector,
+                                         stats, prog, sf, query_id)
     except Exception:
         if memory_pool is not None:
             memory_pool.free(query_id, reserved)
             memory_pool.query_peak_bytes(query_id, pop=True)
         raise
-    from .memory import batch_bytes
-    from ..plan.widths import batch_narrowed_bytes_saved, note_narrowed
-    from .accuracy import est_rows_of as _acc_est
     from .accuracy import record_node as _acc_record
-    staged_rows = staged_bytes = 0
-    narrowed_cols = narrowed_saved = 0
-    for si, (s, b) in enumerate(zip(scan_leaves, batches)):
-        rows = int(np.asarray(b.active).sum())
-        nbytes = batch_bytes(b)
-        staged_rows += rows
-        staged_bytes += nbytes
-        stats.add("scan_rows", rows)
-        collector.operator(_scan_key(si, s), output_rows=rows,
-                           output_bytes=nbytes)
-        # estimate-vs-actual (exec/accuracy.py): the scan leaf's
-        # planner estimate against the rows it actually staged --
-        # structural keys line up with the operator rows and across
-        # workers running the same fragment
-        _acc_record(_scan_key(si, s), _scan_label(s), unit="rows",
-                    est=_acc_est(s, sf), actual=rows)
-        if prog is not None:  # processed-input counters (monotonic)
-            prog.advance(rows=rows, bytes=nbytes)
-        if getattr(s, "physical_dtypes", None):
-            nc, nb = batch_narrowed_bytes_saved(b)
-            narrowed_cols += nc
-            narrowed_saved += nb
-    collector.bump_stage("staging", rows=staged_rows, bytes=staged_bytes)
-    if narrowed_saved:
-        # staged bytes saved vs logical lanes: the QueryStats counter the
-        # acceptance criteria name, plus the process-lifetime /v1/metrics
-        # totals (server/metrics.narrowing_families)
-        stats.add("narrowed_bytes_saved", narrowed_saved)
-        collector.note("narrowed_bytes_saved", narrowed_saved)
-        collector.note("narrowed_columns", narrowed_cols)
-        note_narrowed(narrowed_cols, narrowed_saved)
-        # narrow-width decisions are exactly the kind of silent plan
-        # choice a post-mortem wants on the timeline (flight recorder)
-        from ..server.flight_recorder import record_event
-        record_event("narrow_width", query_id=query_id,
-                     columns=narrowed_cols, bytes_saved=narrowed_saved)
     # staging-time kernel audit (audit/staged.py): with the
     # kernel_audit session property (env PRESTO_TPU_KERNEL_AUDIT) on,
     # trace the fused program once more over the staged batches and run
@@ -845,11 +868,10 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     # query.
     from ..audit.staged import audit_staged_query, kernel_audit_enabled
     if kernel_audit_enabled(session) and not multi_region:
-        with stats.timed("kernel_audit_s"):
-            audit_report = audit_staged_query(
-                plan, batches, mesh=mesh, query_id=query_id,
-                session=session, collector=collector, stats=stats,
-                memory_pool=memory_pool, plan_fp=fp)
+        audit_report = audit_staged_query(
+            plan, batches, mesh=mesh, query_id=query_id, session=session,
+            collector=collector, stats=stats, memory_pool=memory_pool,
+            plan_fp=fp)
         if audit_report and audit_report.get("peak_bytes_estimate"):
             # ... and the estimate side of the footprint accuracy
             # record (actual fills in at finalize from the pool's
@@ -915,7 +937,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                                (fp_cost, ran_caps, scale), call_lock)
             if cost:
                 collector.bump_stage("compile", **cost)
-                stats.add("xla_flops", cost["flops"])
         if rplan.fused and mesh is None and not multi_region \
                 and rplan.regions[0].ops > 1:
             # fused-side sample for the demotion comparator: device
@@ -929,7 +950,6 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                            max(int(device_s * 1e6) - compile_us, 0))
             verdict = mem.maybe_demote(span_fp)
             if verdict is not None:
-                stats.add("fusion_demotions", 1)
                 collector.note("fusion_demotions")
                 from ..server.flight_recorder import record_event
                 record_event("fusion_demotion", query_id=query_id,
@@ -957,16 +977,27 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
         with stage("fetch"):
             res = _batch_to_result(out, root)
     finally:
-        # always drain the per-query peak (success AND failure paths):
-        # the pool's map must stay bounded by in-flight queries
-        peak_reserved = 0
-        if memory_pool is not None:
-            memory_pool.free(query_id, reserved)
-            peak_reserved = memory_pool.query_peak_bytes(query_id, pop=True)
-    stats.add("output_rows", res.row_count)
-    res.stats = stats.snapshot()
-    _finalize_query_stats(collector, res, t_query0, peak_reserved, root,
-                          dp=dp, acc=acc, sf=sf)
+        # the close-out, from `fetch`'s exit to the return into the
+        # caller, on the success AND failure paths: the pool's map must
+        # stay bounded by in-flight queries, so the per-query peak is
+        # always drained; the statement's device buffers (its staged
+        # batches, its output) are let go here, inside the span, and not
+        # at the frame's exit (the runtime frees them when the next
+        # transfer is issued: PERF.md section 6, PR 36); a statement
+        # that answered folds its ledgers into its stats
+        with stage("finish"):
+            peak_reserved = 0
+            if memory_pool is not None:
+                memory_pool.free(query_id, reserved)
+                peak_reserved = memory_pool.query_peak_bytes(query_id,
+                                                             pop=True)
+            batches = out = None
+            if res is not None:
+                stats.add("output_rows", res.row_count)
+                res.stats = stats.snapshot()
+                _finalize_query_stats(collector, res, t_query0,
+                                      peak_reserved, root, dp=dp, acc=acc,
+                                      sf=sf)
     return res
 
 
@@ -1287,11 +1318,10 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                                                 use_cache)
             rfp = plan_fingerprint(reg.root)
             if audit_on:
-                with stats.timed("kernel_audit_s"):
-                    report = audit_staged_query(
-                        plan, rbatches, mesh=None, query_id=query_id,
-                        session=session, collector=collector, stats=stats,
-                        memory_pool=memory_pool, plan_fp=rfp)
+                report = audit_staged_query(
+                    plan, rbatches, mesh=None, query_id=query_id,
+                    session=session, collector=collector, stats=stats,
+                    memory_pool=memory_pool, plan_fp=rfp)
                 if report and report.get("peak_bytes_estimate"):
                     fusion_memory().note_footprint(
                         rfp, report["peak_bytes_estimate"])
@@ -1386,7 +1416,6 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                                    (rfp, ran_caps, scale), dlock)
                 if cost:
                     collector.bump_stage("compile", **cost)
-                    stats.add("xla_flops", cost["flops"])
             outputs[reg.index] = out
             if memory_pool is not None and consumers.get(reg.index, 0) > 0:
                 # intermediate output: new HBM is its footprint minus the

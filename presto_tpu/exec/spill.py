@@ -150,7 +150,6 @@ class _HostRows:
         self._mem_bytes = 0
         if stats is not None:
             stats.add("spilled_to_disk_bytes", written)
-            stats.add("spill_run_files", 1)
 
     def columns(self) -> Tuple[List[np.ndarray], List[np.ndarray]]:
         cols_runs: List[List[np.ndarray]] = [[] for _ in self.types]

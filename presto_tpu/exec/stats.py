@@ -50,7 +50,7 @@ from .accuracy import NodeAccuracy, merge_record_maps, \
 from .datapath import HopStats, hop_map_from_json, hop_map_to_json, \
     merge_hop_maps
 
-__all__ = ["RuntimeStats", "timed", "OperatorStats", "StageStats",
+__all__ = ["RuntimeStats", "OperatorStats", "StageStats",
            "QueryStats", "StatsCollector", "current_collector",
            "collecting", "joining", "stage", "span", "interval", "note",
            "note_max"]
@@ -93,23 +93,6 @@ class RuntimeStats:
             return {k: {"count": s.count, "total": round(s.total, 6),
                         "max": round(s.max, 6)}
                     for k, s in self._stats.items()}
-
-    def timed(self, name: str):
-        return timed(self, name)
-
-
-class timed:
-    def __init__(self, stats: RuntimeStats, name: str):
-        self.stats = stats
-        self.name = name
-
-    def __enter__(self):
-        self.t0 = time.time()
-        return self
-
-    def __exit__(self, *exc):
-        self.stats.add(self.name, time.time() - self.t0)
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -341,6 +324,7 @@ class StatsCollector:
         self.spans: List[tuple] = []
         self._next_span = 0
         self._compile_s = 0.0
+        self.closed = False
         self._lock = threading.Lock()
 
     # -- spans ------------------------------------------------------------
@@ -435,7 +419,9 @@ class StatsCollector:
 
     def close(self, trace=None) -> None:
         """Called once, by whoever created the collector, when the
-        statement's last span has closed. Ships the collected spans
+        statement's last span has closed, whether the statement
+        finished or failed (`closed` is then set: an owner with two
+        ways out asks before it calls). Ships the collected spans
         through the tracing emission seam, each under the span that
         caused it; the top-level ones hang under the enclosing
         task/query span. `trace` is a TraceContext (trace id + that
@@ -448,6 +434,7 @@ class StatsCollector:
         its waterfall."""
         from ..server.metrics import observe_histogram
         from ..server.tracing import TraceContext, emit_span, new_span_id
+        self.closed = True
         if isinstance(trace, TraceContext):
             tid, root = trace.trace_id, trace.span_id
         else:
@@ -588,8 +575,10 @@ class joining:
     """The statement's collector for this thread: the ambient one where
     a caller up the stack opened it (the statement server, ``sql()``,
     an outer ``run_query``), else a new one that is ambient inside the
-    block and shipped to the tracer when the block ends cleanly.
-    `trace` is what :meth:`StatsCollector.close` takes."""
+    block and shipped to the tracer when the block ends, by a return
+    or by an exception: a failed statement's spans are the ones its
+    post-mortem wants. `trace` is what :meth:`StatsCollector.close`
+    takes."""
 
     def __init__(self, query_id: str = "query", trace=None):
         self.query_id = query_id
@@ -606,8 +595,7 @@ class joining:
     def __exit__(self, exc_type, *exc):
         if self._own is not None:
             self._own.__exit__(exc_type, *exc)
-            if exc_type is None:
-                self._own.collector.close(self.trace)
+            self._own.collector.close(self.trace)
         return False
 
 

@@ -435,6 +435,11 @@ class StatementServer:
             self._run_inner(q)
         finally:
             if q.machine.is_done():
+                if not q.collector.closed:
+                    # FAILED: the statement never reached _close_stats,
+                    # and the spans it opened before the fault are what
+                    # /v1/trace and presto_tpu_stage_seconds owe it
+                    q.collector.close(q.trace_ctx)
                 self._emit_trace(q)
                 self._account_query(q)
                 self._maybe_flight_dump(q)

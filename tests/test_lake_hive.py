@@ -647,6 +647,76 @@ def test_the_hops_of_a_pipelined_scan_overlap(sorted_file, monkeypatch):
     assert max(walls) <= staging < sum(walls)
 
 
+class _SteppedClock:
+    """`time.time` as a counter: every reading, of any thread, is one
+    millisecond after the last."""
+
+    def __init__(self):
+        self.now, self.lock = 1_000_000.0, threading.Lock()
+
+    def __call__(self):
+        with self.lock:
+            self.now += 0.001
+            return self.now
+
+
+@pytest.mark.parametrize("consumer", ["device", "host"])
+def test_a_scans_thread_seconds_are_its_pieces_intervals_summed(
+        sorted_file, monkeypatch, consumer):
+    """What the envelopes of a pipelined scan hide: the pool's threads'
+    own seconds, each piece's read and decode intervals summed in file
+    order, and the statement thread's wait for pieces, noted once a
+    scan by the one producer for both consumers."""
+    from presto_tpu.exec.stats import StatsCollector, collecting, stage
+    text = "SELECT sum(quantity), max(shipdate) FROM hive.sorted_1k"
+    if consumer == "device":
+        sql(text, sf=SF)  # compile outside the stepped clock
+    pieces = {}
+    real = parquet.PieceScan._piece
+
+    def keeping(self, src):
+        piece = pieces[src[0]] = real(self, src)
+        return piece
+    monkeypatch.setattr(parquet.PieceScan, "_piece", keeping)
+    monkeypatch.setattr(time, "time", _SteppedClock())
+    collector = StatsCollector()
+    with collecting(collector):
+        if consumer == "device":
+            sql(text, sf=SF)
+        else:
+            with stage("staging"):
+                parquet.read_columns("sorted_1k", Q6_COLS)
+    monkeypatch.undo()
+    counters = collector.stats.counters
+    assert len(pieces) == 59 == counters["lake_row_groups_read"]
+    assert counters["lake_row_groups_pipelined"] == \
+        (59 if consumer == "device" else 0)
+    read_s = decode_s = 0.0
+    for group in sorted(pieces):
+        r, d = pieces[group].read_at, pieces[group].decode_at
+        read_s += r[1] - r[0]
+        decode_s += d[1] - d[0]
+    assert counters["lake_read_thread_us"] == round(read_s * 1e6) >= 59_000
+    assert counters["lake_decode_thread_us"] == round(decode_s * 1e6) \
+        >= 59_000
+    # the consumer read the clock twice a piece around its wait
+    staging = collector.stats.stages["staging"].wall_us
+    assert 59_000 <= counters["lake_consumer_wait_us"] <= staging
+    # the envelopes are what they were: first entry to last exit
+    spans = {name: (t0, t1) for name, t0, t1, *_rest in collector.spans}
+    for hop, at in (("connector_read", "read_at"), ("decode", "decode_at")):
+        assert spans[hop][0] == min(getattr(p, at)[0]
+                                    for p in pieces.values()), hop
+        assert spans[hop][1] >= max(getattr(p, at)[1]
+                                    for p in pieces.values()), hop
+
+
+def test_a_scan_that_reads_no_file_notes_no_thread_seconds():
+    counters = sql(Q6.format(t="tpch.tiny.lineitem"),
+                   sf=SF).query_stats.counters
+    assert not [k for k in counters if k.startswith("lake_")]
+
+
 def test_q6_pipelines_every_group_and_a_filtered_join_none_of_lineitems(
         lake):
     c = sql(Q6.format(t="hive.lineitem"), sf=SF).query_stats.counters

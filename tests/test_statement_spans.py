@@ -26,7 +26,7 @@ from presto_tpu.sql import plan_sql, sql
 # what tiles the server-side life of a statement, in order (`write`
 # only where there is a sink, `batch` only for a hot text shape)
 TOP_LEVEL = ("queue", "batch", "plan", "dynfilter", "staging", "execute",
-             "fetch", "render", "write")
+             "fetch", "finish", "render", "write")
 
 SELECT = ("SELECT orderkey, sum(quantity) FROM lineitem "
           "WHERE quantity < 30 GROUP BY orderkey "
@@ -80,7 +80,8 @@ def served():
 
 @pytest.mark.parametrize("name", [n for n in TOP_LEVEL if n != "write"]
                          + ["dispatch", "device_wait", "plan.sql",
-                            "plan.prepare", "batch.prepare", "batch.wait"])
+                            "plan.prepare", "batch.prepare", "batch.wait",
+                            "scan_count"])
 def test_protocol_stats_carry_every_stage(served, name):
     stages = served["select"][-1]["stats"]["stages"]
     assert name in stages, sorted(stages)
@@ -128,6 +129,8 @@ def test_children_inside_parents_and_top_level_tiles(served, which):
     ("stage.plan.prepare", "stage.plan"),
     ("stage.connector_read", "stage.staging"),
     ("stage.device_put", "stage.staging"),
+    ("stage.scan_count", "stage.staging"),
+    ("stage.finish", "query"),
     ("stage.dispatch", "stage.execute"),
     ("stage.device_wait", "stage.execute"),
     ("stage.batch.wait", "stage.batch"),
@@ -482,6 +485,103 @@ def test_kernel_hop_is_device_wait(cells, name):
     assert kernel["wall_us"] <= execute_us - compile_us + 1
 
 
+def _named(run, name):
+    return sorted((s for s in run["spans"] if s["name"] == f"stage.{name}"),
+                  key=lambda s: s["startUs"])
+
+
+@pytest.mark.parametrize("name", CELL_STATEMENTS)
+def test_scan_count_closes_staging_before_execute_opens(cells, name):
+    """What was staged is counted under `staging`, as its last child:
+    one `scan_count` a statement, over as many scans as were put, its
+    `bytes_read_back` the `active` masks read back whole to count rows
+    (a byte a row of capacity: 60,000 for lineitem at this scale where
+    nothing pruned it); `execute` opens after it."""
+    run = cells[name]
+    (count,), (staging,), (execute_,) = (
+        _named(run, n) for n in ("scan_count", "staging", "execute"))
+    assert count["parentId"] == staging["spanId"]
+    assert staging["startUs"] <= count["startUs"] \
+        and count["endUs"] <= staging["endUs"] <= execute_["startUs"]
+    puts = [p for p in _named(run, "device_put")  # not `dynfilter`'s
+            if p["parentId"] == staging["spanId"]]
+    assert count["attributes"]["scans"] == len(puts) >= 1
+    assert all(p["endUs"] <= count["startUs"] for p in puts)
+    read_back = count["attributes"]["bytes_read_back"]
+    staged = run["stats"]["stages"]["staging"]
+    assert read_back >= staged["rows"] > 0  # a live row has a mask byte
+    if name.startswith("q6"):
+        assert read_back == 60_000
+    assert run["stats"]["stages"]["scan_count"]["invocations"] == 1
+
+
+@pytest.mark.parametrize("name", CELL_STATEMENTS)
+def test_finish_is_top_level_and_follows_fetch(cells, name):
+    """From `fetch`'s exit to the return into the server: one `finish`
+    under the statement's root, after `fetch`, before `render`."""
+    run = cells[name]
+    (fetch,), (finish,), (render,), (staging,) = (
+        _named(run, n) for n in ("fetch", "finish", "render", "staging"))
+    # a sibling of `staging` and `render`: the statement's root, which
+    # the server ships last, may not be in the trace yet
+    assert finish["parentId"] == staging["parentId"] == render["parentId"]
+    assert finish["parentId"] not in {
+        s["spanId"] for s in run["spans"] if s["name"].startswith("stage.")}
+    assert fetch["endUs"] <= finish["startUs"] \
+        and finish["endUs"] <= render["startUs"]
+    assert run["stats"]["stages"]["finish"]["invocations"] == 1
+
+
+def _library_spans(text, **kw):
+    """`text` through `sql()` on a collector of the test's own: the
+    result and the collector's span records by name."""
+    from presto_tpu.exec.stats import StatsCollector, collecting
+    collector = StatsCollector()
+    with collecting(collector):
+        res = sql(text, sf=0.01, **kw)
+    by_name = {}
+    for rec in sorted(collector.spans, key=lambda r: r[1]):
+        by_name.setdefault(rec[0], []).append(rec)
+    return res, by_name
+
+
+def test_prune_is_the_dynamic_filters_host_side(cells):
+    """A dynamic-filtered scan reads its split, prunes it on the host
+    and only then re-proves and puts what is left: `prune` lies between
+    that scan's `connector_read` and `narrow_cast`, under `staging`,
+    and its `rows_in` - `rows_kept` are the rows the filter pruned; a
+    statement with no dynamic filter has no such span."""
+    res, spans = _library_spans(_cell_text("q3-mem"))
+    prunes = spans["prune"]
+    assert prunes and res.query_stats.stages["prune"].invocations \
+        == len(prunes)
+    (staging,) = spans["staging"]
+    pruned = 0
+    for name, start, end, attrs, _span, parent in prunes:
+        assert parent == staging[4]
+        pruned += attrs["rows_in"] - attrs["rows_kept"]
+        reads = [r for r in spans["connector_read"] if r[2] <= start]
+        narrows = [r for r in spans["narrow_cast"] if r[1] >= end]
+        assert reads and narrows
+        # nothing of the scan's other hops lies between them
+        between = [r for hop in HOPS for r in spans.get(hop, ())
+                   if reads[-1][2] < r[1] and r[2] < narrows[0][1]]
+        assert not between, between
+    assert pruned == res.stats["dynamic_filter_rows_pruned"]["total"] > 0
+    assert sum(a["rows_kept"] for _n, _s, _e, a, *_r in prunes) == \
+        res.stats["dynamic_filter_rows_staged"]["total"]
+    # over the protocol: in Q3's stages, not in Q6's
+    assert "prune" in cells["q3-mem"]["stats"]["stages"]
+    assert _named(cells["q3-mem"], "prune")
+    for name in ("q6-mem", "q6-gen"):
+        assert "prune" not in cells[name]["stats"]["stages"]
+        assert not _named(cells[name], "prune")
+    off, spans_off = _library_spans(
+        _cell_text("q3-mem"), session={"dynamic_filtering": False})
+    assert "prune" not in spans_off
+    assert off.rows() == res.rows()
+
+
 # a probe of 60,000 rows is four times as long as 4,096 slots; at the
 # default 65,536 no join of this scale has a second form
 TIGHT = {"join_capacity": "4096"}
@@ -603,10 +703,11 @@ def test_failed_statement_closes_its_spans(how, monkeypatch):
     """A statement that fails after `plan`, before planning or inside
     `plan`: every span opened before the fault is in the statement's
     collector, closed; the tracer holds the statement's `query` root
-    and state spans, closed, under its trace id. What the parent does
-    not do, and this pins as it is (ROADMAP C14): a failed statement's
-    collector is never closed, so its stage spans stay out of
-    /v1/trace and its walls out of presto_tpu_stage_seconds."""
+    and state spans, closed, under its trace id; and its collector is
+    closed, once, on the way out (`StatementServer._run`), so the stage
+    spans are served by /v1/trace under that root and their walls are
+    in presto_tpu_stage_seconds."""
+    from presto_tpu.server import metrics
     from presto_tpu.client import QueryError
     from presto_tpu.exec.memory import MemoryPool
     from presto_tpu.exec.stats import StatsCollector
@@ -618,6 +719,14 @@ def test_failed_statement_closes_its_spans(how, monkeypatch):
         closes.append(self.query_id)
         return real_close(self, trace)
     monkeypatch.setattr(StatsCollector, "close", counted_close)
+    observed = []
+    real_observe = metrics.observe_histogram
+
+    def counted_observe(name, value, labels=None, **kw):
+        if name == "presto_tpu_stage_seconds":
+            observed.append((labels["stage"], kw.get("trace_id")))
+        return real_observe(name, value, labels=labels, **kw)
+    monkeypatch.setattr(metrics, "observe_histogram", counted_observe)
     before = get_tracer()
     set_tracer(RecordingTracer())
     try:
@@ -629,6 +738,9 @@ def test_failed_statement_closes_its_spans(how, monkeypatch):
                         session={"failpoints": spec} if spec else {})
             (qid, q), = srv._queries.items()
             assert q.machine.state == "FAILED"
+            deadline = time.time() + 5  # the client saw FAILED; the
+            while not q.collector.closed and time.time() < deadline:
+                time.sleep(0.01)  # statement's thread is on its way out
             with urllib.request.urlopen(f"{srv.url}/v1/trace/{qid}") as r:
                 spans = json.load(r)["spans"]
             recorded = list(q.collector.spans)
@@ -647,10 +759,17 @@ def test_failed_statement_closes_its_spans(how, monkeypatch):
     by_id = {s["spanId"] for s in spans}
     assert all(s["parentId"] in by_id for s in spans
                if s["name"] != "query")
-    # the parent's behaviour (the gap): never closed, nothing shipped
-    assert closes.count(qid) == 0
-    assert not [s["name"] for s in spans
-                if s["name"].startswith("stage.")]
+    # closed once, and every recorded span shipped under the root
+    assert closes.count(qid) == 1
+    shipped = sorted(s["name"] for s in spans
+                     if s["name"].startswith("stage."))
+    assert shipped == sorted(f"stage.{rec[0]}" for rec in recorded)
+    assert {f"stage.{n}" for n in opened} <= set(shipped)
+    # the walls of its stages, exemplar'd with the statement's trace id
+    walled = {n for n, st in q.collector.stats.stages.items()
+              if st.wall_us}
+    assert {stage_name for stage_name, _tid in observed} == walled
+    assert {tid for _n, tid in observed} <= {q.trace_ctx.trace_id}
 
 
 def test_flight_dump_has_one_account_of_time(tmp_path):
