@@ -851,7 +851,7 @@ class BatchingExecutor:
                 jax.block_until_ready(out)
             finally:
                 prog.release(state="FINISHED")
-            flags, steps, compacted = split_flags(
+            flags, steps, compacted, direct = split_flags(
                 plan.split_status(overflow)[0])
             if int(flags.max()) != 0:
                 # a member overflowed a static bucket: the serial
@@ -870,7 +870,7 @@ class BatchingExecutor:
             return
         device_us = int((time.time() - t0) * 1e6)
         self._fan_out(out, plan, entries, device_us, steps, expand_steps,
-                      compacted)
+                      compacted, direct)
         self._account(entries)
 
     def _stage_inputs(self, key, plan, sf: float) -> list:
@@ -936,7 +936,7 @@ class BatchingExecutor:
 
     def _fan_out(self, out, plan, entries: List[_Pending],
                  device_us: int, search_steps, expand_steps,
-                 compacted) -> None:
+                 compacted, direct) -> None:
         """Slice the batched output back into per-member QueryResults
         (member i owns batch row i -- ordering is positional by
         construction). ONE host conversion covers the whole batch;
@@ -960,8 +960,9 @@ class BatchingExecutor:
             qs.output_rows = res.row_count
             qs.counters["batched_queries"] = 1
             qs.counters["batch_size"] = nbatch
-            if search_steps[i]:
+            if search_steps[i] or direct[i] or expand_steps is not None:
                 qs.counters["join_search_steps"] = int(search_steps[i])
+                qs.counters["join_lookup_direct"] = int(direct[i])
             if expand_steps is not None:
                 qs.counters["join_expand_steps"] = expand_steps
                 qs.counters["join_probe_compacted"] = int(compacted[i])

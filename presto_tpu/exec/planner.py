@@ -50,18 +50,23 @@ __all__ = ["compile_plan", "CompiledPlan", "split_flags"]
 # the status word a program returns beside its batch: overflow flags in
 # the low bits, the joins' binary-search trips in STEP_BITS from
 # FLAG_BITS up (held to what the field takes: 127 lookups of 32 trips),
-# the joins whose probe was compacted above them
+# then in COUNT_BITS each the joins whose probe was compacted and the
+# lookups their directory answered alone (31 each at most)
 FLAG_BITS = 8
 STEP_BITS = 12
+COUNT_BITS = 5
 
 
 def split_flags(word):
     """The status word, taken apart on the host: (overflow flags: bit0
-    hard, bit1 exchange slots; join_search_steps; join_probe_compacted).
-    `word` is an int, or the array of them a vmapped program returns."""
+    hard, bit1 exchange slots; join_search_steps; join_probe_compacted;
+    join_lookup_direct). `word` is an int, or the array of them a
+    vmapped program returns."""
+    count = (1 << COUNT_BITS) - 1
+    at = FLAG_BITS + STEP_BITS
     return (word & ((1 << FLAG_BITS) - 1),
             (word >> FLAG_BITS) & ((1 << STEP_BITS) - 1),
-            word >> (FLAG_BITS + STEP_BITS))
+            (word >> at) & count, (word >> (at + COUNT_BITS)) & count)
 
 
 @dataclasses.dataclass
@@ -271,11 +276,18 @@ def compile_plan(root: N.PlanNode, mesh=None,
                     and not right_replicated:  # exchange already gathered
                 build = broadcast_build(build, axis)
             cap = node.out_capacity or default_join_capacity
+            # a build side hash-exchanged here holds a share of its keys'
+            # span: the lookup's directory is that many times wider
+            exchanged = (dist and isinstance(node.right, N.ExchangeNode)
+                         and node.right.kind == "REPARTITION"
+                         and node.right.scope == "REMOTE")
             r = hash_join(probe, build, node.left_keys, node.right_keys,
-                          cap, node.join_type, node.right_output_channels)
+                          cap, node.join_type, node.right_output_channels,
+                          spread=n_workers if exchanged else 1)
             _note_overflow(r.overflow)
             needs[order[id(node)]] = r.num_rows
             search_steps.append(r.search_steps)
+            direct.append(r.direct)
             expand_steps.append(r.expand_steps)
             compacted.append(r.compacted)
             return r.batch
@@ -291,9 +303,13 @@ def compile_plan(root: N.PlanNode, mesh=None,
                 else [node.source_key]
             fk = node.filtering_key if isinstance(node.filtering_key, list) \
                 else [node.filtering_key]
+            looked: List = []
             m, mnull = semi_join_mask(src, filt, sk, fk,
                                       node.null_keys_match,
-                                      steps_out=search_steps)
+                                      steps_out=looked)
+            for steps, answered in looked:
+                search_steps.append(steps)
+                direct.append(answered.astype(jnp.int32))
             from ..block import Column
             return Batch(src.columns + (Column(m, mnull, T.BOOLEAN),),
                          src.active)
@@ -435,6 +451,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
 
     overflow_box: List = []
     search_steps: List = []  # one trip count per join lookup lowered
+    direct: List = []  # one count per join: lookups its directories answered
     expand_steps: List[int] = []  # one per join expansion lowered
     compacted: List = []  # one 0/1 per join: its probe was compacted
     needs: Dict[int, jax.Array] = {}  # pre-order index -> rows needed
@@ -449,6 +466,7 @@ def compile_plan(root: N.PlanNode, mesh=None,
     def run(scan_batches: Sequence[Batch]):
         overflow_box.clear()
         search_steps.clear()
+        direct.clear()
         expand_steps.clear()
         compacted.clear()
         needs.clear()
@@ -469,20 +487,27 @@ def compile_plan(root: N.PlanNode, mesh=None,
                 hard = hard | f
         steps = sum(search_steps, jnp.zeros((), dtype=jnp.int32))
         took = sum(compacted, jnp.zeros((), dtype=jnp.int32))
+        answered = sum(direct, jnp.zeros((), dtype=jnp.int32))
         if dist:
             hard = jax.lax.psum(hard.astype(jnp.int32), axis) > 0
             slots = jax.lax.psum(slots.astype(jnp.int32), axis) > 0
             steps = jax.lax.pmax(steps, axis)  # the deepest shard's
             took = jax.lax.pmax(took, axis)  # each shard chooses for itself
+            # what every shard's directory answered (a min, as a max:
+            # the all-reduce the chip is known to lower in 32 bits)
+            answered = -jax.lax.pmax(-answered, axis)
         # one word, one host read: bit0 = hard (non-scalable), bit1 =
-        # exchange slots, then the joins' binary-search trips and the
-        # joins that compacted their probe (the counters
-        # join_search_steps, join_probe_compacted; `split_flags` takes
-        # it apart)
+        # exchange slots, then the joins' binary-search trips, the
+        # joins that compacted their probe and the lookups the directory
+        # answered (the counters join_search_steps, join_probe_compacted,
+        # join_lookup_direct; `split_flags` takes it apart)
         steps = jnp.minimum(steps, (1 << STEP_BITS) - 1)
+        count = (1 << COUNT_BITS) - 1
+        at = FLAG_BITS + STEP_BITS
         word = (hard.astype(jnp.int32) + 2 * slots.astype(jnp.int32)
                 + (steps << FLAG_BITS)
-                + (took << (FLAG_BITS + STEP_BITS)))
+                + (jnp.minimum(took, count) << at)
+                + (jnp.minimum(answered, count) << (at + COUNT_BITS)))
         # beside the word, still one host read (`split_status`): what
         # each counted node needed, its join's output rows or its
         # groups, which the ladder sizes the node from; a capacity is a

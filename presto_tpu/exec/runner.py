@@ -1048,14 +1048,15 @@ def _read_status(status, plan, expand_steps: Optional[int]
     trips its joins' lookups took go to the statement's counters, and
     with them `expand_steps`, the trips of its joins' expansions
     (`CompiledPlan.expand_steps_of`: None for a program without a
-    join), and how many of its joins compacted their probe. With the
-    flags come the bytes of rows a meshed program's exchanges routed (a
-    chip's mean) and what each counted node needed, by pre-order
-    index."""
+    join), how many of its joins compacted their probe, and how many of
+    its lookups their directory answered alone. With the flags come the
+    bytes of rows a meshed program's exchanges routed (a chip's mean)
+    and what each counted node needed, by pre-order index."""
     word, routed, needs = plan.split_status(status)
-    flags, steps, compacted = split_flags(int(word))
-    if steps:
-        note("join_search_steps", steps)
+    flags, steps, compacted, direct = split_flags(int(word))
+    if steps or direct or expand_steps is not None:  # a lookup ran
+        note("join_search_steps", steps)  # 0 where directories answered
+        note("join_lookup_direct", direct)
     if expand_steps is not None:  # 0 too: a join whose table is its
         note("join_expand_steps", expand_steps)  # own directory
         note("join_probe_compacted", compacted)

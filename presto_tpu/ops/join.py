@@ -9,13 +9,16 @@ TPU-first redesign: no pointer-chasing hash table. The build side is
 SORTED by key words once (O(n log n) on device), and a probe row looks
 its key up ONCE (`_match_ranges`): a bucket directory over the sorted
 keys, indexed by the high bits of key - min (Velox HashTable's array
-mode, without a second code path), brackets the key to a handful of
-build rows; a binary search runs only as deep as the fullest bracket
-needs (1 trip on dense keys, never more than log n); the end of the
-match range is read off the build side's run ends. A gather pass over
-the probe is the unit of cost on the chip: four 32-bit ones here where
-keys are dense, against 2 x log n of 64 bits. 1:N matches expand
-through a static-capacity prefix-sum expansion:
+mode). Where the keys' span fits the directory (a bucket is one key
+value: primary keys, dense ranks) the directory IS the answer, packed
+one int32 a bucket, and a probe row costs one 32-bit gather; where it
+does not, the directory brackets the key to a handful of build rows, a
+binary search runs only as deep as the fullest bracket needs (never
+more than log n), and the end of the match range is read off the build
+side's run ends: three or four gathers. The device picks by its own
+directory's shift. A gather pass over the probe is the unit of cost on
+the chip. 1:N matches expand through a static-capacity prefix-sum
+expansion:
 
   start[i], end[i] = _match_ranges(build, probe_i)
   cnt[i]   = end - start  (0 for null/missing keys)
@@ -83,12 +86,14 @@ class JoinResult:
     overflow: jnp.ndarray
     search_steps: jnp.ndarray  # binary-search trips the lookups took
     compacted: jnp.ndarray  # 1 where the probe took the compacted form
+    direct: jnp.ndarray  # lookups the directory answered alone
     expand_steps: int = 0  # gather trips a slot of `_slot_rows` took
 
 
 jax.tree_util.register_dataclass(JoinResult,
                                  data_fields=["batch", "num_rows", "overflow",
-                                              "search_steps", "compacted"],
+                                              "search_steps", "compacted",
+                                              "direct"],
                                  meta_fields=["expand_steps"])
 
 
@@ -196,47 +201,67 @@ def _halves(words: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return (words >> 32).astype(jnp.uint32), words.astype(jnp.uint32)
 
 
+def _directory_bits(nb: int, spread: int) -> int:
+    """log2 of the buckets `_match_ranges`' directory has over `nb`
+    build rows: 2**ceil(log2 nb), times `spread` (rounded up to a power
+    of two) where the build side is one of `spread` hash shares of its
+    keys (it reached the join through `exchange_by_hash`, so its keys
+    lie about `spread` apart over the whole span)."""
+    return max((nb - 1).bit_length(), 1) + (spread - 1).bit_length()
+
+
 @jax.named_scope("_match_ranges")
 def _match_ranges(sorted_keys: jnp.ndarray, n_usable: jnp.ndarray,
-                  queries: jnp.ndarray):
+                  queries: jnp.ndarray, spread: int = 1):
     """The lookup that turns a probe key into its match range. For each
     query, [start, end) are the positions of its key among the first
     `n_usable` rows of `sorted_keys` (uint64, ascending; the rows behind
     them are `_sort_build`'s MAX-masked tail and never match): the
     integers searchsorted(side="left") / (side="right") give once
     clamped to n_usable, as int32. Also returns `steps`, the
-    binary-search trips taken, a device scalar.
+    binary-search trips taken, and `direct`, whether the directory
+    answered alone (device scalars).
 
-    1. A directory of D = 2**ceil(log2 nb) buckets over the usable keys'
-       range: bucket(key) = (key - min) >> shift, monotone in the key,
-       so directory[b] = first sorted position whose bucket is >= b, and
-       a query's range lies inside [directory[b(q)], directory[b(q) + 1]].
-       O(nb + D), no pass over the queries.
-    2. A lower-bound search inside that bracket, as deep as the fullest
-       bracket needs: 1 trip where keys are dense (orderkeys, partkeys),
-       the log of the longest run of equal keys where they repeat,
-       ceil(log2 nb) at worst (one far outlier: a plain search's depth).
-    3. `end` without a second search: the search remembers whether the
+    1. A directory of D buckets (`_directory_bits`) over the usable
+       keys' range: bucket(key) = (key - min) >> shift, monotone in the
+       key, so directory[b] = first sorted position whose bucket is >= b,
+       and a query's range lies inside [directory[b(q)],
+       directory[b(q) + 1]]. O(nb + D), no pass over the queries.
+    2. One gather a query from a table of D + 2 rows (a row for below
+       the smallest key, one for past the largest). Where the span fits
+       the directory (shift 0: a bucket is one key value, as for the
+       primary keys TPC-H joins on, or dense ranks) and n << k fits 31
+       bits, k the bits of the longest run, a row holds its bucket's
+       range packed, start << k | (end - start): that is the answer,
+       with no search trip (`steps` 0) and no second gather. Else it
+       holds the bracket's low end, and 3-4 follow. The device chooses
+       by its own shift; no host read.
+    3. A lower-bound search inside the bracket, as deep as the fullest
+       bracket needs: the log of the longest run of equal keys where
+       they repeat, ceil(log2 nb) at worst (one far outlier: a plain
+       search's depth); keys are read as two uint32 halves.
+    4. `end` without a second search: the search remembers whether the
        row it settles on holds the query's key; if so its run of equal
-       keys ends where the build side says (`run_end`, O(nb)).
+       keys ends where the build side says (`run_end`, O(nb)), read in
+       the one branch of a `cond` the direct form does not take.
 
-    A pass over the queries costs by its gathers, 7.6 ns an index for
-    one 32-bit lane on the chip (a uint64 lane: 19-29), so keys are
-    read as two uint32 halves and only the bracket's low end is looked
-    up: four such gathers a query where keys are dense. (Rows of 2-4
-    lanes gather at 2.3-5.3 ns an index, but XLA:TPU then pads the
-    result to 512 bytes a query when the table is small: PERF.md.)
-    """
+    A pass over the queries costs by its gathers (7.6-9.8 ns an index
+    for one 32-bit lane, 18.8 where the indices scatter over a 15M-row
+    table; a uint64 lane 19-29): one a query in the direct form, four
+    where the search takes one trip (PERF.md, PR 37: step 0's layouts;
+    a row of two lanes, or a uint64 one, costs more)."""
     nb = sorted_keys.shape[0]
     if nb == 0:
         zero = jnp.zeros(queries.shape, dtype=jnp.int32)
-        return zero, zero, jnp.zeros((), dtype=jnp.int32)
+        return (zero, zero, jnp.zeros((), dtype=jnp.int32),
+                jnp.zeros((), dtype=bool))
     if nb == 1:
         # a second, tail row: XLA:CPU folds the cumsum of a ONE-update
         # scatter to a wrong constant (met with one query, jax 0.9)
         sorted_keys = jnp.concatenate([sorted_keys, sorted_keys])
         nb = 2
-    log2d = max((nb - 1).bit_length(), 1)
+    log2d = _directory_bits(nb, spread)
+    d = 1 << log2d
     n = n_usable.astype(jnp.int32)
     pos = jnp.arange(nb, dtype=jnp.int32)
 
@@ -250,29 +275,34 @@ def _match_ranges(sorted_keys: jnp.ndarray, n_usable: jnp.ndarray,
             .astype(jnp.int32)
 
     # the build rows' buckets ascend with their position, tail included
-    # (clipped to kmax's bucket, weight 0)
-    hist = jnp.zeros(2 ** log2d + 1, dtype=jnp.int32).at[
+    # (clipped to kmax's bucket, weight 0); summed along rows, as a flat
+    # cumsum over 2**26 buckets would compile for minutes (PERF.md, PR 29)
+    hist = jnp.zeros(d + 1, dtype=jnp.int32).at[
         bucket(sorted_keys) + 1].add((pos < n).astype(jnp.int32),
                                      indices_are_sorted=True)
-    directory = jnp.cumsum(hist, dtype=jnp.int32)
+    directory = _running_sum(hist)
     fullest = jnp.max(directory[1:] - directory[:-1])
-    steps = 32 - jax.lax.clz(fullest)  # ceil(log2(fullest + 1))
+    k = 32 - jax.lax.clz(fullest)  # ceil(log2(fullest + 1))
+    # a bucket is one key value, and n << k fits an int32's 31 bits
+    direct = (shift == 0) & (k + 32 - jax.lax.clz(n) <= 31)
 
-    # run_end[i]: one past the last usable position holding row i's key,
-    # through run ids (cumsum is the one scan XLA:TPU compiles in
-    # seconds: 6 s at 1.5M rows where cummin takes 30)
-    prev = jnp.concatenate([sorted_keys[:1], sorted_keys[:-1]])
-    run = jnp.cumsum(((sorted_keys != prev) | (pos == n)).astype(jnp.int32),
-                     dtype=jnp.int32)
-    run_start = jnp.full(nb + 1, nb, dtype=jnp.int32).at[run].min(
-        pos, indices_are_sorted=True)
-    run_end = jnp.minimum(run_start[run + 1], n)
-
-    # the bracket's low end is looked up; its high end is at most
-    # `fullest` rows on, and the rows between the true one and that
-    # belong to higher buckets: greater keys, so the search is the same
-    lo = directory[bucket(queries)]
-    hi = jnp.minimum(lo + fullest, n)
+    # one gather a query: row 0 below the smallest key, b + 1 for bucket
+    # b, D + 1 past the last. Directly the row holds start << k | (end -
+    # start), the answer; else the bracket's low end (its high end is at
+    # most `fullest` rows on, and the rows between the true one and that
+    # belong to higher buckets: greater keys, so the search is the same)
+    bits = jnp.where(direct, k, 0)
+    length = jnp.concatenate([directory[1:] - directory[:-1],
+                              jnp.zeros(1, dtype=jnp.int32)])
+    table = jnp.concatenate([jnp.zeros(1, dtype=jnp.int32),
+                             jnp.where(direct, (directory << k) | length,
+                                       directory)])
+    row = jnp.where(queries < kmin, 0, jnp.minimum(
+        (queries - kmin) >> shift, d).astype(jnp.int32) + 1)
+    packed = table[row]
+    lo = packed >> bits
+    hi = jnp.where(direct, lo + (packed & ((1 << bits) - 1)),
+                   jnp.minimum(lo + fullest, n))
     high, low = _halves(sorted_keys)
     q_high, q_low = _halves(queries)
 
@@ -287,19 +317,38 @@ def _match_ranges(sorted_keys: jnp.ndarray, n_usable: jnp.ndarray,
                 jnp.where(left, (k_high == q_high) & (k_low == q_low), hit))
 
     # hit: the row settled on holds the query's key; none yet (lo > hi
-    # is all False, and as varying as the carry under shard_map)
-    start, _, hit = jax.lax.fori_loop(0, steps, halve, (lo, hi, lo > hi))
-    end = jnp.where(hit, run_end[jnp.minimum(start, nb - 1)], start)
-    return start, end, steps
+    # is all False, and as varying as the carry under shard_map). No
+    # trip where the directory answered: [lo, hi) is the range
+    steps = jnp.where(direct, 0, k)
+    start, top, hit = jax.lax.fori_loop(0, steps, halve, (lo, hi, lo > hi))
+
+    def searched():
+        # run_end[i]: one past the last usable position holding row i's
+        # key, through run ids (cumsum is the one scan XLA:TPU compiles
+        # in seconds: 6 s at 1.5M rows where cummin takes 30)
+        prev = jnp.concatenate([sorted_keys[:1], sorted_keys[:-1]])
+        run = jnp.cumsum(((sorted_keys != prev) | (pos == n))
+                         .astype(jnp.int32), dtype=jnp.int32)
+        run_start = jnp.full(nb + 1, nb, dtype=jnp.int32).at[run].min(
+            pos, indices_are_sorted=True)
+        run_end = jnp.minimum(run_start[run + 1], n)
+        return jnp.where(hit, run_end[jnp.minimum(start, nb - 1)], start)
+
+    # the end of the range: the directory's, or read off the build
+    # side's run ends (a second gather a query) where it searched
+    end = jax.lax.cond(direct, lambda: top, searched)
+    return start, end, steps, direct
 
 
 def _lookup(sorted_words: Sequence[jnp.ndarray], usable: jnp.ndarray,
-            query_words: Sequence[jnp.ndarray]):
+            query_words: Sequence[jnp.ndarray], spread: int = 1):
     """`_match_ranges` of `query_words` in one side's `_sort_build`
-    output; several words a key go through `_pack_ranks` first."""
+    output; several words a key go through `_pack_ranks` first (dense
+    ranks: the directory answers them)."""
     n_usable = jnp.sum(usable, dtype=jnp.int32)
     if len(query_words) == 1:
-        return _match_ranges(sorted_words[0], n_usable, query_words[0])
+        return _match_ranges(sorted_words[0], n_usable, query_words[0],
+                             spread)
     ranks, q_ranks = _pack_ranks(list(sorted_words), list(query_words))
     return _match_ranges(ranks.astype(jnp.uint64), n_usable,
                          q_ranks.astype(jnp.uint64))
@@ -415,17 +464,19 @@ def _compact_probe(emits: jnp.ndarray, capacity: int) -> jnp.ndarray:
 
 def _probe_slots(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
                  p_words: Sequence[jnp.ndarray], p_usable: jnp.ndarray,
-                 p_active: jnp.ndarray, outer_probe: bool, out_capacity: int):
+                 p_active: jnp.ndarray, outer_probe: bool, out_capacity: int,
+                 spread: int):
     """The probe side of the join over the rows given (the whole probe,
     or its compacted rows): look every row up, sum what each emits, and
     map the output slots back. Returns, per slot, the row that emits it
     (int32), whether the slot is live, whether it carries a build row,
     and that row's place in the sorted build side; then the slots the
-    rows emit in all (int64) and the lookups' search trips."""
+    rows emit in all (int64), the lookup's search trips and whether its
+    directory answered alone."""
     n = p_usable.shape[0]
     nb = b_usable.shape[0]
     # match ranges inside the usable (sorted-front) region
-    start, end, steps = _lookup(sb_words, b_usable, p_words)
+    start, end, steps, direct = _lookup(sb_words, b_usable, p_words, spread)
 
     # per probe row in int32 (they are gathered per slot), and so their
     # running sum: exact where the total fits 31 bits, and a total that
@@ -448,12 +499,13 @@ def _probe_slots(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
     valid = (k < total) & (j < emit[prow])
     matched = j < cnt[prow]
     srow = jnp.clip(start[prow] + j, 0, nb - 1)
-    return prow, valid, matched, srow, total, steps
+    return prow, valid, matched, srow, total, steps, direct
 
 
 def _probe_side(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
                 p_keys: Sequence[Block], p_active: jnp.ndarray,
-                outer_probe: bool, out_capacity: int, compact_capacity: int):
+                outer_probe: bool, out_capacity: int, compact_capacity: int,
+                spread: int = 1):
     """`_probe_slots` over the probe rows that can emit a slot, where
     there are at most `compact_capacity` of them (`_compact_probe`; the
     key columns gathered at those rows), and over every probe row where
@@ -469,7 +521,7 @@ def _probe_side(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
     def slots(keys, active):
         words, usable = _combined_key(keys, active)
         return _probe_slots(sb_words, b_usable, words, usable, active,
-                            outer_probe, out_capacity)
+                            outer_probe, out_capacity, spread)
 
     def full():
         return slots(p_keys, p_active)
@@ -485,11 +537,11 @@ def _probe_side(sb_words: Sequence[jnp.ndarray], b_usable: jnp.ndarray,
     def compacted():
         crow = _compact_probe(emits, compact_capacity)
         live = jnp.arange(compact_capacity, dtype=jnp.int32) < n_emit
-        prow, valid, matched, srow, total, steps = slots(
+        prow, valid, matched, srow, total, steps, direct = slots(
             [_gather(c, crow) for c in p_keys], live)
         # a slot past the total names the last probe row, as in `full`
         return (jnp.where(valid, crow[prow], npr - 1), valid, matched, srow,
-                total, steps)
+                total, steps, direct)
 
     took = n_emit <= compact_capacity
     return (*jax.lax.cond(took, compacted, full), took)
@@ -501,7 +553,8 @@ def hash_join(probe: Batch, build: Batch,
               build_key_channels: Sequence[int],
               out_capacity: int,
               join_type: str = "inner",
-              build_output_channels: Optional[Sequence[int]] = None) -> JoinResult:
+              build_output_channels: Optional[Sequence[int]] = None,
+              spread: int = 1) -> JoinResult:
     """Join probe x build. join_type in {inner, left, right, full}
     (spi/plan/JoinType.java:20-23). Output columns are probe.columns ++
     build.columns[build_output_channels].
@@ -516,11 +569,17 @@ def hash_join(probe: Batch, build: Batch,
     (each build row must live on exactly one worker; plan.distribute
     forces it).
 
-    The probe side is `_probe_side`: four 32-bit gathers a probe row
-    where every row is looked up, none for a row that cannot emit where
-    those that can fit `out_capacity` and the probe is four times as
-    long (the result's `compacted` says which ran: the counter
-    join_probe_compacted); `expand_steps` is the same in both forms."""
+    The probe side is `_probe_side`: one 32-bit gather a probe row where
+    every row is looked up and the build keys' span fits the lookup's
+    directory (three or four where it does not: `direct` counts the
+    lookups answered by the directory, the counter join_lookup_direct),
+    none for a row that cannot emit where those that can fit
+    `out_capacity` and the probe is four times as long (the result's
+    `compacted` says which ran: the counter join_probe_compacted);
+    `expand_steps` is the same in both forms. `spread` is the number of
+    hash shares the build side is one of (it was exchanged by its keys
+    over that many chips): its keys then lie that far apart, and the
+    lookup's directory is that many times wider."""
     assert join_type in ("inner", "left", "right", "full"), join_type
     if build_output_channels is None:
         build_output_channels = range(build.num_columns)
@@ -537,18 +596,21 @@ def hash_join(probe: Batch, build: Batch,
     # sort build by key words (unusable rows masked to MAX, sorted last)
     sb_words, b_perm = _sort_build(b_words, b_usable,
                                    jnp.arange(nb, dtype=jnp.int32))
-    prow, valid, matched, srow, total, steps, took = _probe_side(
+    prow, valid, matched, srow, total, steps, direct, took = _probe_side(
         sb_words, b_usable, p_keys, probe.active,
         join_type in ("left", "full"), out_capacity,
-        _compact_capacity(npr, out_capacity))
+        _compact_capacity(npr, out_capacity), spread)
+    direct = direct.astype(jnp.int32)
     expand_steps = _slot_trips(npr, out_capacity)
 
     outer_build = join_type in ("right", "full")
     if outer_build:
         # reverse probe: does any usable probe row carry this build key?
         sp_words, _ = _sort_build(p_words, p_usable, None)
-        bs, be, steps2 = _lookup(sp_words, p_usable, b_words)
+        bs, be, steps2, direct2 = _lookup(sp_words, p_usable, b_words,
+                                          spread)
         steps = steps + steps2
+        direct = direct + direct2.astype(jnp.int32)
         b_matched = b_usable & (be > bs)
         unmatched = build.active & ~b_matched
         u = unmatched.astype(jnp.int32)
@@ -583,7 +645,7 @@ def hash_join(probe: Batch, build: Batch,
         out_cols.append(g)
     out = Batch(tuple(out_cols), all_valid)
     return JoinResult(out, total2, overflow, steps, took.astype(jnp.int32),
-                      expand_steps)
+                      direct, expand_steps)
 
 
 from ..block import gather_block as _gather  # shared row gather
@@ -609,7 +671,8 @@ def semi_join_mask(probe: Batch, build: Batch,
     FROM) and null_flag is always False -- the INTERSECT/EXCEPT and
     mark-distinct membership semantics.
 
-    The lookup's binary-search trip count is appended to `steps_out`."""
+    The lookup's binary-search trip count and whether its directory
+    answered alone are appended to `steps_out`."""
     p_keys = [probe.column(c) for c in probe_key_channels]
     b_keys = [build.column(c) for c in build_key_channels]
     p_keys, b_keys = _align_key_widths(p_keys, b_keys)
@@ -623,9 +686,9 @@ def semi_join_mask(probe: Batch, build: Batch,
         p_words, p_usable = _combined_key(p_keys, probe.active)
         b_words, b_usable = _combined_key(b_keys, build.active)
     sb_words, _ = _sort_build(b_words, b_usable, None)
-    start, end, steps = _lookup(sb_words, b_usable, p_words)
+    start, end, steps, direct = _lookup(sb_words, b_usable, p_words)
     if steps_out is not None:
-        steps_out.append(steps)
+        steps_out.append((steps, direct))
     match = p_usable & (end > start)
     if null_keys_match:
         return match, jnp.zeros_like(match)

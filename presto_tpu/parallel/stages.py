@@ -94,7 +94,8 @@ def distributed_hash_join(probe_shard: Batch, build_shard: Batch,
                                        slot_capacity)
         overflow = p_ovf | b_ovf
         res = hash_join(p_ex, b_ex, probe_keys, build_keys, out_capacity,
-                        join_type, build_output_channels)
+                        join_type, build_output_channels,
+                        spread=jax.lax.axis_size(axis_name))
     overflow = jax.lax.psum((overflow | res.overflow).astype(jnp.int32),
                             axis_name) > 0
     return res, overflow

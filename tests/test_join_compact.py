@@ -254,7 +254,11 @@ def test_compacted_branch_gathers_by_the_capacity(join_type):
                                            .astype(np.int32)], capacity=nb)
     jaxpr = jax.make_jaxpr(lambda p, b: hash_join(
         p, b, [0], [0], capacity, join_type).batch)(probe, build)
-    conds = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    # the probe side's `cond` (its seven values; the lookups' own, a
+    # range's end, lie inside its branches
+    # and, for the reverse probe, beside it)
+    conds = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"
+             and len(e.outvars) == 7]
     assert len(conds) == 1
     full, compacted = conds[0].params["branches"]  # index 0 is False
 
@@ -271,7 +275,9 @@ def test_compacted_branch_gathers_by_the_capacity(join_type):
 
 def test_ineligible_shape_compiles_one_form():
     """A probe no longer than four outputs (Q3's `JoinNode.6`): no
-    `cond`, no second form, and the counter's share is a constant 0."""
+    second form and no `cond` between forms (the lookup's own, between
+    the directory's answer and the search, returns a range's end), and
+    the counter's share is a constant 0."""
     rng = np.random.default_rng(6)
     probe = batch_from_numpy([T.INTEGER], [rng.integers(0, 400, 3000)
                                            .astype(np.int32)], capacity=3000)
@@ -283,5 +289,6 @@ def test_ineligible_shape_compiles_one_form():
         return r.batch, r.compacted
 
     jaxpr = jax.make_jaxpr(fn)(probe, build)
-    assert not [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    assert [len(e.outvars) for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name == "cond"] == [1]
     assert int(jax.jit(fn)(probe, build)[1]) == 0

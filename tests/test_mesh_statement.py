@@ -275,6 +275,24 @@ def test_the_session_values_keep_working(server, value, want):
     assert (kinds.get("exchange.hash", 0) >= 2) == (want == "partitioned")
 
 
+@pytest.mark.parametrize("value", ["BROADCAST", "PARTITIONED"])
+@pytest.mark.parametrize("template,joins", [("q3", 2), ("q14", 1)])
+def test_every_join_is_answered_by_its_directory(server, template, joins,
+                                                 value):
+    """The build keys are primary keys (custkey, orderkey, partkey): a
+    replicated build holds their whole span, a hash-exchanged one a
+    quarter of it spread over the whole span, in a directory four times
+    wider (`spread`). Either way the directory answers every lookup,
+    with no search trip, on every chip, and the rows are one chip's."""
+    done = execute(server.url, _text(template, "x4_"),
+                   session={"join_distribution_type": value})
+    assert done.data == execute(server.url, _text(template, "x1_")).data
+    counters = _counters(done)
+    assert (counters.get("exchange.hash", 0) >= 2) == (value == "PARTITIONED")
+    assert counters["join_lookup_direct"] == joins
+    assert counters["join_search_steps"] == 0
+
+
 def test_a_receivers_capacity_follows_its_shard(server):
     """Slots are sized from the sender's shard: after a hash exchange a
     chip holds a little over what it sent, not the whole table's

@@ -316,18 +316,20 @@ Q6 = ("SELECT sum(extendedprice * discount) FROM lineitem "
 @pytest.mark.parametrize("text,joins", [(Q3, 2), (Q6, 0)],
                          ids=["q3", "q6"])
 def test_join_search_steps_ride_the_status_word(text, joins):
-    """The trips `_match_ranges` took leave the device in the word the
-    program already returns (bits 8 and up) and land in the statement's
-    counters over the protocol; a statement without a join has none."""
+    """The trips `_match_ranges` took and the lookups its directory
+    answered alone leave the device in the word the program already
+    returns (bits 8 and up) and land in the statement's counters over
+    the protocol; a statement without a join has neither. Q3's build
+    keys (customer, orders) are dense: both directories answer, with no
+    trip."""
     with StatementServer(sf=0.01) as srv:
         stats = execute(srv.url, text).stats["queryStats"]
     steps = stats["counters"].get("join_search_steps")
+    direct = stats["counters"].get("join_lookup_direct")
     if not joins:
-        assert steps is None
+        assert steps is None and direct is None
     else:
-        # at least a trip a join, at most a whole binary search of the
-        # largest build capacity (the default join capacity, 2**16) each
-        assert joins <= steps <= joins * 16
+        assert (steps, direct) == (0, joins)
     assert stats["stages"]["device_wait"]["invocations"] == \
         stats["stages"]["dispatch"]["invocations"]
 
@@ -365,9 +367,9 @@ def test_a_hard_overflow_still_reruns_under_the_steps():
     assert roomy.query_stats.stages["dispatch"].invocations == 1
     assert tight.query_stats.stages["dispatch"].invocations == 2
     assert tight.stats["capacity_reruns"]["count"] == 1
-    once = roomy.query_stats.counters["join_search_steps"]
-    assert once >= 1
-    assert tight.query_stats.counters["join_search_steps"] == 2 * once
+    assert roomy.query_stats.counters["join_lookup_direct"] == 1
+    assert tight.query_stats.counters["join_lookup_direct"] == 2
+    assert tight.query_stats.counters["join_search_steps"] == 0
     # 11,976 probe rows a slot: blocks of 1 at 65,536 slots; of 16 and
     # 1 at 1,024 and 16,384
     assert roomy.query_stats.counters["join_expand_steps"] == 0
@@ -656,20 +658,25 @@ def test_a_ladder_rerun_sums_the_compacted_probes():
     assert tight.query_stats.counters["join_expand_steps"] == 6 + 4
 
 
-def test_the_status_word_splits_three_ways():
+def test_the_status_word_splits_four_ways():
     """Flags in bits 0-7, the search trips in the twelve above them
-    (held to the field), the compacted probes above those; an array of
-    words (a vmapped program's) splits lane by lane."""
+    (held to the field), then five bits each for the compacted probes
+    and the lookups the directory answered; an array of words (a
+    vmapped program's) splits lane by lane."""
     import numpy as np
-    from presto_tpu.exec.planner import FLAG_BITS, STEP_BITS, split_flags
-    word = 1 + (37 << FLAG_BITS) + (2 << FLAG_BITS + STEP_BITS)
-    assert split_flags(word) == (1, 37, 2)
-    assert split_flags(2) == (2, 0, 0)
-    flags, steps, compacted = split_flags(
-        np.asarray([word, 3 << FLAG_BITS, 1 << FLAG_BITS + STEP_BITS],
-                   dtype=np.int32))
-    assert (flags.tolist(), steps.tolist(), compacted.tolist()) == \
-        ([1, 0, 0], [37, 3, 0], [2, 0, 1])
+    from presto_tpu.exec.planner import (COUNT_BITS, FLAG_BITS, STEP_BITS,
+                                         split_flags)
+    at = FLAG_BITS + STEP_BITS
+    word = 1 + (37 << FLAG_BITS) + (2 << at) + (3 << at + COUNT_BITS)
+    assert split_flags(word) == (1, 37, 2, 3)
+    assert split_flags(2) == (2, 0, 0, 0)
+    full = (31 << at) + (31 << at + COUNT_BITS)  # both fields at their cap
+    assert split_flags(full) == (0, 0, 31, 31)
+    flags, steps, compacted, direct = split_flags(
+        np.asarray([word, 3 << FLAG_BITS, 1 << at, full], dtype=np.int32))
+    assert (flags.tolist(), steps.tolist(), compacted.tolist(),
+            direct.tolist()) == \
+        ([1, 0, 0, 0], [37, 3, 0, 0], [2, 0, 1, 31], [3, 0, 0, 31])
 
 
 # -- a failed statement -------------------------------------------------

@@ -92,24 +92,32 @@ def test_lex_sort_compiles_as_one_single_key_sort(one_chip):
     assert "while" in text  # the scan over key words
 
 
-@pytest.mark.parametrize("npr,slots,nb", [
-    (60_000_000, 4_194_304, 15_000_000),  # Q3's lineitem x orders
-    (60_000_000, 1_048_576, 2_000_000),   # Q14's lineitem x part
+@pytest.mark.parametrize("npr,slots,nb,spread", [
+    (60_000_000, 4_194_304, 15_000_000, 1),  # Q3's lineitem x orders
+    (60_000_000, 1_048_576, 2_000_000, 1),   # Q14's lineitem x part
     # Q3 at the capacities its nodes need (the ladder fits a node from
     # its own count): lineitem x orders, and x customer, whose probe is
     # now four times its output and so holds both forms too
-    (60_000_000, 2_097_152, 15_000_000),
-    (2_097_152, 524_288, 1_500_000),
+    (60_000_000, 2_097_152, 15_000_000, 1),
+    (2_097_152, 524_288, 1_500_000, 1),
+    # a chip's share of the mesh's Q3 at SF30: 56M probe rows after the
+    # exchange against a 14M-row hash quarter of orders, the lookup's
+    # directory four times wider (2**26 buckets)
+    (56_000_000, 2_097_152, 14_000_000, 4),
 ], ids=["sf10-q3-join7", "sf10-q14-join4", "sf10-q3-join7-fitted",
-        "sf10-q3-join6-fitted"])
+        "sf10-q3-join6-fitted", "mesh4-sf30-q3-join12"])
 def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
-                                                            slots, nb):
+                                                            slots, nb,
+                                                            spread):
     """`hash_join`'s probe side holds its two forms in a `cond`, and
     XLA:TPU fails to place some 64-bit scans inside a `cond`'s branch
     ("vmem while allocating on stack", by the scan's length: a flat
     int64 cumsum over 4,194,304 rows failed here, over 1,048,576 it did
     not), which no CPU run shows: the forms keep their running sums in
-    int32, and this holds them to it at the benchmark's SF10 shapes."""
+    int32, and this holds them to it at the benchmark's SF10 shapes.
+    Inside each form the lookup holds its own `cond` (the directory's
+    answer or the search's run ends), and the mesh's directory of 2**26
+    buckets is summed along rows (`_running_sum`): both compile here."""
     from presto_tpu import types as T
     from presto_tpu.block import Column
     from presto_tpu.ops import join
@@ -119,13 +127,13 @@ def test_probe_side_compiles_with_both_forms_at_sf10_shapes(one_chip, npr,
     def fn(sorted_keys, b_usable, p_keys, p_active):
         key = Column(p_keys, jnp.zeros(p_keys.shape, dtype=bool), T.INTEGER)
         return join._probe_side([sorted_keys], b_usable, [key], p_active,
-                                False, slots, capacity)
+                                False, slots, capacity, spread)
 
     text = jax.jit(fn).lower(
         _shape((nb,), jnp.uint64, one_chip), _shape((nb,), jnp.bool_, one_chip),
         _shape((npr,), jnp.int32, one_chip),
         _shape((npr,), jnp.bool_, one_chip)).compile().as_text()
-    assert "conditional" in text
+    assert text.count("conditional(") == 3  # the forms', a lookup's each
 
 
 def test_a_meshed_programs_needs_cross_the_chips_in_32_bits(one_chip):
