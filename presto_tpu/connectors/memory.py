@@ -23,9 +23,10 @@ ConnectorMetadata.finishInsert() publish point.
 
 from __future__ import annotations
 
+import functools
 import threading
 import uuid
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -36,7 +37,7 @@ __all__ = ["SCHEMA", "create_table", "drop_table", "reset",
            "table_row_count", "generate_columns", "generate_batch",
            "column_type", "begin_insert", "append", "finish_insert",
            "abort_insert", "table_names", "table_properties",
-           "table_workers"]
+           "table_workers", "scan_snapshot", "on_publish"]
 
 
 class _Table:
@@ -87,17 +88,52 @@ _lock = threading.RLock()
 _tables: Dict[str, _Table] = {}
 _pending: Dict[str, dict] = {}  # handle id -> staging
 _versions: Dict[str, int] = {}  # table -> mutation counter
+# (table, new version) of each bump made under `_lock`, told to the
+# listeners once it is released (`_publishes`)
+_bumped: List[tuple] = []
+_publish_listeners: List[Callable[[str, int], None]] = []
 
 
 def table_version(name: str) -> int:
     """Monotonic per-table mutation counter: fragment-result caching
-    keys on it so cached scans invalidate when a table changes."""
+    and the resident tier (exec/resident.py) key on it, so what they
+    keep of a table invalidates when the table changes."""
     with _lock:
         return _versions.get(name, 0)
 
 
+def on_publish(listener: Callable[[str, int], None]) -> None:
+    """Call `listener(table, version)` whenever `table` moves to a new
+    version (create, publish, rewrite, drop), in the thread that moved
+    it, once the store's lock is released: a listener may take locks
+    of its own, and the store never waits on them."""
+    with _lock:
+        _publish_listeners.append(listener)
+
+
 def _bump_version(name: str) -> None:
-    _versions[name] = _versions.get(name, 0) + 1
+    _versions[name] = version = _versions.get(name, 0) + 1
+    _bumped.append((name, version))
+
+
+def _publishes(fn):
+    """`fn` may bump versions under the store's lock: once it returns
+    (or raises) and the lock is let go, tell each bump to the
+    listeners. A thread may tell another's bump; a listener takes the
+    newest version it is told."""
+    @functools.wraps(fn)
+    def told(*args, **kwargs):
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            with _lock:
+                bumped = list(_bumped)
+                del _bumped[:]
+                listeners = list(_publish_listeners)
+            for name, version in bumped:
+                for listener in listeners:
+                    listener(name, version)
+    return told
 
 
 class SCHEMA(dict):  # noqa: N801 - registry expects a SCHEMA mapping
@@ -139,9 +175,12 @@ def table_names() -> List[str]:
         return sorted(_tables)
 
 
+@_publishes
 def reset() -> None:
     """Test hook: drop everything."""
     with _lock:
+        for name in list(_tables):
+            _bump_version(name)
         _tables.clear()
         _pending.clear()
 
@@ -177,21 +216,29 @@ def table_properties_of(table: str) -> dict:
     return {"workers": workers} if workers > 1 else {}
 
 
+@_publishes
 def create_table(name: str, columns: Sequence[str],
                  types: Sequence[T.Type],
                  if_not_exists: bool = False,
                  properties: Optional[dict] = None) -> None:
     with _lock:
-        if name in _tables:
-            if if_not_exists:
-                return
-            raise ValueError(f"memory table {name!r} already exists")
-        _tables[name] = _Table(
-            list(columns), list(types),
-            table_properties(properties or {}).get("workers", 1))
-        _bump_version(name)
+        _create_locked(name, columns, types, if_not_exists, properties)
 
 
+def _create_locked(name: str, columns: Sequence[str],
+                   types: Sequence[T.Type], if_not_exists: bool = False,
+                   properties: Optional[dict] = None) -> None:
+    if name in _tables:
+        if if_not_exists:
+            return
+        raise ValueError(f"memory table {name!r} already exists")
+    _tables[name] = _Table(
+        list(columns), list(types),
+        table_properties(properties or {}).get("workers", 1))
+    _bump_version(name)
+
+
+@_publishes
 def drop_table(name: str, if_exists: bool = False) -> None:
     with _lock:
         if name not in _tables and not if_exists:
@@ -209,6 +256,19 @@ def column_type(table: str, column: str) -> T.Type:
 def table_row_count(table: str, sf: float = 0.0) -> int:
     with _lock:
         return _tables[table].row_count
+
+
+def scan_snapshot(table: str, columns: Sequence[str]):
+    """A whole-table scan's read, as one publish left the table: its
+    version, its row count and each column's values and null mask (in
+    `columns` order), read together under the store's lock, so that no
+    scan pairs one version with another's rows."""
+    with _lock:
+        t = _tables[table]
+        idx = [t.columns.index(c) for c in columns]
+        return (_versions.get(table, 0), t.row_count,
+                [_view(t.values[i][:]) for i in idx],
+                [_view(t.nulls[i][:]) for i in idx])
 
 
 def generate_columns(table: str, sf: float, columns: Sequence[str],
@@ -297,6 +357,7 @@ def generate_batch(table: str, sf: float, columns: Sequence[str],
 # -- write protocol ---------------------------------------------------------
 
 
+@_publishes
 def begin_insert(table: str,
                  create_columns: Optional[Sequence[str]] = None,
                  create_types: Optional[Sequence[T.Type]] = None,
@@ -308,8 +369,8 @@ def begin_insert(table: str,
     with _lock:
         created = False
         if create_columns is not None:
-            create_table(table, create_columns, create_types,
-                         properties=properties)
+            _create_locked(table, create_columns, create_types,
+                           properties=properties)
             created = True
         if table not in _tables:
             raise KeyError(f"no memory table {table!r}")
@@ -342,6 +403,7 @@ def append(handle: str, columns: Sequence[np.ndarray],
         return n
 
 
+@_publishes
 def finish_insert(handle: str) -> int:
     """Atomic publish of every staged chunk; returns rows written.
     Column by column, each column's chunks let go as soon as they are
@@ -371,11 +433,13 @@ def _to_object(arr) -> np.ndarray:
     return out
 
 
+@_publishes
 def abort_insert(handle: str) -> None:
     with _lock:
         st = _pending.pop(handle, None)
         if st is not None and st["created"]:
             _tables.pop(st["table"], None)
+            _bump_version(st["table"])
 
 
 def data_version(table: str) -> int:
@@ -383,6 +447,7 @@ def data_version(table: str) -> int:
     return table_version(table)
 
 
+@_publishes
 def replace_table(name: str, columns: Sequence[np.ndarray],
                   nulls: Sequence[np.ndarray]) -> int:
     """Atomically swap a table's contents (DELETE/UPDATE rewrite sink).

@@ -877,7 +877,10 @@ class BatchingExecutor:
         """Stage the template's scan batches, replayed from the staged
         cache when every leaf's connector proves its data unchanged
         (data_version -- the worker fragment cache's contract; volatile
-        catalogs stage fresh every batch)."""
+        catalogs stage fresh every batch). A template that scans a
+        memory table is not replayed here: its whole-table scans take
+        the resident tier (exec/resident.py), the one cache of a memory
+        table's staged columns, which evicts and invalidates them."""
         versions: Optional[list] = []
         for s in plan.scan_nodes:
             if isinstance(s, N.ValuesNode):
@@ -888,8 +891,9 @@ class BatchingExecutor:
                 versions = None
                 break
             from ..connectors import catalog
-            fn = getattr(catalog(s.connector), "data_version", None)
-            if fn is None:
+            conn = catalog(s.connector)
+            fn = getattr(conn, "data_version", None)
+            if fn is None or hasattr(conn, "scan_snapshot"):
                 versions = None
                 break
             versions.append((s.connector, s.table, fn(s.table)))

@@ -163,7 +163,12 @@ def _build_domains(build: N.PlanNode, sf: float, channels: List[int]):
     try:
         plan = compile_plan(build)
         from .runner import _scan_batch
-        batches = [_scan_batch(s, sf, None, 8) for s in plan.scan_nodes]
+        # staged afresh, not through the resident tier: this program is
+        # traced, lowered and read from the compile cache anew in every
+        # statement, and on the chip that ran several times slower, in
+        # stretches of seconds, on resident inputs than on fresh ones
+        batches = [_scan_batch(s, sf, None, 8, resident=False)
+                   for s in plan.scan_nodes]
         out, _flags = jax.jit(plan.fn)(batches)
     except Exception:  # noqa: BLE001 - collection is best-effort
         return None

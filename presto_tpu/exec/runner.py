@@ -29,6 +29,9 @@ from .. import types as T
 from ..block import Batch, batch_from_numpy, to_numpy
 from ..plan import nodes as N
 from .planner import compile_plan, shape_key, split_flags
+from .resident import budget as resident_budget
+from .resident import shard_key
+from .resident import tier as resident_tier
 from .stats import (QueryStats, RuntimeStats, StatsCollector, joining, note,
                     note_max, span, stage)
 
@@ -215,12 +218,13 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
     connector_read (host column materialization), decode (a file's
     arrow arrays to lanes), narrow_cast (the staging-time range
     re-proof), device_put (host -> HBM staging, the bytes QueryStats'
-    staging stage counts)."""
+    staging stage counts). A whole-table scan of a memory table does
+    not come here where a budget is known: `_scan_batch` sends it
+    through the resident tier (`_stage_resident`), which shares the
+    host-column tail (`_stage_host_columns`) for what it misses."""
     from .datapath import timed_hop
     from .memory import batch_bytes
     phys = getattr(node, "physical_dtypes", None)
-    shards = len(sharding.mesh.devices.flat) if sharding is not None else 1
-    by_shard = {"shards": shards} if sharding is not None else None
     if not hasattr(conn, "read_columns") and (
             not hasattr(conn, "generate_columns")
             or (sharding is None and (not phys or not any(phys)))):
@@ -246,37 +250,148 @@ def stage_scan_split(conn, node: "N.TableScanNode", sf: float, start: int,
         if b is not None:
             return b
     arrays, nulls = _read_split(conn, node, sf, start, count, predicate)
+    return _stage_host_columns(node.column_types, phys, arrays, nulls,
+                               capacity, sharding)
+
+
+def _stage_host_columns(types, phys, arrays, nulls, capacity: int,
+                        sharding=None) -> Batch:
+    """Host columns to a device batch at the narrowed dtypes `phys`
+    asks for: the staging-time range guard re-proves each narrowed lane
+    against the values (hop ``narrow_cast``; under a mesh shard by
+    shard), then one `batch_from_numpy` (hop ``device_put``)."""
+    from .datapath import timed_hop
+    from .memory import batch_bytes
+    shards = len(sharding.mesh.devices.flat) if sharding is not None else 1
+    by_shard = {"shards": shards} if sharding is not None else None
     if phys and any(phys):
         from ..plan.widths import checked_physical_dtypes
         with timed_hop("narrow_cast", _host_bytes(arrays, nulls), by_shard):
-            phys = checked_physical_dtypes(
-                phys, node.column_types, arrays, nulls=nulls) \
+            phys = checked_physical_dtypes(phys, types, arrays,
+                                           nulls=nulls) \
                 if sharding is None else _checked_by_shard(
-                    phys, node.column_types, arrays, nulls, shards,
-                    capacity // shards)
+                    phys, types, arrays, nulls, shards, capacity // shards)
     with timed_hop("device_put", attrs=by_shard) as t_put:
-        b = batch_from_numpy(node.column_types, arrays, nulls=nulls,
-                             capacity=capacity,
+        b = batch_from_numpy(types, arrays, nulls=nulls, capacity=capacity,
                              physical_dtypes=phys or None,
                              sharding=sharding)
         # sync so the measured wall is the transfer, not the async
-        # dispatch returning early (bench.py learned this on the chip):
-        # the caller host-reads b.active right after, so this adds no
-        # serialization. Nothing overlaps on this path: host columns
-        # are whole before the first byte is put (a lake scan's pieces
-        # overlap in `_stage_pieces`).
+        # dispatch returning early (bench.py learned this on the chip).
+        # Nothing overlaps on this path: host columns are whole before
+        # the first byte is put (a lake scan's pieces overlap in
+        # `_stage_pieces`).
         jax.block_until_ready(b)
         t_put.bytes = batch_bytes(b)
     return b
 
 
+def _tier_room(node: N.PlanNode, scan_range, dyn_filters,
+               hbm_budget) -> Optional[int]:
+    """The room the resident tier gives a scan that takes it (the
+    caller's budget, `resident_budget`): a whole-table scan (no row
+    range, no dynamic filter) of a table its connector keeps versions
+    of (`scan_snapshot`: the memory store). None for any other scan,
+    and where no budget is known."""
+    if not isinstance(node, N.TableScanNode) or scan_range is not None \
+            or dyn_filters or not node.columns:
+        return None
+    from ..connectors import catalog
+    if not hasattr(catalog(node.connector), "scan_snapshot"):
+        return None
+    return resident_budget(hbm_budget)
+
+
+def _resident_place(node: "N.TableScanNode", version: int, rows: int,
+                    capacity_hint, pad_multiple: int, sharding):
+    """A tier scan's place (exec/resident.py) and the (column, dtype)
+    pairs it wants."""
+    cap = capacity_hint or max(-(-rows // pad_multiple) * pad_multiple,
+                               pad_multiple)
+    phys = list(getattr(node, "physical_dtypes", None)
+                or [None] * len(node.columns))
+    return ((node.connector, node.table, version, cap, shard_key(sharding)),
+            list(zip(node.columns, phys)))
+
+
+def _stage_resident(conn, node: "N.TableScanNode", capacity_hint,
+                    pad_multiple: int, sharding, budget: int,
+                    memory_pool=None, query_id: Optional[str] = None
+                    ) -> Batch:
+    """A whole-table scan of a table its connector keeps versions of
+    (`scan_snapshot`: the memory store), through the resident tier
+    (exec/resident.py). The version, the row count and the host columns
+    are read as one snapshot (hop ``connector_read``, which holds the
+    tier's lookup too); the columns the tier holds at that version,
+    capacity and sharding are taken as they lie in HBM, and only the
+    others are re-proved and put (`_stage_host_columns`) and kept (in
+    `memory_pool`, moved out of `query_id`'s reservation). A scan the
+    tier answers whole records no ``narrow_cast`` and no
+    ``device_put``. Counters ``resident_hits`` and ``resident_misses``:
+    the columns taken from the tier and those staged for it."""
+    from .datapath import timed_hop
+    resident = resident_tier()
+    resident.watch(node.connector, conn)
+    with timed_hop("connector_read") as t_read:
+        version, rows, arrays, nulls = conn.scan_snapshot(node.table,
+                                                          node.columns)
+        place, wanted = _resident_place(node, version, rows, capacity_hint,
+                                        pad_multiple, sharding)
+        found, kept = resident.take(place, wanted)
+        missing = [i for i, w in enumerate(wanted)
+                   if w not in found and w not in wanted[:i]]
+        t_read.bytes = _host_bytes([arrays[i] for i in missing],
+                                   [nulls[i] for i in missing])
+    note("resident_hits", len(found))
+    note("resident_misses", len(missing))
+    active = kept[0] if kept is not None else None
+    if missing:
+        part = _stage_host_columns(
+            [node.column_types[i] for i in missing],
+            [wanted[i][1] for i in missing], [arrays[i] for i in missing],
+            [nulls[i] for i in missing], place[3], sharding)
+        staged = {wanted[i]: col for i, col in zip(missing, part.columns)}
+        resident.keep(place, staged, part.active, rows, budget,
+                      memory_pool, query_id)
+        found.update(staged)
+        active = part.active if active is None else active
+    return Batch(tuple(found[w] for w in wanted), active)
+
+
+def _resident_pooled_bytes(node: N.PlanNode, capacity_hint,
+                           pad_multiple: int, sharding, memory_pool) -> int:
+    """Bytes of a tier scan (`_tier_room`) that the tier holds
+    registered in `memory_pool` at the table's version: the
+    statement's reservation leaves them out, as the tier's
+    registration counts them."""
+    from ..connectors import catalog
+    conn = catalog(node.connector)
+    place, wanted = _resident_place(
+        node, conn.table_version(node.table),
+        conn.table_row_count(node.table), capacity_hint, pad_multiple,
+        sharding)
+    return resident_tier().pooled_bytes(place, wanted, memory_pool)
+
+
 def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
                 pad_multiple: int,
                 scan_range: Optional[Tuple[int, int]] = None,
-                dyn_filters=None, stats=None, sharding=None) -> Batch:
+                dyn_filters=None, stats=None, sharding=None,
+                hbm_budget=None, memory_pool=None,
+                query_id: Optional[str] = None, resident: bool = True
+                ) -> Batch:
     """One scan leaf's staged batch; with `sharding` (a statement over
     a mesh) laid out over the mesh's devices, a table's rows shard by
-    shard from the host (`stage_scan_split`)."""
+    shard from the host. What the code observes picks the path:
+
+    * a whole-table scan (no row range, no dynamic filter) of a table
+      its connector keeps versions of (`scan_snapshot`), where a budget
+      is known (`_tier_room`: the statement's `hbm_budget_bytes`
+      capped at the device's limit, else that limit): through the
+      resident tier (`_stage_resident`), `memory_pool` and `query_id`
+      the statement's; `resident=False` stages it afresh (the dynamic
+      filter's dimension side: exec/dynfilter.py);
+    * a dynamic-filtered scan: read, pruned on the host, then staged;
+    * every other scan: `stage_scan_split`."""
     if isinstance(node, N.ValuesNode):
         arrays = []
         null_masks = []
@@ -305,6 +420,10 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
     assert isinstance(node, N.TableScanNode)
     from ..connectors import catalog
     conn = catalog(node.connector)
+    room = _tier_room(node, scan_range, dyn_filters, hbm_budget)
+    if room and resident:
+        return _stage_resident(conn, node, capacity_hint, pad_multiple,
+                               sharding, room, memory_pool, query_id)
     if scan_range is not None:
         start, count = scan_range
     else:
@@ -313,7 +432,6 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
         # dynamic filtering: prune fact rows host-side BEFORE they are
         # staged into HBM (DynamicFilterSourceOperator pushdown; the
         # win here is smaller staged shapes)
-        from .datapath import timed_hop
         from .dynfilter import apply_dynamic_filters
         arrays, nulls = _read_split(conn, node, sf, start, count)
         # the filter's host side, between the read and the re-proof: the
@@ -331,22 +449,11 @@ def _scan_batch(node: N.PlanNode, sf: float, capacity_hint: Optional[int],
             arrays = [a[keep] for a in arrays]
             if nulls is not None:
                 nulls = [n[keep] for n in nulls]
-        tys = node.column_types
         nrows = len(arrays[0])
         cap = max(-(-nrows // pad_multiple) * pad_multiple, pad_multiple)
-        phys = getattr(node, "physical_dtypes", None)
-        if phys and any(phys):
-            from ..plan.widths import checked_physical_dtypes
-            with timed_hop("narrow_cast", _host_bytes(arrays, nulls)):
-                phys = checked_physical_dtypes(phys, tys, arrays,
-                                               nulls=nulls)
-        from .memory import batch_bytes
-        with timed_hop("device_put") as t_put:
-            b = batch_from_numpy(tys, arrays, capacity=cap, nulls=nulls,
-                                 physical_dtypes=phys or None)
-            jax.block_until_ready(b)
-            t_put.bytes = batch_bytes(b)
-        return b
+        return _stage_host_columns(node.column_types,
+                                   getattr(node, "physical_dtypes", None),
+                                   arrays, nulls, cap)
     cap = capacity_hint or max(-(-count // pad_multiple) * pad_multiple,
                                pad_multiple)
     # connector statistics pruning: a file scan skips the row groups the
@@ -362,21 +469,31 @@ def _count_staged(scan_leaves, batches, collector: StatsCollector,
                   stats: RuntimeStats, prog, sf: float,
                   query_id: str) -> int:
     """Staging's own bookkeeping, the child span ``scan_count`` of
-    ``staging``: each scan's rows, counted by reading its `active` mask
-    back whole (attribute `bytes_read_back`: the masks' bytes), its
-    bytes, its operator and accuracy records, what narrowing saved; the
-    sums go to the ``staging`` stage. Returns the staged bytes."""
+    ``staging``: each scan's rows, its bytes, its operator and accuracy
+    records, what narrowing saved; the sums go to the ``staging``
+    stage. A scan the resident tier holds the mask of
+    (exec/resident.py) takes its rows from the tier's entry; any other
+    is counted by reading its `active` mask back whole (attribute
+    `bytes_read_back`: the masks read back). Where the statement went
+    through the tier, counter ``resident_bytes``: what the tier holds
+    after the staging, on its fullest chip. Returns the staged
+    bytes."""
     from ..plan.widths import batch_narrowed_bytes_saved, note_narrowed
     from .accuracy import est_rows_of as _acc_est
     from .accuracy import record_node as _acc_record
     from .memory import batch_bytes
+    resident = resident_tier()
+    known = [resident.rows_of(b) for b in batches]
     staged_rows = staged_bytes = 0
     narrowed_cols = narrowed_saved = 0
     with stage("scan_count", {
             "scans": len(batches),
-            "bytes_read_back": sum(int(b.active.nbytes) for b in batches)}):
+            "bytes_read_back": sum(int(b.active.nbytes)
+                                   for b, n in zip(batches, known)
+                                   if n is None)}):
         for si, (s, b) in enumerate(zip(scan_leaves, batches)):
-            rows = int(np.asarray(b.active).sum())
+            rows = int(np.asarray(b.active).sum()) if known[si] is None \
+                else known[si]
             nbytes = batch_bytes(b)
             staged_rows += rows
             staged_bytes += nbytes
@@ -397,6 +514,8 @@ def _count_staged(scan_leaves, batches, collector: StatsCollector,
                 narrowed_saved += nb
         collector.bump_stage("staging", rows=staged_rows,
                              bytes=staged_bytes)
+        if "resident_hits" in collector.stats.counters:
+            collector.note_max("resident_bytes", resident.held_bytes())
         if narrowed_saved:
             # staged bytes saved vs logical lanes: the QueryStats
             # counter the acceptance criteria name, plus the
@@ -819,11 +938,17 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
     if memory_pool is not None:
         # admission accounting (MemoryPool.reserve analog): PLANNED scan
         # footprints are charged before any device allocation, so a
-        # reservation failure surfaces before the scan stage can OOM
-        reserved = sum(
+        # reservation failure surfaces before the scan stage can OOM;
+        # what the resident tier has registered of a scan is left out
+        # (its registration counts those bytes)
+        reserved = sum(max(
             _planned_scan_bytes(s, sf, hints.get(s.id), pad,
                                 scan_ranges.get(s.id), remote_sources)
-            for s in scan_leaves)
+            - (_resident_pooled_bytes(s, hints.get(s.id), pad, sharding,
+                                      memory_pool)
+               if _tier_room(s, scan_ranges.get(s.id),
+                             dyn_filters.get(s.id), hbm_budget) else 0),
+            0) for s in scan_leaves)
         memory_pool.reserve(query_id, reserved)
         if prog is not None:
             prog.note_memory(reserved)
@@ -844,7 +969,9 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                         s, sf, hints.get(s.id), pad,
                         scan_ranges.get(s.id),
                         dyn_filters=dyn_filters.get(s.id),
-                        stats=stats, sharding=sharding))
+                        stats=stats, sharding=sharding,
+                        hbm_budget=hbm_budget, memory_pool=memory_pool,
+                        query_id=query_id))
                 collector.operator(
                     _scan_key(si, s), _scan_label(s),
                     wall_us=int((time.time() - t_scan0) * 1e6))
@@ -903,13 +1030,14 @@ def _run_query_inner(root: N.PlanNode, sf: float = 0.01, mesh=None,
                     use_cache, stats, session, adaptive_off, refine,
                     prog, collector, query_id,
                     memory_pool, plan_fp_root=plan_fingerprint(root),
-                    sf=sf)
+                    sf=sf, hbm_budget=hbm_budget)
             else:
                 (out, device_s, dispatch_fn, call_lock, ran_caps,
                  scale, plan) = _dispatch_ladder(
                     root, plan, jfn, call_lock, batches, mesh,
                     default_join_capacity, use_cache, fp, stats,
-                    adaptive_off, refine, prog, rplan.regions[0].tag)
+                    adaptive_off, refine, prog, rplan.regions[0].tag,
+                    hbm_budget)
         # XLA compile cost (compile-time captured via jax.monitoring; a
         # plan-cache hit naturally reports zero) + the program's
         # FLOPs / bytes-accessed from cost_analysis, memoized per plan.
@@ -1063,12 +1191,14 @@ def _read_status(status, plan, expand_steps: Optional[int]
     return flags, int(routed), {k: int(v) for k, v in needs.items()}
 
 
-def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
-    """Device memory of the program `dispatch_fn` has just run on
+def _program_hbm_bytes(plan, dispatch_fn, batches) -> int:
+    """Device memory of the program `dispatch_fn` is about to run on
     `batches`: its arguments, outputs and temporaries as XLA's memory
     analysis of the executable gives them, aliased bytes counted once.
-    Lowering the call again finds the executable jit keeps for these
-    shapes (no trace, no compile), and the answer stays with the
+    Called under the plan's call lock before the dispatch: lowering
+    traces and compiles a shape met for the first time, and the call
+    that follows finds the executable jit keeps for these shapes (no
+    second trace, no second compile); the answer stays with the
     compiled plan. A program over a mesh plans that much on each of its
     chips: the analysis of an SPMD executable is one device's. Where the
     executable gives no analysis (one read from a compile cache may
@@ -1078,10 +1208,8 @@ def _program_hbm_bytes(plan, dispatch_fn, call_lock, batches) -> int:
     if key not in plan.hbm_bytes:
         found = 0
         try:
-            with call_lock if call_lock is not None \
-                    else contextlib.nullcontext():
-                ma = dispatch_fn.lower(tuple(batches)).compile() \
-                    .memory_analysis()
+            ma = dispatch_fn.lower(tuple(batches)).compile() \
+                .memory_analysis()
             found = int(ma.argument_size_in_bytes + ma.output_size_in_bytes
                         + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         except Exception:  # noqa: BLE001 - a backend without the analysis
@@ -1103,7 +1231,7 @@ def _worth_refit(ran: Dict[int, int], fitted: Dict[int, int]) -> bool:
 def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
                      mesh, default_join_capacity: int, use_cache: bool,
                      fp: Optional[str], stats, adaptive_off: bool,
-                     refine: bool, prog, tag: str):
+                     refine: bool, prog, tag: str, hbm_budget=None):
     """The overflow->rerun dispatch loop for ONE compiled program (a
     whole fused plan or a single pipeline region).
 
@@ -1136,7 +1264,10 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
     ``execute`` with the region's `tag`: ``dispatch`` (the call of the
     jitted program until it returns: flatten, jit-cache lookup,
     trace/lower/compile or cache read on a miss, enqueue) and
-    ``device_wait`` (block_until_ready plus the status read).
+    ``device_wait`` (block_until_ready plus the status read). Before
+    each dispatch, the program's planned bytes (`_program_hbm_bytes`)
+    are noted and the resident tier trimmed to the room the
+    statement's `hbm_budget` leaves beside them (exec/resident.py).
 
     Returns (out, device_s, dispatch_fn, call_lock, the capacities that
     ran as a hashable, scale, plan)."""
@@ -1170,18 +1301,18 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
     while True:
         t_disp0 = _now_us()
         with stage("dispatch", region):
-            if jfn is None:
-                fn = jax.jit(plan.fn)
-                dispatch_fn = fn
-                out, overflow = fn(tuple(batches))
+            dispatch_fn = jax.jit(plan.fn) if jfn is None else jfn
+            lock = call_lock if jfn is not None \
+                else contextlib.nullcontext()
+            with lock:  # serialize trace-time closure state
+                planned = _program_hbm_bytes(plan, dispatch_fn, batches)
                 expand_steps = plan.expand_steps_of(batches)
                 exchanges = plan.exchanges_of(batches)
-            else:
-                dispatch_fn = jfn
-                with call_lock:  # serialize trace-time closure state
-                    out, overflow = jfn(tuple(batches))
-                    expand_steps = plan.expand_steps_of(batches)
-                    exchanges = plan.exchanges_of(batches)
+            note_max("program_hbm_bytes", planned)
+            # the resident tier makes room before the program runs
+            resident_tier().note_program(planned, resident_budget(hbm_budget))
+            with lock:
+                out, overflow = dispatch_fn(tuple(batches))
         with stage("device_wait", region):
             jax.block_until_ready(out)
             # host-observed device occupancy of this dispatch: the
@@ -1191,8 +1322,6 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
             device_s += (_now_us() - t_disp0) / 1e6
             flags, routed, needs = _read_status(overflow, plan,
                                                 expand_steps)
-        note_max("program_hbm_bytes",
-                 _program_hbm_bytes(plan, dispatch_fn, call_lock, batches))
         if prog is not None:  # each landed dispatch advances
             prog.advance()
         if flags == 0:
@@ -1261,7 +1390,8 @@ def _dispatch_ladder(root: N.PlanNode, plan, jfn, call_lock, batches,
 def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                      use_cache, stats, session, adaptive_off, refine,
                      prog, collector, query_id,
-                     memory_pool, plan_fp_root: str, sf: float = 0.01):
+                     memory_pool, plan_fp_root: str, sf: float = 0.01,
+                     hbm_budget=None):
     """Materialized region executor (exec/regions.py partition): run
     each pipeline region as its own compiled-and-cached program in
     producer order. Region outputs stay DEVICE-resident Batches handed
@@ -1407,7 +1537,7 @@ def _execute_regions(rplan, scan_leaves, batches, default_join_capacity,
                     _dispatch_ladder(
                         reg.root, plan, jfn, call_lock, rbatches, None,
                         default_join_capacity, use_cache, rfp, stats,
-                        adaptive_off, refine, prog, reg.tag)
+                        adaptive_off, refine, prog, reg.tag, hbm_budget)
             if cost_on and collector is not None and dispatch_fn is not None:
                 # per-region XLA cost analysis: the fused path's FLOPs /
                 # bytes-accessed split, summed region by region so EXPLAIN

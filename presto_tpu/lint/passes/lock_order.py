@@ -44,6 +44,9 @@ CONCURRENCY_TARGETS = (
     "presto_tpu/exec/regions.py",
     "presto_tpu/exec/datapath.py",
     "presto_tpu/exec/accuracy.py",
+    "presto_tpu/exec/resident.py",
+    "presto_tpu/exec/memory.py",
+    "presto_tpu/connectors/memory.py",
     "presto_tpu/utils/locks.py",
 )
 

@@ -390,3 +390,37 @@ def test_perfgate_gates_staging_rate(tmp_path):
     key, metrics, _meta = perfgate_cli.load_artifact(str(art))
     assert metrics["staging_gb_per_s"] == pytest.approx(0.21)
     assert key == "tpch_sf1_q1_rows_per_sec|cpu"
+
+
+def test_a_resident_scan_puts_nothing_and_stages_the_same_bytes():
+    """A whole-table scan of a memory table through the resident tier
+    (a budget known: here the statement's `hbm_budget_bytes`): the
+    first run stages and keeps its columns, the put reconciling with the
+    staged bytes as above; the second takes them from HBM, so its
+    waterfall has no `device_put` and no `narrow_cast`, its
+    `connector_read` (the store's snapshot) carries no bytes, and the
+    staged bytes and the kernel's are the first run's."""
+    from presto_tpu.connectors import memory
+    from presto_tpu.exec.resident import tier
+    from presto_tpu.sql import sql
+    sql("DROP TABLE IF EXISTS memory.dp_lineitem", sf=0.01)
+    sql("CREATE TABLE memory.dp_lineitem AS SELECT returnflag, linestatus, "
+        "quantity, extendedprice, discount, tax, shipdate "
+        "FROM tpch.tiny.lineitem", sf=0.01)
+    text = TPCH_Q1.replace("FROM lineitem", "FROM memory.dp_lineitem")
+    tier().clear()
+    try:
+        first, again = (sql(text, sf=0.01, hbm_budget_bytes=1 << 30)
+                        for _ in range(2))
+    finally:
+        tier().clear()
+        memory.drop_table("dp_lineitem", if_exists=True)
+    assert first.rows() == again.rows()
+    staged = first.query_stats.stages["staging"].bytes
+    put = first.query_stats.datapath["device_put"].bytes
+    assert staged > 0 and abs(put - staged) / staged < 0.01
+    hops = again.query_stats.datapath
+    assert "device_put" not in hops and "narrow_cast" not in hops
+    assert hops["connector_read"].bytes == 0
+    assert again.query_stats.stages["staging"].bytes == staged
+    assert hops["kernel"].bytes == first.query_stats.datapath["kernel"].bytes

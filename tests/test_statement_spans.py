@@ -517,6 +517,36 @@ def test_scan_count_closes_staging_before_execute_opens(cells, name):
     assert run["stats"]["stages"]["scan_count"]["invocations"] == 1
 
 
+@pytest.mark.parametrize("name", ["q6-mem", "q1-mem"])
+def test_a_resident_hit_stages_without_a_put(cells, name):
+    """Where a budget is known (here the statement's `hbm_budget_bytes`)
+    a whole-table scan of a memory table goes through the resident
+    tier: a statement's second run takes every column from HBM, so its
+    `staging` holds the store's snapshot (`connector_read`) and
+    `scan_count` and no `narrow_cast` or `device_put`, and `scan_count`
+    reads no mask back; the rows it counts are the first run's."""
+    from presto_tpu.exec.resident import tier
+    tier().clear()
+    try:
+        (first, s1), (again, s2) = (
+            _library_spans(_cell_text(name), hbm_budget_bytes=1 << 30)
+            for _ in range(2))
+    finally:
+        tier().clear()
+    assert first.rows() == again.rows()
+    assert "device_put" in s1 and "narrow_cast" in s1
+    assert "device_put" not in s2 and "narrow_cast" not in s2
+    (staging,), (count,) = s2["staging"], s2["scan_count"]
+    (read,) = s2["connector_read"]
+    assert read[5] == count[5] == staging[4]  # children of `staging`
+    assert count[3] == {"scans": 1, "bytes_read_back": 0}
+    assert s1["scan_count"][0][3]["bytes_read_back"] == 0
+    counters = again.query_stats.counters
+    assert counters["resident_hits"] >= 1 and counters["resident_misses"] == 0
+    assert again.query_stats.stages["staging"].rows == \
+        first.query_stats.stages["staging"].rows > 0
+
+
 @pytest.mark.parametrize("name", CELL_STATEMENTS)
 def test_finish_is_top_level_and_follows_fetch(cells, name):
     """From `fetch`'s exit to the return into the server: one `finish`
